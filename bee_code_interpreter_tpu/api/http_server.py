@@ -683,11 +683,12 @@ def create_http_server(
 
             if req.target == "device":
                 # Raw device-runtime capture (docs/observability.md
-                # "Accelerator observability"): serving steps when an
-                # engine is attached, a probe computation otherwise. 501
-                # with the concrete reason when the runtime cannot trace
-                # (no profiler wired, jax.profiler missing, or start_trace
-                # rejected by this backend); 503 only for the transient
+                # "Accelerator observability"): the attached engine's
+                # steps under its own profiler trace. 501 with the reason
+                # when there is nothing to trace through (no profiler
+                # wired, no in-process engine — this process then does
+                # not hold the device — or start_trace rejected by the
+                # backend); 503 only for the transient
                 # capture-already-running case.
                 if device_profiler is None or not getattr(
                     device_profiler, "available", True
@@ -695,7 +696,8 @@ def create_http_server(
                     return web.json_response(
                         {
                             "detail": "device profiling unavailable: no "
-                            "jax.profiler on this runtime"
+                            "in-process engine attached (the device "
+                            "belongs to the process that runs one)"
                         },
                         status=501,
                     )

@@ -194,20 +194,20 @@ class ApplicationContext:
         self.serving_profiler = ServingProfiler(self.serving)
         # Accelerator observability (docs/observability.md "Accelerator
         # observability"): compile/retrace wide events + counters, the
-        # device-memory sampler (live-buffer estimate on CPU), per-mesh-
-        # shape step timing. Constructed unconditionally — metrics must
-        # exist either way, and the constructor's eager memory sample
-        # registers the HBM gauges; attach_serving_engine binds the
-        # batcher's tracked jits, start_observability starts the sampler.
+        # device-memory sampler, per-mesh-shape step timing. Constructed
+        # unconditionally (its metrics must exist either way) but inert:
+        # a chip belongs to one process, and on the execute path that is
+        # the sandbox child — so nothing here imports or initializes jax
+        # until attach_serving_engine binds an in-process engine, whose
+        # batcher hands the memory rows and the profiler trace in.
         self.device = DeviceMonitor(
             metrics=self.metrics,
             recorder=self.flight,
             sample_interval_s=self.config.device_sample_interval_s,
             max_compiles=self.config.device_compile_records,
         )
-        # POST /v1/profile target=device: raw jax.profiler capture —
-        # serving steps when an engine is attached, a probe computation
-        # otherwise (501 when the runtime cannot trace at all).
+        # POST /v1/profile target=device: the attached engine's steps
+        # under its own jax.profiler trace; 501 while none is attached.
         self.device_profiler = DeviceProfiler(self.serving)
         # Telemetry export: with APP_OTLP_ENDPOINT set, finished traces and
         # metric snapshots are pushed OTLP/JSON to the collector by a

@@ -176,7 +176,7 @@ def filtered_probs_host(
 ) -> np.ndarray:
     """The numpy mirror of ``transformer.filter_logits`` + softmax for one
     row — pure host math so the decode loop never dispatches per-row jax
-    ops through a (possibly tunneled) device. Tie semantics match the
+    ops (each a dispatch and a sync of its own). Tie semantics match the
     device filter exactly (top-k keeps >= kth; nucleus order is a stable
     descending argsort; top token always kept) — pinned by
     tests/test_serving.py::test_host_filter_parity_with_device."""
@@ -484,9 +484,7 @@ class ContinuousBatcher:
                 )
             if gamma < 1:
                 raise ValueError(f"gamma must be >= 1, got {gamma}")
-        self.cache = alloc_paged_cache(config, n_pages, page_size)
-        if mesh is not None:
-            self.cache = self._shard_pool(self.cache)
+        self.cache = self._alloc_pool(config, n_pages)
         self.block_table = np.full(
             (max_batch, max_pages_per_seq), _SCRATCH_PAGE, dtype=np.int32
         )
@@ -582,14 +580,11 @@ class ContinuousBatcher:
         if draft_config is not None:
             # the draft's own paged pool, addressed by the SAME block
             # tables/pages (one allocation covers both models' K/V)
-            self.draft_cache = alloc_paged_cache(
-                draft_config, n_pages, page_size
-            )
+            self.draft_cache = self._alloc_pool(draft_config, n_pages)
             if mesh is not None:
                 self.draft_params = shard_params(
                     draft_params, draft_config, mesh
                 )
-                self.draft_cache = self._shard_pool(self.draft_cache)
             self._draft_decode = self._track(
                 jax.jit(
                     functools.partial(decode_step_paged, config=draft_config),
@@ -733,6 +728,24 @@ class ContinuousBatcher:
         (observability.DeviceMonitor.attach calls this). Programs compiled
         before attachment are not reported retroactively."""
         self._device_monitor = monitor
+
+    def device_memory(self) -> list[dict]:
+        """Memory rows (``parallel.mesh.device_memory_rows``) for the
+        devices this batcher's page pool lives on. The device monitor
+        samples through this: only a process that runs an engine touches
+        the accelerator, so the control plane never imports jax itself."""
+        from bee_code_interpreter_tpu.parallel.mesh import device_memory_rows
+
+        return device_memory_rows(
+            sorted(self.cache["k"].devices(), key=lambda d: d.id)
+        )
+
+    def profiler_trace(self, trace_dir: str):
+        """Context manager: a ``jax.profiler`` trace of this process into
+        ``trace_dir``. ``POST /v1/profile`` captures through this for the
+        same reason ``device_memory`` exists — only the process that holds
+        the chip can trace it."""
+        return jax.profiler.trace(trace_dir)
 
     def kv_telemetry(self) -> dict:
         """KV-cache pool telemetry (docs/observability.md "Serving
@@ -882,18 +895,31 @@ class ContinuousBatcher:
             (self.page_ref > 0).sum()
         )
 
-    def _shard_pool(self, pool: dict) -> dict:
-        """Shard a page pool's kv-head axis over the mesh's tp axis (axis 2
-        of [n_layers, n_pages, kvh, ps, dh]; the int8 scale planes share
-        the leading dims, so the one spec covers every leaf). A mesh
+    def _pool_sharding(self):
+        """The page pool's sharding under the mesh: the kv-head axis over tp
+        (axis 2 of [n_layers, n_pages, kvh, ps, dh]; the int8 scale planes
+        share the leading dims, so the one spec covers every leaf). A mesh
         without a tp axis replicates the pool — matching param_specs'
         whichever-axes-exist stance."""
         from jax.sharding import NamedSharding, PartitionSpec
 
         tp = "tp" if "tp" in self.mesh.axis_names else None
-        spec = NamedSharding(
+        return NamedSharding(
             self.mesh, PartitionSpec(None, None, tp, None, None)
         )
+
+    def _alloc_pool(self, config: TransformerConfig, n_pages: int) -> dict:
+        """A zeroed page pool, placed where it will live: under a mesh each
+        device receives only its own shard (ops.paged_kv_cache
+        .alloc_paged_cache has the why)."""
+        return alloc_paged_cache(
+            config, n_pages, self.page_size,
+            sharding=None if self.mesh is None else self._pool_sharding(),
+        )
+
+    def _shard_pool(self, pool: dict) -> dict:
+        """Place a restored snapshot's pool under the mesh."""
+        spec = self._pool_sharding()
         return {k: jax.device_put(v, spec) for k, v in pool.items()}
 
     # ------------------------------------------------------------- admission

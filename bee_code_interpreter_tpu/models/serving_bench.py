@@ -23,8 +23,9 @@ parameters), and an operator at a REPL all run the SAME arithmetic:
   ``GET /v1/serving/requests`` serves).
 
 CPU-pinned tiny-model by default: the point is a stable trajectory of the
-SERVING STACK's behavior in every artifact; hardware decode numbers live
-in scripts/bench-decode.py's evidence ledger. Note the default geometry
+SERVING STACK's behavior in every artifact — never a device number;
+scripts/bench-decode.py is what measures decode on the chip. Note the
+default geometry
 (batch 8 × 32 tokens) is the FAIREST tiny-model denominator for the
 overhead A/B, not a flattering one: instrumentation cost is fixed per
 step/request, and the tiny model's ~1-2 ms CPU steps are already a far

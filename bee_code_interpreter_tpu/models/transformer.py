@@ -386,10 +386,9 @@ def _attention(
     # inside ulysses), so disable the check exactly there and keep it for
     # the kernel-free CPU paths.
     from bee_code_interpreter_tpu.ops.flash_attention import uses_flash
-    from bee_code_interpreter_tpu.parallel.mesh import shard_map_compat
 
     uses_pallas = uses_flash()
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         local, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=not uses_pallas,
     )

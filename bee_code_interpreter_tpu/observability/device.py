@@ -16,14 +16,16 @@ signals, all CPU-deterministic so tier-1 needs no TPU:
   ``xla.compile`` span inside that request's span tree, all naming the
   same trace_id. A TTFT spike caused by a mid-stream retrace is therefore
   visible in three correlated places, not zero.
-- **Device-memory accounting**: a periodic sampler over
-  ``device.memory_stats()`` where the backend provides it (TPU), degrading
-  to a live-buffer byte estimate from ``jax.live_arrays()`` on CPU (rows
-  marked ``estimated``), published as ``bci_device_hbm_bytes{kind=
-  live|peak|limit}`` per device. The paged-KV pool occupancy joins the
-  snapshot from the attached batcher's ``kv_telemetry()`` (PR 9
-  ``pool_telemetry``) so "how full is HBM" and "how full is the KV pool"
-  read from one call.
+- **Device-memory accounting**: a periodic sampler over the ATTACHED
+  batcher's ``device_memory()`` — ``device.memory_stats()`` where the
+  backend provides it (TPU), a live-buffer byte estimate on CPU (rows
+  marked ``estimated``) — published as ``bci_device_hbm_bytes{kind=
+  live|peak|limit}`` per device. A chip belongs to one process, so a
+  process with no engine attached (the execute-path control plane, whose
+  sandbox children own the chip) never imports jax and reports no memory
+  rows. The paged-KV pool occupancy joins the snapshot from the batcher's
+  ``kv_telemetry()`` (PR 9 ``pool_telemetry``) so "how full is HBM" and
+  "how full is the KV pool" read from one call.
 - **Mesh-aware step telemetry**: the batcher (and the MULTICHIP dryrun)
   report per-step wall time tagged with the mesh's shape key
   (``parallel.mesh.mesh_shape_key``), aggregated per shape — the
@@ -46,6 +48,12 @@ from collections import deque
 
 from bee_code_interpreter_tpu.observability.tracing import current_trace
 
+# why a process with no engine attached reports no device memory
+NO_ENGINE = (
+    "no in-process engine attached: the accelerator belongs to the "
+    "process that runs one (here, the sandbox children)"
+)
+
 # histogram buckets for compile wall time: compiles run 10 ms (tiny CPU
 # programs) to minutes (big sharded models) — the serving-latency buckets
 # top out far too low to see them
@@ -54,17 +62,13 @@ COMPILE_SECONDS_BUCKETS = (
 )
 
 
-def _device_key(device) -> str:
-    return f"{device.platform}:{device.id}"
-
-
 class DeviceMonitor:
     """Compile/retrace tracking, device-memory accounting, and per-mesh-
     shape step telemetry. Constructed by the composition root next to the
-    other monitors (metrics register immediately; the constructor takes
-    one memory sample so the HBM gauges exist before the sampler starts);
-    :meth:`attach` binds a ``models.engine.Engine`` or bare
-    ``ContinuousBatcher`` and injects the monitor into its tracked jits.
+    other monitors (metrics register immediately); :meth:`attach` binds a
+    ``models.engine.Engine`` or bare ``ContinuousBatcher``, injects the
+    monitor into its tracked jits and takes the first memory sample —
+    until then nothing here touches the device.
     """
 
     def __init__(
@@ -116,9 +120,6 @@ class DeviceMonitor:
                 "bci_device_step_seconds",
                 "Batcher/dryrun step wall time, by mesh shape",
             )
-        # one eager sample: the HBM gauges must exist (and the snapshot
-        # must be complete) before — or without — the background sampler
-        self.sample_memory()
 
     # ------------------------------------------------------------ wiring
 
@@ -143,6 +144,9 @@ class DeviceMonitor:
             self._loop = asyncio.get_running_loop()
         except RuntimeError:
             pass
+        # the HBM gauges must exist (and the snapshot must be complete)
+        # before — or without — the background sampler
+        self.sample_memory()
 
     @property
     def available(self) -> bool:
@@ -302,76 +306,22 @@ class DeviceMonitor:
     # ----------------------------------------------------- memory sampler
 
     def sample_memory(self) -> list[dict]:
-        """One device-memory sample: ``memory_stats()`` where the backend
-        provides it (TPU), else the live-buffer estimate (CPU — rows
-        marked ``estimated``, peak tracked as a running max, no limit).
-        Registers the per-(device, kind) ``bci_device_hbm_bytes`` gauge
-        series on first sight."""
-        try:
-            import jax
-
-            devices = jax.devices()
-        except Exception:
+        """One device-memory sample through the attached batcher's
+        ``device_memory()``; estimated (CPU) rows get their peak tracked
+        here as a running max. No engine attached: no rows, and nothing is
+        imported or initialized. Registers the per-(device, kind)
+        ``bci_device_hbm_bytes`` gauge series on first sight."""
+        if self._batcher is None:
             return []
-        rows: list[dict] = []
-        live_estimate: dict[str, int] | None = None
-        for device in devices:
-            try:
-                stats = device.memory_stats()
-            except Exception:
-                stats = None
-            key = _device_key(device)
-            if stats:
-                live = int(stats.get("bytes_in_use", 0))
-                rows.append(
-                    {
-                        "device": key,
-                        "platform": device.platform,
-                        "live_bytes": live,
-                        "peak_bytes": int(
-                            stats.get("peak_bytes_in_use", live)
-                        ),
-                        "limit_bytes": (
-                            int(stats["bytes_limit"])
-                            if "bytes_limit" in stats
-                            else None
-                        ),
-                        "estimated": False,
-                    }
+        rows = self._batcher.device_memory()
+        for row in rows:
+            if row["estimated"]:
+                peak = max(
+                    self._peak_estimate.get(row["device"], 0),
+                    row["live_bytes"],
                 )
-                continue
-            if live_estimate is None:
-                live_estimate = {}
-                for arr in jax.live_arrays():
-                    try:
-                        arr_devices = list(arr.devices())
-                    except Exception:
-                        continue
-                    if not arr_devices:
-                        continue
-                    # a sharded array's nbytes is the GLOBAL size: spread
-                    # it evenly over its devices for the per-device view
-                    per_device = int(
-                        getattr(arr, "nbytes", 0) or 0
-                    ) // len(arr_devices)
-                    for arr_device in arr_devices:
-                        dk = _device_key(arr_device)
-                        live_estimate[dk] = (
-                            live_estimate.get(dk, 0) + per_device
-                        )
-            live = live_estimate.get(key, 0)
-            peak = max(self._peak_estimate.get(key, 0), live)
-            self._peak_estimate[key] = peak
-            rows.append(
-                {
-                    "device": key,
-                    "platform": device.platform,
-                    "live_bytes": live,
-                    "peak_bytes": peak,
-                    "limit_bytes": None,
-                    "estimated": True,
-                }
-            )
+                self._peak_estimate[row["device"]] = peak
+                row["peak_bytes"] = peak
         with self._lock:
             self._memory = rows
             self._memory_unix = time.time()
@@ -385,9 +335,9 @@ class DeviceMonitor:
                     self._gauged.add(gauge_key)
                     self._metrics.gauge(
                         "bci_device_hbm_bytes",
-                        "Device memory bytes by kind (live|peak|limit); "
-                        "live-buffer estimate on backends without "
-                        "memory_stats",
+                        "Device memory bytes by kind (live|peak|limit) on "
+                        "the attached engine's devices; live-buffer "
+                        "estimate on backends without memory_stats",
                         (
                             lambda d=row["device"], k=kind: float(
                                 self._memory_value(d, k)
@@ -411,10 +361,11 @@ class DeviceMonitor:
     def snapshot(self, recent: int = 16) -> dict:
         """The ``GET /v1/accelerator`` body: compile totals + per-function
         ledgers + the last ``recent`` compile records, the latest memory
-        sample (``estimated`` marks the CPU degradation), the KV-pool
-        occupancy joined from the attached batcher, the mesh descriptor,
-        and the per-shape step aggregates. Pure host bookkeeping — safe on
-        every scrape."""
+        sample (``estimated`` marks the CPU degradation; no rows and a
+        ``reason`` while no engine is attached), the KV-pool occupancy
+        joined from the attached batcher, the mesh descriptor, and the
+        per-shape step aggregates. Pure host bookkeeping — safe on every
+        scrape."""
         with self._lock:
             functions = {
                 name: {
@@ -445,6 +396,9 @@ class DeviceMonitor:
                         else None
                     ),
                     "devices": memory_rows,
+                    "reason": (
+                        None if self._batcher is not None else NO_ENGINE
+                    ),
                 },
                 "mesh": dict(self._mesh) if self._mesh else None,
                 "steps": {

@@ -9,17 +9,20 @@ module drills into the slow *op* without redeploying anything:
   injects :data:`SANDBOX_PROFILE_DIR` into the request env and the trace
   artifacts ride back through the ordinary changed-file snapshot — no new
   download channel.
-- **The serving engine**: :class:`ServingProfiler` wraps anything with a
-  ``step()`` (an ``Engine`` or ``ContinuousBatcher``) and captures N steps
-  under ``jax.profiler`` into a local directory the operator can pull into
-  TensorBoard/XProf.
+- **The serving engine**: :class:`ServingProfiler` wraps a stepper — the
+  ``ServingMonitor`` once an engine is attached — and captures N steps
+  under the trace the stepper itself opens (``profiler_trace``, which the
+  batcher implements with ``jax.profiler``) into a local directory the
+  operator can pull into TensorBoard/XProf.
 
-``jax`` is imported lazily: a control plane serving only the executor path
-never pays a jax import for having the endpoint mounted.
+This module never imports ``jax``: only the process that holds the chip
+can trace it, and a control plane serving the executor path holds none —
+its sandbox children do. No engine attached means no profiler (501).
 """
 
 from __future__ import annotations
 
+import contextlib
 import os
 import shutil
 import tempfile
@@ -34,7 +37,8 @@ SANDBOX_PROFILE_DIR = "/workspace/.bci-profile"
 
 
 class ProfilerUnavailable(RuntimeError):
-    """jax (or its profiler backend) is not importable/usable here."""
+    """No engine to trace through, its profiler backend refused to start,
+    or a capture is already running."""
 
 
 def inject_profile_env(env: dict[str, str] | None) -> dict[str, str]:
@@ -56,14 +60,18 @@ def profile_artifacts(files: dict[str, str], profile_dir: str) -> list[str]:
 
 
 class ServingProfiler:
-    """Captures batcher/engine steps under ``jax.profiler``.
+    """Captures batcher/engine steps under the stepper's profiler trace.
 
-    ``stepper`` is anything with a ``step()`` method. Overlapping captures
-    are rejected internally (atomic check-and-set under a lock) —
-    ``jax.profiler`` is process-global and two concurrent traces would
-    corrupt each other, and the HTTP handler runs captures off-loop in a
-    thread pool where two requests CAN race.
+    ``stepper`` is anything with ``step()`` and ``profiler_trace(dir)`` (a
+    context manager around the capture — ``ContinuousBatcher``'s is
+    ``jax.profiler.trace``). Overlapping captures are rejected internally
+    (atomic check-and-set under a lock) — ``jax.profiler`` is
+    process-global and two concurrent traces would corrupt each other, and
+    the HTTP handler runs captures off-loop in a thread pool where two
+    requests CAN race.
     """
+
+    _dir_prefix = "bci-profile-"
 
     def __init__(self, stepper, trace_root: str | Path | None = None) -> None:
         self._stepper = stepper
@@ -87,7 +95,7 @@ class ServingProfiler:
         """Run ``steps`` stepper steps under a profiler trace; returns
         ``{trace_dir, files, steps, duration_ms}`` with ``files`` relative
         to ``trace_dir``. Raises :class:`ProfilerUnavailable` if a capture
-        is already running."""
+        is already running or the trace cannot start."""
         if steps < 1:
             raise ValueError(f"steps must be >= 1, got {steps}")
         with self._lock:
@@ -97,31 +105,24 @@ class ServingProfiler:
         # EVERY exit path below must reset the flag — a stuck True would
         # 503 all future serving captures until process restart.
         try:
-            try:
-                import jax
-            except ImportError as e:  # pragma: no cover - jax is baked in
-                raise ProfilerUnavailable(f"jax not importable: {e}") from e
             trace_dir = tempfile.mkdtemp(
-                prefix="bci-profile-", dir=self._trace_root
+                prefix=self._dir_prefix, dir=self._trace_root
             )
             t0 = time.monotonic()
-            try:
-                jax.profiler.start_trace(trace_dir)
-            except Exception as e:
-                # Nothing was captured: don't leak an empty trace dir per
-                # failed attempt on hosts without a profiler backend.
-                shutil.rmtree(trace_dir, ignore_errors=True)
-                raise ProfilerUnavailable(
-                    f"jax.profiler unavailable: {e}"
-                ) from e
-            try:
+            with contextlib.ExitStack() as stack:
+                try:
+                    stack.enter_context(
+                        self._stepper.profiler_trace(trace_dir)
+                    )
+                except Exception as e:
+                    # Nothing was captured: don't leak an empty trace dir
+                    # per failed attempt on hosts without a profiler.
+                    shutil.rmtree(trace_dir, ignore_errors=True)
+                    raise ProfilerUnavailable(
+                        f"profiler trace cannot start: {e}"
+                    ) from e
                 for _ in range(steps):
                     self._stepper.step()
-            finally:
-                try:
-                    jax.profiler.stop_trace()
-                except Exception:
-                    pass
         finally:
             self._capturing = False
         files = sorted(
@@ -137,98 +138,15 @@ class ServingProfiler:
         }
 
 
-class DeviceProfiler:
-    """``POST /v1/profile target=device``: one on-demand ``jax.profiler``
-    trace directory of raw device activity (docs/observability.md
-    "Accelerator observability").
+class DeviceProfiler(ServingProfiler):
+    """``POST /v1/profile target=device``: the same capture, kept as its
+    own route for the raw device timeline (XLA ops, transfers, compiles —
+    docs/observability.md "Accelerator observability"). It traces the
+    attached engine's steps; with no engine attached the edge answers 501,
+    because this process does not hold the device and a probe computation
+    here would take it from the sandbox that does."""
 
-    Unlike ``target=serving`` this does not REQUIRE an engine: with one
-    attached (``stepper.available``) the capture windows real batcher
-    steps; without one it runs a small probe computation so the timeline
-    is never empty — the capture is about the DEVICE runtime (XLA ops,
-    transfers, compilation), not the serving loop. Raises
-    :class:`ProfilerUnavailable` with the concrete reason (the edge's 501
-    body) when the runtime cannot trace at all.
-    """
-
-    def __init__(self, stepper=None, trace_root: str | Path | None = None) -> None:
-        self._stepper = stepper
-        self._trace_root = str(trace_root) if trace_root else None
-        self._capturing = False
-        self._lock = threading.Lock()
-
-    @property
-    def capturing(self) -> bool:
-        return self._capturing
-
-    @property
-    def available(self) -> bool:
-        """True when jax.profiler is importable here. Whether start_trace
-        actually works on this backend is only knowable by trying — the
-        capture path turns that failure into ProfilerUnavailable."""
-        try:
-            import jax.profiler  # noqa: F401
-        except Exception:
-            return False
-        return True
+    _dir_prefix = "bci-device-profile-"
 
     def capture(self, steps: int = 8) -> dict:
-        """Capture a device trace: ``steps`` engine steps when an engine is
-        attached, a probe computation otherwise. Returns the
-        ``ServingProfiler.capture`` shape plus ``source`` =
-        ``serving|probe``."""
-        if steps < 1:
-            raise ValueError(f"steps must be >= 1, got {steps}")
-        with self._lock:
-            if self._capturing:
-                raise ProfilerUnavailable("a capture is already in progress")
-            self._capturing = True
-        try:
-            try:
-                import jax
-                import jax.numpy as jnp
-            except ImportError as e:  # pragma: no cover - jax is baked in
-                raise ProfilerUnavailable(f"jax not importable: {e}") from e
-            trace_dir = tempfile.mkdtemp(
-                prefix="bci-device-profile-", dir=self._trace_root
-            )
-            t0 = time.monotonic()
-            try:
-                jax.profiler.start_trace(trace_dir)
-            except Exception as e:
-                shutil.rmtree(trace_dir, ignore_errors=True)
-                raise ProfilerUnavailable(
-                    f"jax.profiler cannot trace on this runtime: {e}"
-                ) from e
-            stepped = bool(
-                self._stepper is not None
-                and getattr(self._stepper, "available", True)
-            )
-            try:
-                if stepped:
-                    for _ in range(steps):
-                        self._stepper.step()
-                else:
-                    x = jnp.ones((256, 256), dtype=jnp.float32)
-                    for _ in range(steps):
-                        x = x @ x / 256.0
-                    x.block_until_ready()
-            finally:
-                try:
-                    jax.profiler.stop_trace()
-                except Exception:
-                    pass
-        finally:
-            self._capturing = False
-        files = sorted(
-            str(Path(root, name).relative_to(trace_dir))
-            for root, _dirs, names in os.walk(trace_dir)
-            for name in names
-        )
-        return {
-            "trace_dir": trace_dir,
-            "files": files,
-            "steps": steps,
-            "source": "serving" if stepped else "probe",
-            "duration_ms": (time.monotonic() - t0) * 1000.0,
-        }
+        return {**super().capture(steps), "source": "serving"}

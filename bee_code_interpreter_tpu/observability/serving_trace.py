@@ -243,6 +243,14 @@ class ServingMonitor:
         else:
             raise RuntimeError("no serving engine attached")
 
+    def profiler_trace(self, trace_dir: str):
+        """The attached batcher's profiler trace (a context manager) —
+        the other half of the stepper surface; the batcher owns the jax
+        import."""
+        if self._batcher is None:
+            raise RuntimeError("no serving engine attached")
+        return self._batcher.profiler_trace(trace_dir)
+
     # ----------------------------------------------------- gauge callbacks
 
     def spec_accept_ratio(self) -> float:

@@ -12,8 +12,10 @@ layout XLA's collectives want.
 from __future__ import annotations
 
 import dataclasses
+import json
 import math
 import os
+import sys
 
 import jax
 import numpy as np
@@ -31,20 +33,8 @@ def initialize_distributed() -> bool:
     # Idempotency must NOT be probed via jax.process_count(): that call
     # initializes the XLA backend, after which jax.distributed.initialize
     # refuses to run at all (caught by tests/test_multihost_distributed.py).
-    # is_initialized() checks the coordination client without touching XLA —
-    # but only newer jax exposes it publicly; otherwise probe the internal
-    # coordination state the same way is_initialized() does.
-    is_initialized = getattr(jax.distributed, "is_initialized", None)
-    if is_initialized is not None:
-        initialized = is_initialized()
-    else:
-        try:
-            from jax._src.distributed import global_state
-
-            initialized = global_state.client is not None
-        except Exception:
-            initialized = False
-    if initialized:
+    # is_initialized() checks the coordination client without touching XLA.
+    if jax.distributed.is_initialized():
         return True
     jax.distributed.initialize(
         coordinator_address=os.environ["JAX_COORDINATOR_ADDRESS"],
@@ -107,51 +97,6 @@ def auto_mesh(n_devices: int | None = None, *, sp: int = 1) -> Mesh:
             break
     dp = rest // tp
     return make_mesh({"dp": dp, "sp": sp, "tp": tp})
-
-
-def axis_size_compat(axis_name: str) -> int:
-    """Static size of a named mesh axis from inside ``shard_map`` across
-    jax versions: new jax has ``lax.axis_size``; on 0.4.x ``psum(1, axis)``
-    constant-folds to the same static int."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
-
-def pcast_compat(x, axes, *, to="varying"):
-    """``lax.pcast`` across jax versions: marks a value varying over mesh
-    axes for the vma type system. 0.4.x has no vma typing (and
-    ``shard_map_compat`` runs it with the replication check off), so the
-    cast is the identity there."""
-    from jax import lax
-
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to=to)
-    return x
-
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """``jax.shard_map`` across jax versions: new jax exposes it at the top
-    level with ``check_vma``; 0.4.x spells it ``jax.experimental.shard_map
-    .shard_map``. Every shard_map call in models/ and parallel/ routes
-    through here. On 0.4.x the replication checker (``check_rep``) predates
-    vma typing and rejects valid ``lax.cond`` bodies (the ring/pipeline
-    hop-skipping pattern) with "mismatched replication types", so the
-    legacy path always disables it — ``check_vma`` only reaches a backend
-    that can actually honor it."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
-    )
 
 
 def sharding(mesh: Mesh, *spec) -> NamedSharding:
@@ -230,3 +175,91 @@ def mesh_descriptor(mesh: Mesh | None) -> dict:
         "coords": coords,
         "platform": local[0].platform if local else "unknown",
     }
+
+
+def device_memory_rows(devices) -> list[dict]:
+    """One memory row per device for telemetry (``observability
+    .DeviceMonitor`` samples through the attached batcher's
+    ``device_memory``, so the control plane never imports jax):
+    ``memory_stats()`` where the backend reports it (TPU), else a
+    live-buffer byte estimate (CPU — rows marked ``estimated``, peak and
+    limit unknown to this function)."""
+    def key_of(device) -> str:
+        return f"{device.platform}:{device.id}"
+
+    rows: list[dict] = []
+    live_estimate: dict[str, int] | None = None
+    for device in devices:
+        key = key_of(device)
+        stats = device.memory_stats()
+        if stats:
+            live = int(stats.get("bytes_in_use", 0))
+            rows.append(
+                {
+                    "device": key,
+                    "platform": device.platform,
+                    "live_bytes": live,
+                    "peak_bytes": int(stats.get("peak_bytes_in_use", live)),
+                    "limit_bytes": (
+                        int(stats["bytes_limit"])
+                        if "bytes_limit" in stats
+                        else None
+                    ),
+                    "estimated": False,
+                }
+            )
+            continue
+        if live_estimate is None:
+            live_estimate = {}
+            for arr in jax.live_arrays():
+                arr_devices = list(arr.devices())
+                # a sharded array's nbytes is the GLOBAL size: spread it
+                # evenly over its devices for the per-device view
+                per_device = int(arr.nbytes) // max(1, len(arr_devices))
+                for arr_device in arr_devices:
+                    dk = key_of(arr_device)
+                    live_estimate[dk] = live_estimate.get(dk, 0) + per_device
+        live = live_estimate.get(key, 0)
+        rows.append(
+            {
+                "device": key,
+                "platform": device.platform,
+                "live_bytes": live,
+                "peak_bytes": live,
+                "limit_bytes": None,
+                "estimated": True,
+            }
+        )
+    return rows
+
+
+def require_tpu(script: str):
+    """How a one-process chip-facing script starts: a measurement path that
+    finds no chip fails, it never falls back to the CPU. Exit 2 unless this
+    process's jax backend is a TPU; return ``emit(case, payload)``, which
+    prints one JSON line per case stamped with the device (platform, kind,
+    count) the process runs on. The caller IS the process that holds the
+    chip — no out-of-process probe."""
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        print(
+            f"{script}: no TPU (jax backend is {devices[0].platform!r}); "
+            "a device measurement does not run on the host",
+            file=sys.stderr,
+        )
+        sys.exit(2)
+    device = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+    def emit(case: str, payload: dict) -> None:
+        print(
+            json.dumps(
+                {"case": case, "script": script, "device": device, **payload}
+            ),
+            flush=True,
+        )
+
+    return emit

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from bee_code_interpreter_tpu.parallel.mesh import pcast_compat
-
 
 def spmd_pipeline(
     stage_fn: Callable,
@@ -71,15 +69,10 @@ def spmd_pipeline(
     xm = x.reshape(M, mb, *x.shape[1:])
 
     def scalar_zero(ref):
-        # Scalar f32 zero for the aux accumulators. Under vma typing it must
-        # be data-derived (plain constants are unvarying and scan/fori reject
-        # the carry); on 0.4.x it must be a PLAIN constant — a data-derived
-        # scalar is computed in grad's known sub-jaxpr and crosses into the
-        # staged one as a float32[] residual whose {0: axes} name the legacy
-        # transpose cannot check (no dim 0 to map).
-        if hasattr(jax, "shard_map"):
-            return (ref.reshape(-1)[0] * 0.0).astype(jnp.float32)
-        return jnp.float32(0.0)
+        # Scalar f32 zero for the aux accumulators: data-derived, because
+        # under vma typing a plain constant is unvarying and scan/fori
+        # reject the carry.
+        return (ref.reshape(-1)[0] * 0.0).astype(jnp.float32)
 
     def per_rank(local_params, xm):
         # local_params: [n_layers/S, ...] (this rank's layer block)
@@ -126,9 +119,9 @@ def spmd_pipeline(
 
         # the loop body produces pp-varying values (axis_index branches), so
         # the initial carry must be marked varying too or scan rejects it
-        state0 = pcast_compat(jnp.zeros_like(xm[0]), (axis,), to="varying")
-        outputs0 = pcast_compat(jnp.zeros_like(xm), (axis,), to="varying")
-        aux0 = pcast_compat(scalar_zero(xm), (axis,), to="varying")
+        state0 = lax.pcast(jnp.zeros_like(xm[0]), (axis,), to="varying")
+        outputs0 = lax.pcast(jnp.zeros_like(xm), (axis,), to="varying")
+        aux0 = lax.pcast(scalar_zero(xm), (axis,), to="varying")
         _, outputs, aux_acc = lax.fori_loop(
             0, M + S - 1, tick, (state0, outputs0, aux0)
         )
@@ -143,35 +136,13 @@ def spmd_pipeline(
         )
         return out, aux
 
-    from bee_code_interpreter_tpu.parallel.mesh import shard_map_compat
-
     batch = batch_axes or None
-    if hasattr(jax, "shard_map"):
-        fn = shard_map_compat(
-            per_rank,
-            mesh=mesh,
-            in_specs=(P(axis), P(None, batch)),
-            out_specs=(P(None, batch), P()),
-        )
-        out, aux = fn(layer_params, xm)
-    else:
-        # 0.4.x shard_map cannot transpose (grad through) UNMAPPED
-        # out_specs with the replication checker off: give each output a
-        # leading pp-mapped dim instead — every rank returns the identical
-        # psum'd value, the global array stacks S copies, and row 0 is the
-        # answer. Same numerics, grad-safe on the legacy tracer.
-        def per_rank_stacked(layer_params, xm):
-            out, aux = per_rank(layer_params, xm)
-            return out[None], aux[None]
-
-        fn = shard_map_compat(
-            per_rank_stacked,
-            mesh=mesh,
-            in_specs=(P(axis), P(None, batch)),
-            out_specs=(P(axis, None, batch), P(axis)),
-        )
-        out, aux = fn(layer_params, xm)
-        out, aux = out[0], aux[0]
+    out, aux = jax.shard_map(
+        per_rank,
+        mesh=mesh,
+        in_specs=(P(axis), P(None, batch)),
+        out_specs=(P(None, batch), P()),
+    )(layer_params, xm)
     out = out.reshape(B, *x.shape[1:])
     if with_aux:
         return out, aux

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from bee_code_interpreter_tpu.parallel.mesh import axis_size_compat
 
 
 def _block_attend(q, k, v, m, l, o, sm_scale, mask):
@@ -116,7 +115,7 @@ def ring_attention(
     Lk = k.shape[2]
     sm_scale = sm_scale if sm_scale is not None else D ** -0.5
 
-    n = axis_size_compat(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
 
     qf = q.astype(jnp.float32).reshape(B, KVH, H // KVH, Lq, D)
@@ -223,7 +222,7 @@ def _ring_attention_flash(
     B, H, Lq, D = q.shape
     KVH = k.shape[1]
     Lk = k.shape[2]
-    n = axis_size_compat(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     scale = sm_scale if sm_scale is not None else D ** -0.5
 
@@ -348,9 +347,7 @@ def ring_attention_sharded(
     from bee_code_interpreter_tpu.ops.flash_attention import uses_flash
 
     flash = use_flash if use_flash is not None else uses_flash()
-    from bee_code_interpreter_tpu.parallel.mesh import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(
             ring_attention, axis_name=axis_name, causal=causal,
             sm_scale=sm_scale, use_flash=use_flash, window=window,

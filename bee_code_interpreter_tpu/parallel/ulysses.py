@@ -36,7 +36,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from bee_code_interpreter_tpu.parallel.mesh import axis_size_compat
 
 
 def ulysses_attention(
@@ -97,7 +96,7 @@ def ulysses_attention(
             "window/use_flash with a custom local_attention: fold them into "
             "the callable instead (the default dispatch handles them)"
         )
-    sp = axis_size_compat(axis_name)
+    sp = lax.axis_size(axis_name)
     B, H, Lloc, D = q.shape
     KVH = k.shape[1]
     if H % sp != 0:
@@ -150,9 +149,7 @@ def ulysses_attention_sharded(
 
     flash = use_flash if use_flash is not None else uses_flash()
     spec = P(None, None, axis_name, None)
-    from bee_code_interpreter_tpu.parallel.mesh import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(
             ulysses_attention, axis_name=axis_name, causal=causal,
             window=window, use_flash=use_flash,

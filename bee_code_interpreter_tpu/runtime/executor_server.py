@@ -55,7 +55,11 @@ from bee_code_interpreter_tpu.utils.request_id import (
 logger = logging.getLogger(__name__)
 
 
-def create_app(core: ExecutorCore, tracer: Tracer | None = None) -> web.Application:
+def create_app(
+    core: ExecutorCore,
+    tracer: Tracer | None = None,
+    warm_error: str | None = None,
+) -> web.Application:
     app = web.Application(client_max_size=1 << 30)
     # Pod-local retention only: the edge's store is the one an operator
     # queries; this one exists so in-pod spans/logs still correlate when a
@@ -220,7 +224,12 @@ def create_app(core: ExecutorCore, tracer: Tracer | None = None) -> web.Applicat
                 )
 
     async def healthz(_request: web.Request) -> web.Response:
-        return web.json_response({"status": "ok", "workspace": str(core.workspace)})
+        body = {"status": "ok", "workspace": str(core.workspace)}
+        if warm_error:
+            # the startup accelerator warm-up failed (ExecutorCore.warmup):
+            # the pod serves, but says what it could not reach
+            body["warm_error"] = warm_error
+        return web.json_response(body)
 
     app.router.add_put("/workspace/{path:.+}", upload_file)
     app.router.add_get("/workspace/{path:.+}", download_file)
@@ -270,9 +279,14 @@ def main() -> None:
     core = core_from_env()
     listen = os.environ.get("APP_LISTEN_ADDR", "0.0.0.0:8000")
     host, _, port = listen.rpartition(":")
+    warm_error = None
     if os.environ.get("APP_WARMUP", "") == "1":
-        asyncio.run(core.warmup())
-    web.run_app(create_app(core), host=host or "0.0.0.0", port=int(port))
+        warm_error = asyncio.run(core.warmup())
+    web.run_app(
+        create_app(core, warm_error=warm_error),
+        host=host or "0.0.0.0",
+        port=int(port),
+    )
 
 
 if __name__ == "__main__":

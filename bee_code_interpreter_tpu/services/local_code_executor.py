@@ -24,6 +24,7 @@ from bee_code_interpreter_tpu.resilience import Deadline
 from bee_code_interpreter_tpu.runtime.executor_core import ExecutorCore
 from bee_code_interpreter_tpu.services.code_executor import Result
 from bee_code_interpreter_tpu.services.storage import Storage
+from bee_code_interpreter_tpu.utils.jaxcache import CHECKOUT_CACHE_DIR
 from bee_code_interpreter_tpu.utils.validation import AbsolutePath, Hash
 
 logger = logging.getLogger(__name__)
@@ -270,4 +271,8 @@ class LocalCodeExecutor:
             default_timeout_s=self._execution_timeout_s,
             shim_dir=self._shim_dir,
             installed_cache=self._installed_cache,
+            # this backend runs from the checkout: sandboxes share its
+            # fixed compile-cache directory unless the environment or the
+            # operator (APP_JAX_CACHE_DIR) names another
+            jax_cache_dir=CHECKOUT_CACHE_DIR,
         )

@@ -53,6 +53,7 @@ from bee_code_interpreter_tpu.resilience import (
 from bee_code_interpreter_tpu.services.code_executor import LeaseHandle, Result
 from bee_code_interpreter_tpu.services.executor_http_driver import ExecutorHttpDriver
 from bee_code_interpreter_tpu.services.storage import Storage
+from bee_code_interpreter_tpu.utils.jaxcache import CHECKOUT_CACHE_DIR
 from bee_code_interpreter_tpu.utils.validation import AbsolutePath, Hash
 
 logger = logging.getLogger(__name__)
@@ -90,6 +91,8 @@ class NativeSandbox:
     # preloading: the server gates the execute internally, so the preload
     # tail counts against the HTTP request and needs timeout headroom.
     overlap_dispatch: bool = False
+    # /healthz "warm_error": why the server's warm-up or a preload failed
+    warm_error: str = ""
 
     def destroy(self) -> None:
         if self.proc.poll() is None:
@@ -643,15 +646,12 @@ class NativeProcessCodeExecutor(ExecutorHttpDriver):
         shim = cfg.resolved_shim_dir()
         if shim:
             env["APP_SHIM_DIR"] = str(shim)
-        if cfg.jax_cache_dir:
-            env["APP_JAX_CACHE_DIR"] = cfg.jax_cache_dir
+        # Sandboxes on this host share one compile cache: the operator's
+        # directory, else the checkout's fixed one (the server still lets an
+        # inherited JAX_COMPILATION_CACHE_DIR win).
+        env["APP_JAX_CACHE_DIR"] = cfg.jax_cache_dir or CHECKOUT_CACHE_DIR
         env["APP_DIE_WITH_PARENT"] = "1"  # server watches us via PDEATHSIG+ppid
         env["APP_PARENT_PID"] = str(os.getpid())
-        # Hermetic-mode scrub prefixes: envscrub.py is the single source of
-        # truth; the C++ server's built-in list is only a fallback.
-        from bee_code_interpreter_tpu.utils.envscrub import TUNNEL_PLUGIN_PREFIXES
-
-        env["APP_SCRUB_PREFIXES"] = ",".join(TUNNEL_PLUGIN_PREFIXES)
         stdlib_file = await self._stdlib_file()
         if stdlib_file:
             env["APP_STDLIB_FILE"] = stdlib_file
@@ -726,6 +726,16 @@ class NativeProcessCodeExecutor(ExecutorHttpDriver):
                 try:
                     response = await self._http.get(f"http://{addr}/healthz")
                     if response.status_code == 200:
+                        health = response.json()
+                        warm_error = health.get("warm_error")
+                        if warm_error and not box.warm_error:
+                            # the server's warm-up or a preload failed (e.g.
+                            # the chip is held by another process): the
+                            # sandbox still serves, so say it here too
+                            box.warm_error = warm_error
+                            logger.warning(
+                                "Sandbox %s warm-up failed: %s", name, warm_error
+                            )
                         # Best-effort: hold the sandbox back until its warm
                         # worker finished preloading, so requests never pay
                         # the preload wait. A slow preload (up to 15 s past
@@ -736,7 +746,7 @@ class NativeProcessCodeExecutor(ExecutorHttpDriver):
                             return self._spawned_ready(box)
                         if warm_deadline is None:
                             warm_deadline = min(loop.time() + 15.0, deadline)
-                        if response.json().get("warm", True):
+                        if health.get("warm", True):
                             return self._spawned_ready(box)
                         if loop.time() > warm_deadline:
                             return self._spawned_ready(box)

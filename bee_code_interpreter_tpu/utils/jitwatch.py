@@ -13,9 +13,7 @@ function's first compile or a retrace.
 Detection is cheap by design: jax's jit wrapper exposes ``_cache_size()``
 (the number of compiled executables it holds), so the hot path pays two
 integer probes and one clock read per call — the human-readable signature
-is only computed on the rare call that actually compiled. When the probe
-is missing (older/newer jax), the wrapper falls back to hashing the
-abstract signature of every call, which is slower but exact.
+is only computed on the rare call that actually compiled.
 
 This module is stdlib-only (the arrays are duck-typed via
 ``shape``/``dtype``/``nbytes``) so it imports anywhere ``utils.metrics``
@@ -89,15 +87,12 @@ class TrackedJit:
     through to the wrapped jit, so AOT-lowering call sites keep working.
     """
 
-    __slots__ = ("fn", "name", "_get_monitor", "_signatures")
+    __slots__ = ("fn", "name", "_get_monitor")
 
     def __init__(self, fn, name: str, get_monitor: Callable) -> None:
         self.fn = fn
         self.name = name
         self._get_monitor = get_monitor
-        # fallback dedupe set, used only when the jit exposes no
-        # _cache_size probe (then every call pays a signature render)
-        self._signatures: set[str] = set()
 
     def __getattr__(self, item):
         return getattr(self.fn, item)
@@ -106,22 +101,14 @@ class TrackedJit:
         monitor = self._get_monitor()
         if monitor is None:
             return self.fn(*args, **kwargs)
-        probe = getattr(self.fn, "_cache_size", None)
-        before = probe() if probe is not None else None
+        before = self.fn._cache_size()
         t0 = time.monotonic()
         out = self.fn(*args, **kwargs)
         duration_ms = (time.monotonic() - t0) * 1000.0
-        if probe is not None:
-            if probe() <= before:
-                return out
-            trigger = "first_call" if before == 0 else "retrace"
-            signature = abstract_signature(args, kwargs)
-        else:
-            signature = abstract_signature(args, kwargs)
-            if signature in self._signatures:
-                return out
-            trigger = "first_call" if not self._signatures else "retrace"
-            self._signatures.add(signature)
+        if self.fn._cache_size() <= before:
+            return out
+        trigger = "first_call" if before == 0 else "retrace"
+        signature = abstract_signature(args, kwargs)
         # duration includes the (comparatively negligible) dispatch of the
         # freshly compiled executable — it IS the stall the caller felt
         monitor.on_compile(

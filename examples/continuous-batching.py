@@ -5,7 +5,10 @@
 # requests alongside cannot change an answer.
 #
 # f32 so the equality assert is trustworthy (same reasoning as
-# speculative-decode.py: bf16 near-tie argmax flips are rounding noise).
+# speculative-decode.py: bf16 near-tie argmax flips are rounding noise) —
+# and at "highest" matmul precision, because the assert compares two
+# DIFFERENT programs (generate_cached vs the paged batcher) and a TPU runs
+# f32 matmuls at reduced precision by default.
 import dataclasses
 import time
 
@@ -16,7 +19,12 @@ import numpy as np
 from bee_code_interpreter_tpu.models import transformer as T
 from bee_code_interpreter_tpu.models.serving import ContinuousBatcher
 
-on_tpu = jax.devices()[0].platform == "tpu"
+jax.config.update("jax_default_matmul_precision", "highest")
+
+# The size follows the device the code finds — and says so: a run that
+# landed on the host CPU must never read as a run on the chip.
+device = jax.devices()[0]
+on_tpu = device.platform == "tpu"
 config = dataclasses.replace(
     T.TransformerConfig(
         vocab_size=32000, d_model=1024, n_layers=8, n_heads=16,
@@ -24,6 +32,9 @@ config = dataclasses.replace(
     ) if on_tpu else T.TransformerConfig.tiny(),
     dtype=jnp.float32,
 )
+print(f"platform={device.platform} device_kind={device.device_kind!r} "
+      f"devices={len(jax.devices())} model=d{config.d_model}x"
+      f"{config.n_layers}L ({'1024-wide' if on_tpu else 'tiny: no TPU found'})")
 params = T.init_params(config, jax.random.PRNGKey(0))
 model = T.Transformer(config)
 

@@ -3,10 +3,11 @@
 # target's greedy decode (the draft only changes how many target forwards
 # run). Uses the bundled models/speculative.py.
 #
-# f32 everywhere: the equality check compares the window forward against
-# single-step decode, whose logits agree only up to rounding — at bf16 a
-# near-tied argmax can flip, which is rounding noise, not a speculation
-# bug. f32 margins dwarf that rounding, making the assert trustworthy.
+# f32 everywhere, at "highest" matmul precision: the equality check compares
+# the window forward against single-step decode, whose logits agree only up
+# to rounding — at bf16 (or a TPU's default reduced-precision f32 matmul) a
+# near-tied argmax can flip, which is rounding noise, not a speculation bug.
+# f32 margins dwarf that rounding, making the assert trustworthy.
 import dataclasses
 import time
 
@@ -15,7 +16,12 @@ import jax
 from bee_code_interpreter_tpu.models import transformer as T
 from bee_code_interpreter_tpu.models import speculative_generate
 
-on_tpu = jax.devices()[0].platform == "tpu"
+jax.config.update("jax_default_matmul_precision", "highest")
+
+# The size follows the device the code finds — and says so: a run that
+# landed on the host CPU must never read as a run on the chip.
+device = jax.devices()[0]
+on_tpu = device.platform == "tpu"
 config = dataclasses.replace(
     T.TransformerConfig(
         vocab_size=32000, d_model=1024, n_layers=8, n_heads=16,
@@ -23,6 +29,9 @@ config = dataclasses.replace(
     ) if on_tpu else T.TransformerConfig.tiny(),
     dtype=jax.numpy.float32,
 )
+print(f"platform={device.platform} device_kind={device.device_kind!r} "
+      f"model=d{config.d_model}x{config.n_layers}L "
+      f"({'1024-wide' if on_tpu else 'tiny: no TPU found'})")
 draft_config = dataclasses.replace(config, n_layers=1)
 
 params = T.init_params(config, jax.random.PRNGKey(0))

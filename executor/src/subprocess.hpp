@@ -17,6 +17,7 @@
 #include <algorithm>
 #include <chrono>
 #include <cstring>
+#include <functional>
 #include <map>
 #include <string>
 #include <thread>
@@ -67,8 +68,10 @@ struct Child {
 // Waits for a specific status byte on the pipe, skipping earlier protocol
 // bytes (the warm worker writes 'P' at preload-done, then 'S' right before
 // user code runs; a caller waiting for 'S' must tolerate an unconsumed 'P').
-// expected == 0 accepts any byte.
-inline bool wait_for_status_byte(int fd, double timeout_s, char expected = 0) {
+// expected == 0 accepts any byte. Skipped bytes are appended to *skipped when
+// given (the preload's failure records travel ahead of 'S').
+inline bool wait_for_status_byte(int fd, double timeout_s, char expected = 0,
+                                 std::string* skipped = nullptr) {
   if (fd < 0) return false;
   auto deadline = std::chrono::steady_clock::now() +
                   std::chrono::duration<double>(timeout_s);
@@ -85,6 +88,7 @@ inline bool wait_for_status_byte(int fd, double timeout_s, char expected = 0) {
       ssize_t n = read(fd, &b, 1);
       if (n == 1) {
         if (expected == 0 || b == expected) return true;
+        if (skipped) skipped->push_back(b);
         continue;  // earlier protocol byte; keep draining
       }
       if (n == 0) return false;  // EOF: writer exited silently
@@ -242,9 +246,16 @@ inline RunResult collect(Child child, double timeout_s) {
 // finalization (~100 ms with a scientific stack loaded — measured as the
 // whole warm-path latency floor). The zombie is reaped on a detached thread.
 // Falls back to a blocking reap when the worker dies without reporting
-// (crash/signal/user closed fd 3).
-inline RunResult collect_warm(Child child, double timeout_s) {
-  if (!child.valid()) return {"", "spawn failed", -1, false};
+// (crash/signal/user closed fd 3). on_exit runs once the worker has been
+// reaped, on whichever thread did it — the hook for work that must not
+// overlap the worker's life (it may still hold the accelerator while it
+// finalizes).
+inline RunResult collect_warm(Child child, double timeout_s,
+                              std::function<void()> on_exit = [] {}) {
+  if (!child.valid()) {
+    on_exit();
+    return {"", "spawn failed", -1, false};
+  }
   if (child.stdin_fd >= 0) { close(child.stdin_fd); child.stdin_fd = -1; }
   int out_pipe0 = child.out_fd, err_pipe0 = child.err_fd;
   pid_t pid = child.pid;
@@ -287,6 +298,7 @@ inline RunResult collect_warm(Child child, double timeout_s) {
     if (child.status_fd >= 0) { close(child.status_fd); child.status_fd = -1; }
     int status = 0;
     waitpid(pid, &status, 0);
+    on_exit();
     result.out.clear();
     result.err = kTimeoutMessage;
     result.exit_code = -1;
@@ -319,9 +331,10 @@ inline RunResult collect_warm(Child child, double timeout_s) {
   }
   if (got_code) {
     result.exit_code = atoi(line.c_str() + 1);
-    std::thread([pid] {
+    std::thread([pid, on_exit] {
       int status = 0;
       waitpid(pid, &status, 0);
+      on_exit();
     }).detach();
   } else {
     // No report: crashed worker (already dead — kill is a no-op) or stdio
@@ -331,6 +344,7 @@ inline RunResult collect_warm(Child child, double timeout_s) {
     kill(-pid, SIGKILL);
     int status = 0;
     waitpid(pid, &status, 0);
+    on_exit();
     if (deadline_hit) {
       result.out.clear();
       result.err = kTimeoutMessage;

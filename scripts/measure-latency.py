@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Measure /v1/execute latency percentiles (BASELINE.md north-star #3).
+"""Measure /v1/execute latency percentiles (BASELINE.json metric: /v1/execute p50).
 
 Drives the trivial health-check payload (``print(21 * 2)``) through two
 execution backends and reports p50/p95/p99 PER STAGE (spawn/upload/execute/

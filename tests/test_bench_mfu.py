@@ -53,3 +53,21 @@ def test_tiny_payload_runs_end_to_end():
     assert n_params > tiny["vocab_size"] * tiny["d_model"]
     per_tok_ms, tps = results["RESULT_DECODE"]
     assert per_tok_ms > 0 and tps > 0
+
+
+def test_peak_comes_from_one_table_keyed_by_device_kind():
+    import pytest
+
+    results = {"RESULT_TRAIN": [100.0, 98.5, 8e8], "RESULT_DECODE": [2.0, 4000.0]}
+    v5e = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    train, decode = bench_mfu.results_rows(v5e, results)
+    assert train["peak_flops"] == 197e12 and train["mfu"] == 0.5
+    assert train["device"] == decode["device"] == v5e
+    # an unknown kind is an error, never a default peak
+    with pytest.raises(KeyError, match="no published bf16 peak"):
+        bench_mfu.results_rows({**v5e, "kind": "TPU v99"}, results)
+    # and a host run is not an MFU at all
+    with pytest.raises(RuntimeError, match="not a TPU"):
+        bench_mfu.results_rows(
+            {"platform": "cpu", "kind": "cpu", "count": 1}, results
+        )

@@ -175,27 +175,49 @@ def test_compile_without_ambient_trace_has_no_trace_id():
 
 
 def test_cpu_memory_degradation_snapshot():
-    """No memory_stats() on the CPU backend: rows come from the live-buffer
-    estimate, marked estimated, peak is a running max, limit unknown."""
-    monitor = DeviceMonitor(metrics=Registry())
-    keep = jnp.ones((256, 256), jnp.float32)  # a buffer the walk must see
+    """No memory_stats() on the CPU backend: rows come from the attached
+    batcher's live-buffer estimate, marked estimated, peak is a running
+    max, limit unknown."""
+    engine, monitor, *_ = monitored_stack()
     rows = monitor.sample_memory()
     assert rows, "no devices visible"
     assert all(r["estimated"] for r in rows)
     assert all(r["limit_bytes"] is None for r in rows)
-    assert sum(r["live_bytes"] for r in rows) >= keep.nbytes
+    pool_bytes = sum(x.nbytes for x in engine.batcher.cache.values())
+    assert sum(r["live_bytes"] for r in rows) >= pool_bytes
 
     snap = monitor.snapshot()
-    assert snap["attached"] is False
+    assert snap["attached"] is True
     assert snap["memory"]["estimated"] is True
-    assert snap["memory"]["samples"] >= 2  # constructor takes an eager one
-    assert snap["kv_pool"] is None
-    assert snap["mesh"] is None
+    assert snap["memory"]["reason"] is None
+    assert snap["memory"]["samples"] >= 2  # attach takes an eager one
 
     fleet = monitor.fleet_summary()
     assert fleet["hbm"]["estimated"] is True
     assert fleet["hbm"]["limit_bytes"] is None
-    assert fleet["hbm"]["live_bytes"] >= keep.nbytes
+    assert fleet["hbm"]["live_bytes"] >= pool_bytes
+
+
+def test_unattached_monitor_reports_no_engine_and_no_memory():
+    """A process with no engine attached does not hold the device: no
+    memory rows, no HBM gauges, and the snapshot says why — never a CPU
+    estimate presented as the accelerator's memory."""
+    metrics = Registry()
+    monitor = DeviceMonitor(metrics=metrics)
+    assert monitor.sample_memory() == []
+    snap = monitor.snapshot()
+    assert snap["attached"] is False
+    assert snap["memory"]["devices"] == []
+    assert snap["memory"]["samples"] == 0
+    assert snap["memory"]["estimated"] is None
+    assert "no in-process engine" in snap["memory"]["reason"]
+    assert snap["kv_pool"] is None and snap["mesh"] is None
+    assert "bci_device_hbm_bytes" not in metrics.expose()
+
+    fleet = monitor.fleet_summary()
+    assert fleet["hbm"] == {
+        "live_bytes": 0, "limit_bytes": None, "estimated": None,
+    }
     assert fleet["mesh"] is None and fleet["compiles"] == 0
 
 
@@ -319,7 +341,8 @@ async def test_http_accelerator_endpoint(local_executor):
             "attached", "compile", "kv_pool", "memory", "mesh", "steps",
         ]
         assert snap["compile"]["total"] == 1
-        assert snap["memory"]["devices"], "memory sample missing"
+        assert snap["memory"]["devices"] == []  # no engine attached
+        assert "no in-process engine" in snap["memory"]["reason"]
         trimmed = await (
             await client.get("/v1/accelerator", params={"recent": "0"})
         ).json()
@@ -345,29 +368,49 @@ async def test_http_accelerator_unwired_and_fleet_summary(local_executor):
         fleet = await (await client.get("/v1/fleet")).json()
         accel = fleet["accelerator"]
         assert accel["compiles"] == 0
-        assert accel["hbm"]["estimated"] is True
+        assert accel["hbm"]["estimated"] is None  # no engine: no rows
 
     await with_client(app, go_fleet)
 
 
 async def test_http_device_profile(local_executor, tmp_path):
-    profiler = DeviceProfiler(trace_root=tmp_path)
+    """With an engine attached the device target traces its steps; the
+    trace is the batcher's own (jax.profiler), handed in through the
+    serving monitor."""
+    engine, _device, serving, *_ = monitored_stack()
+    ticket = engine.submit([1, 2, 3], 4)
+    profiler = DeviceProfiler(serving, trace_root=tmp_path)
     app = make_app(local_executor, device_profiler=profiler)
 
     async def go(client):
         resp = await client.post(
             "/v1/profile", json={"target": "device", "steps": 2}
         )
-        if resp.status == 501:
-            # backends without a working jax.profiler degrade to the
-            # documented 501 + reason; CPU normally captures fine
-            assert "detail" in await resp.json()
-            return
-        assert resp.status == 200
+        assert resp.status == 200, await resp.text()
         body = await resp.json()
         assert body["target"] == "device"
-        assert body["source"] == "probe"  # no engine attached
+        assert body["source"] == "serving"
         assert body["steps"] == 2 and body["duration_ms"] >= 0
+        assert body["trace_dir"].startswith(str(tmp_path))
+        assert body["files"], "no profiler artifacts captured"
+
+    await with_client(app, go)
+    engine.run_to_completion()
+    assert len(engine.result(ticket)) == 4
+
+
+async def test_http_device_profile_without_engine_is_501(local_executor):
+    """No engine attached: 501 with the reason, and no probe computation —
+    this process must not take the device from the sandbox that owns it."""
+    profiler = DeviceProfiler(ServingMonitor(metrics=Registry()))
+    app = make_app(local_executor, device_profiler=profiler)
+
+    async def go(client):
+        resp = await client.post(
+            "/v1/profile", json={"target": "device", "steps": 2}
+        )
+        assert resp.status == 501
+        assert "no in-process engine" in (await resp.json())["detail"]
 
     await with_client(app, go)
 

@@ -305,7 +305,11 @@ def test_inject_profile_env_defaults_and_respects_caller():
 
 
 def test_serving_profiler_captures_steps(tmp_path):
+    import jax.profiler
+
     class Stepper:
+        profiler_trace = staticmethod(jax.profiler.trace)
+
         def __init__(self):
             self.steps = 0
 
@@ -339,7 +343,12 @@ def test_serving_profiler_rejects_overlapping_captures(tmp_path):
 
     started = threading.Event()
 
+    import contextlib
+
     class SlowStepper:
+        def profiler_trace(self, trace_dir):
+            return contextlib.nullcontext()
+
         def step(self):
             started.set()
             time.sleep(0.3)

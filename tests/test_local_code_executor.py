@@ -47,7 +47,7 @@ async def test_binary_file_roundtrip(local_executor: LocalCodeExecutor):
 
 
 async def test_mnist_dp_8chip_example_end_to_end(storage, tmp_path):
-    # BASELINE.md north-star #2: the 8-chip data-parallel MNIST training job
+    # BASELINE.json north star: the 8-chip data-parallel MNIST training job
     # submitted through the execution path completes end-to-end. Runs the
     # actual example payload on 8 virtual CPU devices (SURVEY.md §4's
     # simulated multi-chip strategy); on a real pod the same payload lands on

@@ -87,12 +87,28 @@ def register_router_metrics(registry: Registry) -> None:
 
 def register_device_metrics(registry: Registry) -> None:
     """The accelerator plane (ISSUE 20): DeviceMonitor registers the
-    compile/step families at construction, and its eager memory sample
-    creates the per-device ``bci_device_hbm_bytes`` gauge series — no
-    batcher attachment needed."""
+    compile/step families at construction; the per-device
+    ``bci_device_hbm_bytes`` gauge series appear with the first memory
+    sample, which needs an attached batcher (the control plane alone never
+    touches the device) — a stub handing in one row stands in for it."""
     from bee_code_interpreter_tpu.observability.device import DeviceMonitor
 
-    DeviceMonitor(metrics=registry)
+    class StubBatcher:
+        mesh = None
+
+        def set_device_monitor(self, monitor) -> None:
+            pass
+
+        def device_memory(self) -> list[dict]:
+            return [
+                {"device": "cpu:0", "platform": "cpu", "live_bytes": 0,
+                 "peak_bytes": 0, "limit_bytes": None, "estimated": True}
+            ]
+
+        def kv_telemetry(self) -> dict:
+            return {}
+
+    DeviceMonitor(metrics=registry).attach(StubBatcher())
 
 
 def register_loadgen_metrics(registry: Registry) -> None:

@@ -494,8 +494,11 @@ async def test_profile_sandbox_injects_trace_dir_and_reports_artifacts(
 async def test_profile_serving_captures_engine_steps(tmp_path, local_executor):
     from bee_code_interpreter_tpu.observability import ServingProfiler
 
+    import jax.profiler
+
     class Stepper:
         steps = 0
+        profiler_trace = staticmethod(jax.profiler.trace)
 
         def step(self):
             Stepper.steps += 1

@@ -142,7 +142,7 @@ def test_ring_attention_flash_hops_window_matches_reference(window):
     # The flash-hop ring with a window: own block via the kernel's window
     # mask, full hops via the plain kernel, straddling hops via the
     # jax-level masked block — all merged on lse (interpreter mode here;
-    # scripts/validate-shardmap-pallas.py proves the Mosaic lowering).
+    # chip_smoke.py lowers the flash kernels under Mosaic on the chip).
     import functools
 
     mesh = make_mesh({"sp": 4})

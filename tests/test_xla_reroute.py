@@ -249,13 +249,15 @@ def test_uninstall_restores_numpy():
 
 
 def test_backend_init_watchdog_falls_back(monkeypatch):
-    # a backend whose init blocks (accelerator tunnel plugin) must degrade to
-    # host numpy within BCI_XLA_INIT_TIMEOUT_S, not hang the user's script
+    # a backend whose init blocks must degrade to host numpy within
+    # BCI_XLA_INIT_TIMEOUT_S, not hang the user's script — and say so
     import time
 
     import jax
 
     monkeypatch.setattr(xla_reroute, "_backend_state", None)
+    monkeypatch.setattr(xla_reroute, "_backend_platform", None)
+    monkeypatch.setattr(xla_reroute, "_backend_error", None)
     monkeypatch.setenv("BCI_XLA_INIT_TIMEOUT_S", "0.2")
     monkeypatch.setattr(jax, "devices", lambda *a, **k: time.sleep(60))
     try:
@@ -266,6 +268,9 @@ def test_backend_init_watchdog_falls_back(monkeypatch):
         assert isinstance(out, np.ndarray)
         assert elapsed < 10, elapsed
         assert xla_reroute._backend_state is False
+        status = xla_reroute.backend_status()
+        assert status["ok"] is False and status["platform"] is None
+        assert "still blocked after 0.2s" in status["error"]
         # sticky: later calls skip the probe entirely and stay host-side
         assert isinstance(np.matmul(host, host), np.ndarray)
     finally:
@@ -278,5 +283,32 @@ def test_backend_probe_success_is_cached(monkeypatch):
     try:
         assert xla_reroute._backend_ok() is True
         assert xla_reroute._backend_state is True
+        # the reroute says which backend it landed on (chip_smoke asserts
+        # "tpu" here; the suite runs on the CPU backend)
+        assert xla_reroute.backend_status() == {
+            "probed": True, "ok": True, "platform": "cpu", "error": None,
+        }
     finally:
         xla_reroute._backend_state = True
+
+
+def test_backend_probe_error_is_kept(monkeypatch):
+    # init that RAISES (on a chip host: the chip is held by another process)
+    # falls back to host numpy too, with the reason kept for backend_status
+    import jax
+
+    def boom(*a, **k):
+        raise RuntimeError("TPU is already in use")
+
+    monkeypatch.setattr(xla_reroute, "_backend_state", None)
+    monkeypatch.setattr(xla_reroute, "_backend_platform", None)
+    monkeypatch.setattr(xla_reroute, "_backend_error", None)
+    monkeypatch.setattr(jax, "devices", boom)
+    try:
+        assert xla_reroute._backend_ok() is False
+        status = xla_reroute.backend_status()
+        assert status["ok"] is False
+        assert "already in use" in status["error"]
+    finally:
+        monkeypatch.undo()
+        xla_reroute._backend_state = None
