@@ -60,7 +60,7 @@ import functools
 import hashlib
 import time
 from collections import OrderedDict, deque
-from contextlib import nullcontext
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass
 
 import jax
@@ -527,15 +527,11 @@ class ContinuousBatcher:
         # donate the pool: without aliasing, every decoded token would pay
         # a full page-pool HBM copy (precedent: make_train_step's donation)
         self._decode = self._track(
-            jax.jit(
-                functools.partial(
-                    decode_step_paged,
-                    config=config,
-                    lora_scale=self.lora_scale,
-                ),
-                donate_argnums=(3,),
+            functools.partial(
+                decode_step_paged, config=config, lora_scale=self.lora_scale
             ),
             "decode_step_paged",
+            donate_argnums=(3,),
         )
         # Admission prefill. With a mesh the full forward runs under it —
         # in particular an ``sp`` axis shards the attention over the
@@ -547,10 +543,8 @@ class ContinuousBatcher:
         # remains the single-chip activation-memory tool; sp admission is
         # the multi-chip one.
         self._prefill = self._track(
-            jax.jit(
-                functools.partial(
-                    forward, config=config, return_kv=True, mesh=mesh
-                )
+            functools.partial(
+                forward, config=config, return_kv=True, mesh=mesh
             ),
             "prefill_forward",
         )
@@ -558,24 +552,18 @@ class ContinuousBatcher:
         # without the jit the remainder window would dispatch op-by-op
         # eagerly on every submit
         self._prefill_chunked = self._track(
-            jax.jit(
-                functools.partial(prefill_chunked, config=config),
-                static_argnames=("total_len", "chunk"),
-            ),
+            functools.partial(prefill_chunked, config=config),
             "prefill_chunked",
+            static_argnames=("total_len", "chunk"),
         )
         # suffix-only admission windows (prefix-cache hits); compiles once
         # per page-aligned window width, bounded by max_pages_per_seq
         self._window = self._track(
-            jax.jit(
-                functools.partial(
-                    decode_window_paged,
-                    config=config,
-                    lora_scale=self.lora_scale,
-                ),
-                donate_argnums=(3,),
+            functools.partial(
+                decode_window_paged, config=config, lora_scale=self.lora_scale
             ),
             "decode_window_paged",
+            donate_argnums=(3,),
         )
         if draft_config is not None:
             # the draft's own paged pool, addressed by the SAME block
@@ -586,17 +574,13 @@ class ContinuousBatcher:
                     draft_params, draft_config, mesh
                 )
             self._draft_decode = self._track(
-                jax.jit(
-                    functools.partial(decode_step_paged, config=draft_config),
-                    donate_argnums=(3,),
-                ),
+                functools.partial(decode_step_paged, config=draft_config),
                 "draft_decode_step_paged",
+                donate_argnums=(3,),
             )
             self._draft_prefill = self._track(
-                jax.jit(
-                    functools.partial(
-                        forward, config=draft_config, return_kv=True, mesh=mesh
-                    )
+                functools.partial(
+                    forward, config=draft_config, return_kv=True, mesh=mesh
                 ),
                 "draft_prefill_forward",
             )
@@ -605,13 +589,9 @@ class ContinuousBatcher:
             # happens to equal gamma+1 reuses the compiled program
             self._verify = self._window
             self._draft_window = self._track(
-                jax.jit(
-                    functools.partial(
-                        decode_window_paged, config=draft_config
-                    ),
-                    donate_argnums=(3,),
-                ),
+                functools.partial(decode_window_paged, config=draft_config),
                 "draft_decode_window_paged",
+                donate_argnums=(3,),
             )
 
         # Serving-engine instrumentation (docs/observability.md): ``metrics``
@@ -634,6 +614,10 @@ class ContinuousBatcher:
         self._prefill_tokens = 0
         self._spec_accepted = 0
         self._spec_rejected = 0
+        self._n_steps = 0
+        # the step being recorded's phase -> ms (see _phase); a dict only
+        # inside a step with a lifecycle monitor attached, None otherwise
+        self._phase_ms: dict[str, float] | None = None
         self._t_submit: float | None = None
         if metrics is not None:
             from bee_code_interpreter_tpu.utils.metrics import (
@@ -716,12 +700,58 @@ class ContinuousBatcher:
         in flight are not traced retroactively."""
         self._monitor = monitor
 
-    def _track(self, fn, name: str) -> TrackedJit:
-        """Wrap a jit entry point so an attached device monitor sees its
-        compilations. The monitor resolves per call, so attach/detach
-        works after construction and the unmonitored path pays one None
-        check."""
-        return TrackedJit(fn, name, lambda: self._device_monitor)
+    def _track(self, fn, name: str, **jit_kwargs) -> TrackedJit:
+        """Jit ``fn`` as the program ``name`` and wrap it so an attached
+        device monitor sees its compilations. ``fn`` is a
+        ``functools.partial``, which jax would call ``_unknown``: given the
+        name, the profiler's ``XLA Modules`` line reads ``jit_<name>(...)``
+        and agrees with the compile records. The monitor resolves per call,
+        so attach/detach works after construction and the unmonitored path
+        pays one None check."""
+        fn.__name__ = name
+        return TrackedJit(
+            jax.jit(fn, **jit_kwargs), name, lambda: self._device_monitor
+        )
+
+    @contextmanager
+    def _phase(self, name: str, **stats):
+        """One ``serve.*`` span (docs/observability.md "Serving
+        observability"): always a ``jax.profiler.TraceAnnotation``, which
+        is a flag test while no profiler session is open and an event on
+        the device trace's clock while one is; and, while a monitored
+        ``step`` is recording, the span's milliseconds under the last part
+        of its name in that step's ``phase_ms``."""
+        phases = self._phase_ms
+        with jax.profiler.TraceAnnotation(name, **stats):
+            if phases is None:
+                yield
+                return
+            key = name.rpartition(".")[2]
+            phases.setdefault(key, 0.0)  # keys in the order the phases began
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                phases[key] += (time.perf_counter() - t0) * 1000.0
+
+    def _clocked(self, fn, key: str):
+        """``fn`` itself, or, while a monitored ``step`` is recording, ``fn``
+        with its calls' milliseconds summed under ``key`` in ``phase_ms``
+        (two clock reads a call; no span: a row's pick is too small a
+        thing to put on the trace)."""
+        phases = self._phase_ms
+        if phases is None:
+            return fn
+        phases[key] = 0.0
+
+        def clocked(*args):
+            t0 = time.perf_counter()
+            try:
+                return fn(*args)
+            finally:
+                phases[key] += (time.perf_counter() - t0) * 1000.0
+
+        return clocked
 
     def set_device_monitor(self, monitor) -> None:
         """Attach (or detach, with None) a compile/step telemetry monitor
@@ -1164,7 +1194,9 @@ class ContinuousBatcher:
             if self._monitor is not None
             else nullcontext()
         )
-        with admit_ctx:
+        with admit_ctx, self._phase(
+            "serve.admit", req=req, prompt_tokens=L, pages=n_need
+        ):
             return self._blocking_admit(
                 row, prompt, pages, hashes, matched, L, n_need, sampling,
                 max_new_tokens, adapter_internal, speculative,
@@ -1224,10 +1256,11 @@ class ContinuousBatcher:
             raise
         self._prefill_tokens += L - matched * self.page_size
         self._t_submit = t_submit
-        return self._activate_row(
-            row, last_row, prompt, pages, hashes, L, sampling,
-            max_new_tokens, adapter_internal, req=req, propagate=True,
-        )
+        with self._phase("serve.admit.activate"):
+            return self._activate_row(
+                row, last_row, prompt, pages, hashes, L, sampling,
+                max_new_tokens, adapter_internal, req=req, propagate=True,
+            )
 
     def _activate_row(
         self, row, last_row, prompt, pages, hashes, L, sampling,
@@ -1430,16 +1463,19 @@ class ContinuousBatcher:
         if prefill_chunk is not None:
             # bounded-memory admission: the chunked prefill builds the
             # cache in the pool's layout; copy its leaves verbatim
-            last_logits, contig = self._prefill_chunked(
-                self.params, prompt[None, :],
-                total_len=n_prompt_pages * self.page_size,
-                chunk=prefill_chunk,
-            )
-            self.cache = seed_from_contiguous(
-                self.cache, pages_arr,
-                {name: x[:, 0] for name, x in contig.items()},
-            )
-            last_row = np.asarray(last_logits[0], dtype=np.float32)
+            with self._phase("serve.admit.prefill"):
+                last_logits, contig = self._prefill_chunked(
+                    self.params, prompt[None, :],
+                    total_len=n_prompt_pages * self.page_size,
+                    chunk=prefill_chunk,
+                )
+            with self._phase("serve.admit.seed_pool"):
+                self.cache = seed_from_contiguous(
+                    self.cache, pages_arr,
+                    {name: x[:, 0] for name, x in contig.items()},
+                )
+            with self._phase("serve.admit.pull"):
+                last_row = np.asarray(last_logits[0], dtype=np.float32)
         else:
             # one-shot prefill: exact O(L^2) forward, then the shared
             # one-scatter-per-leaf page seeding (seed_prefill — the
@@ -1449,14 +1485,18 @@ class ContinuousBatcher:
             # so logits[L-1] and K/V[:L] are exact, and distinct
             # prompt lengths share a program per page count instead of
             # one per length.
-            logits, (k_pre, v_pre) = self._prefill(
-                self.params, padded[None, :]
-            )
-            self.cache = seed_prefill(
-                self.cache, pages_arr,
-                k_pre[:, 0, :, :L, :], v_pre[:, 0, :, :L, :],
-            )
-            last_row = np.asarray(logits[0, L - 1, :], dtype=np.float32)
+            with self._phase("serve.admit.prefill"):
+                logits, (k_pre, v_pre) = self._prefill(
+                    self.params, padded[None, :]
+                )
+            with self._phase("serve.admit.seed_pool"):
+                self.cache = seed_prefill(
+                    self.cache, pages_arr,
+                    k_pre[:, 0, :, :L, :], v_pre[:, 0, :, :L, :],
+                )
+            # waits for the device (prefill and seeding), then copies
+            with self._phase("serve.admit.pull"):
+                last_row = np.asarray(logits[0, L - 1, :], dtype=np.float32)
         if speculative:
             # draft prefill into ITS pool at the same pages (the draft
             # is small — the padded one-shot prefill is fine even when
@@ -1624,16 +1664,29 @@ class ContinuousBatcher:
         length in speculative mode), and the throughput window the
         tokens-per-second gauge reads. With a lifecycle monitor attached,
         each step additionally lands one step record (occupancy, token
-        counts, speculative accepts, page churn — see
-        docs/observability.md "Serving observability")."""
-        if (
-            self._metrics is None
-            and self._monitor is None
-            and self._device_monitor is None
+        counts, speculative accepts, page churn, and ``phase_ms``, the
+        step's host time by phase — see docs/observability.md "Serving
+        observability").
+
+        The step is one ``serve.step`` span with the ``serve.step.*``
+        phases inside it (``_phase``): nothing while no profiler session
+        is open, the host's side of the device trace while one is."""
+        self._n_steps += 1
+        rows = int(np.count_nonzero(self.active))
+        with jax.profiler.TraceAnnotation(
+            "serve.step", n=self._n_steps, rows=rows
         ):
-            self._step_inner()
-            return
-        rows_before = int(np.count_nonzero(self.active))
+            if (
+                self._metrics is None
+                and self._monitor is None
+                and self._device_monitor is None
+            ):
+                self._step_inner()
+            else:
+                self._step_observed(rows)
+
+    def _step_observed(self, rows_before: int) -> None:
+        """``_step_inner`` under the attached metrics and monitors."""
         prefilling_before = len(self.prefill_state)
         tokens_before = self.n_tokens_generated
         prefill_before = self._prefill_tokens
@@ -1641,8 +1694,12 @@ class ContinuousBatcher:
         spec_rej_before = self._spec_rejected
         alloc_before = self._pages_allocated
         released_before = self._pages_released
+        phase_ms = self._phase_ms = {} if self._monitor is not None else None
         t0 = time.monotonic()
-        self._step_inner()
+        try:
+            self._step_inner()
+        finally:
+            self._phase_ms = None
         t1 = time.monotonic()
         produced = self.n_tokens_generated - tokens_before
         if self._metrics is not None:
@@ -1667,6 +1724,9 @@ class ContinuousBatcher:
             self._monitor.on_step(
                 {
                     "duration_ms": (t1 - t0) * 1000.0,
+                    # the phases that ran (_phase; an absent key did not);
+                    # what they leave of duration_ms is unspanned
+                    "phase_ms": phase_ms,
                     "mesh": self._mesh_key,
                     "active_rows": rows_before,
                     "active_rows_after": int(np.count_nonzero(self.active)),
@@ -1689,82 +1749,96 @@ class ContinuousBatcher:
 
     def _step_inner(self) -> None:
         if self.prefill_state:
-            self._advance_prefills()
+            with self._phase("serve.step.prefills"):
+                self._advance_prefills()
         if not self.active.any():
             return
         if self.draft_params is not None:
             self._step_speculative()
             return
-        logits, self.cache = self._decode(
-            self.params,
-            jnp.asarray(self.current),
-            jnp.asarray(self.pos),
-            self.cache,
-            jnp.asarray(self.block_table),
-            **self._lora_kwargs(self.row_adapter),
-        )
-        active_rows = np.flatnonzero(self.active)
-        any_sampled = any(
-            self.row_sampling[row].temperature > 0.0 for row in active_rows
-        )
-        # the common all-greedy-no-logprobs case reduces on device and
-        # moves B int32s; the full [max_batch, V] logits cross to host only
-        # when some active row samples, records logprobs, or is steered by
-        # bias/constraints
-        need_rows = any_sampled or any(
-            self.row_sampling[row].logprobs or self.row_sampling[row].steered
-            for row in active_rows
-        )
-        # ...and the device argmax + its [B] pull only runs when some
-        # active row actually decodes greedily (sampled/steered rows pick
-        # from lg): an all-sampled batch was paying an argmax kernel and a
-        # host sync per token for an array nobody read — found by the
-        # jaxlint host-sync audit (docs/analysis.md "Accelerator lint"),
-        # A/B'd with serving_bench(temperature>0)
-        need_greedy = any(
-            self.row_sampling[row].temperature <= 0.0
-            and not self.row_sampling[row].steered
-            for row in active_rows
-        )
-        greedy = (
-            np.asarray(jnp.argmax(logits[:, -1, :], axis=-1), dtype=np.int32)
-            if need_greedy else None
-        )
-        lg = (
-            np.asarray(logits[:, -1, :], dtype=np.float32)
-            if need_rows else None
-        )
-        for row in active_rows:
-            sp = self.row_sampling[row]
-            req_row = int(self.row_request[row])
-            if sp.temperature > 0.0 or sp.steered:
-                try:
-                    nxt = choose_host(
-                        lg[row], sp, self.row_rng[row], self.results[req_row]
+        with self._phase("serve.step.upload"):
+            current = jnp.asarray(self.current)
+            pos = jnp.asarray(self.pos)
+            block_table = jnp.asarray(self.block_table)
+            lora = self._lora_kwargs(self.row_adapter)
+        with self._phase("serve.step.dispatch"):
+            logits, self.cache = self._decode(
+                self.params, current, pos, self.cache, block_table, **lora
+            )
+            active_rows = np.flatnonzero(self.active)
+            # rows that pick their token on the host, from the row's logits
+            sampled_rows = sum(
+                self.row_sampling[row].temperature > 0.0
+                or self.row_sampling[row].steered
+                for row in active_rows
+            )
+            # the common all-greedy-no-logprobs case reduces on device and
+            # moves B int32s; the full [max_batch, V] logits cross to host
+            # only when some active row samples, records logprobs, or is
+            # steered by bias/constraints
+            need_rows = sampled_rows > 0 or any(
+                self.row_sampling[row].logprobs for row in active_rows
+            )
+            # ...and the device argmax + its [B] pull only runs when some
+            # active row actually decodes greedily (sampled/steered rows
+            # pick from lg): an all-sampled batch was paying an argmax
+            # kernel and a host sync per token for an array nobody read —
+            # found by the jaxlint host-sync audit (docs/analysis.md
+            # "Accelerator lint"), A/B'd with serving_bench(temperature>0)
+            need_greedy = sampled_rows < len(active_rows)
+            # the small eager programs that cut the answer down to what the
+            # host reads are queued behind the decode step before anything
+            # waits: the device runs them back to back, and their dispatch
+            # (a dozen tiny programs for one negative index) hides under it
+            last = logits[:, -1, :]
+            greedy = jnp.argmax(last, axis=-1) if need_greedy else None
+            lg = last if need_rows else None
+        with self._phase("serve.step.wait"):
+            jax.block_until_ready((greedy, lg))
+        with self._phase(
+            "serve.step.pull",
+            bytes=sum(x.nbytes for x in (greedy, lg) if x is not None),
+        ):
+            if need_greedy:
+                greedy = np.asarray(greedy, dtype=np.int32)
+            if need_rows:
+                lg = np.asarray(lg, dtype=np.float32)
+        with self._phase("serve.step.sample", sampled_rows=sampled_rows):
+            choose = self._clocked(choose_host, "sample_choose")
+            logprob = self._clocked(logprob_of, "sample_logprob")
+            for row in active_rows:
+                sp = self.row_sampling[row]
+                req_row = int(self.row_request[row])
+                if sp.temperature > 0.0 or sp.steered:
+                    try:
+                        nxt = choose(
+                            lg[row], sp, self.row_rng[row],
+                            self.results[req_row],
+                        )
+                    except ConstraintExhausted:
+                        # grammar terminal state: the request is complete
+                        # as-is
+                        self._retire(int(row), "constraint")
+                        continue
+                    except Exception as e:
+                        # a buggy user callable must not wedge the whole
+                        # batch (request isolation is continuous batching's
+                        # promise): the row retires with the error
+                        # recorded, batch-mates keep decoding
+                        self.errors[req_row] = repr(e)
+                        self._retire(int(row), "error")
+                        continue
+                else:
+                    nxt = int(greedy[row])
+                self.pos[row] += 1
+                self.current[row, 0] = nxt
+                self.results[req_row].append(nxt)
+                self.n_tokens_generated += 1
+                if sp.logprobs:
+                    self.results_logprobs[req_row].append(
+                        logprob(lg[row], nxt)
                     )
-                except ConstraintExhausted:
-                    # grammar terminal state: the request is complete as-is
-                    self._retire(int(row), "constraint")
-                    continue
-                except Exception as e:
-                    # a buggy user callable must not wedge the whole batch
-                    # (request isolation is continuous batching's promise):
-                    # the row retires with the error recorded, batch-mates
-                    # keep decoding
-                    self.errors[req_row] = repr(e)
-                    self._retire(int(row), "error")
-                    continue
-            else:
-                nxt = int(greedy[row])
-            self.pos[row] += 1
-            self.current[row, 0] = nxt
-            self.results[req_row].append(nxt)
-            self.n_tokens_generated += 1
-            if sp.logprobs:
-                self.results_logprobs[req_row].append(
-                    logprob_of(lg[row], nxt)
-                )
-            self._retire_if_done(int(row))
+                self._retire_if_done(int(row))
 
     def _step_speculative(self) -> None:
         """One draft-propose / target-verify / per-row-commit round.

@@ -15,6 +15,7 @@ interrupted, like fleet-top.
 from __future__ import annotations
 
 import argparse
+import statistics
 import sys
 import time
 
@@ -153,6 +154,19 @@ def render_steps(snap: dict) -> str:
             f"{s.get('spec_accepted', 0):>5} {s.get('spec_rejected', 0):>5} "
             f"{s.get('pages_allocated', 0):>4} {s.get('pages_released', 0):>4} "
             f"{s.get('free_pages', 0):>5}"
+        )
+    # the steps' host time by phase (the serve.step.* spans), where the
+    # records carry it: medians over the steps shown, in the order they ran
+    phases: dict[str, list[float]] = {}
+    for s in last:
+        for phase, ms in (s.get("phase_ms") or {}).items():
+            phases.setdefault(phase, []).append(ms)
+    if phases:
+        lines.append(
+            "  phase p50: " + "  ".join(
+                f"{phase} {fmt_ms(statistics.median(ms))}"
+                for phase, ms in phases.items()
+            )
         )
     return "\n".join(lines)
 

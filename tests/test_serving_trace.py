@@ -6,6 +6,7 @@ acceptance e2e — one serving request's wide event, its `/v1/traces` trace,
 and its `bci_serving_ttft_seconds` exemplar all share one trace_id."""
 
 import dataclasses
+import functools
 import json
 import re
 import time
@@ -28,6 +29,7 @@ from bee_code_interpreter_tpu.models.engine import Engine
 from bee_code_interpreter_tpu.models.serving import (
     CapacityError,
     ContinuousBatcher,
+    SamplingParams,
 )
 from bee_code_interpreter_tpu.observability import (
     FlightRecorder,
@@ -416,6 +418,188 @@ def test_speculative_commit_accounting():
     assert snap["totals"]["spec_accepted"] == row["spec_accepted"]
     steps = snap["steps"]["last"]
     assert sum(s["spec_accepted"] for s in steps) == row["spec_accepted"]
+
+
+# ------------------------------------------------ phases, spans, program names
+
+GREEDY = SamplingParams()
+SAMPLED = SamplingParams(temperature=0.8, top_p=0.95, seed=3, logprobs=True)
+MIXES = {
+    "greedy": [GREEDY, GREEDY],
+    "greedy_logprobs": [SamplingParams(logprobs=True), GREEDY],
+    "sampled": [SAMPLED, dataclasses.replace(SAMPLED, seed=4)],
+    "mixed": [GREEDY, SAMPLED],
+}
+TOP_PHASES = ("upload", "dispatch", "wait", "pull", "sample")
+
+
+@functools.lru_cache(maxsize=None)
+def decoded(mix: str, monitored: bool):
+    """Two requests of the mix through a batcher, with or without a
+    lifecycle monitor: (tokens by request, step records, batcher)."""
+    if monitored:
+        engine, mon, *_ = monitored_stack()
+        batcher = engine.batcher
+    else:
+        mon = None
+        batcher = ContinuousBatcher(
+            PARAMS, CFG, max_batch=2, n_pages=32, page_size=4,
+            max_pages_per_seq=8,
+        )
+    reqs = [
+        batcher.submit(prompt, 5, sampling=sampling)
+        for prompt, sampling in zip((SHORT, LONG), MIXES[mix])
+    ]
+    batcher.run_to_completion()
+    tokens = [batcher.result(r) for r in reqs]
+    steps = mon.snapshot(steps=512)["steps"]["last"] if monitored else None
+    return tokens, steps, batcher
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_phase_ms_splits_every_decode_step(mix):
+    _, steps, batcher = decoded(mix, True)
+    decode = [s for s in steps if s["decode_tokens"]]
+    assert decode and batcher._phase_ms is None
+    picks_on_host = sum(
+        sp.temperature > 0 for sp in MIXES[mix]
+    )
+    for record in decode:
+        phases = record["phase_ms"]
+        assert set(phases) == {*TOP_PHASES, "sample_choose", "sample_logprob"}
+        assert all(ms >= 0.0 for ms in phases.values())
+        # the phases lie inside the step, one after another
+        assert sum(phases[k] for k in TOP_PHASES) <= record["duration_ms"]
+        inside = phases["sample_choose"] + phases["sample_logprob"]
+        assert inside <= phases["sample"]
+        # a greedy row's token comes off the device: nothing is chosen here
+        assert (phases["sample_choose"] > 0.0) == (picks_on_host > 0)
+        assert (phases["sample_logprob"] > 0.0) == any(
+            sp.logprobs for sp in MIXES[mix]
+        )
+
+
+@pytest.mark.parametrize("mix", MIXES)
+def test_unmonitored_batcher_keeps_no_phase_state_and_the_same_tokens(mix):
+    tokens, _, batcher = decoded(mix, False)
+    assert batcher._phase_ms is None and batcher._monitor is None
+    assert all(len(t) == 5 for t in tokens)
+    assert tokens == decoded(mix, True)[0]
+
+
+class SpanSpy:
+    """Stands in for ``jax.profiler.TraceAnnotation``: every span with its
+    stats and the spans that were open when it was entered."""
+
+    spans: list = []
+    open_: list = []
+
+    def __init__(self, name, **stats):
+        self.name, self.stats = name, stats
+
+    def __enter__(self):
+        SpanSpy.spans.append((self.name, self.stats, tuple(SpanSpy.open_)))
+        SpanSpy.open_.append(self.name)
+
+    def __exit__(self, *exc):
+        assert SpanSpy.open_.pop() == self.name
+
+
+@pytest.mark.parametrize("mix", ["greedy", "mixed"])
+def test_serve_spans_nest_and_carry_their_stats(monkeypatch, mix):
+    monkeypatch.setattr(SpanSpy, "spans", [])
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", SpanSpy)
+    batcher = ContinuousBatcher(
+        PARAMS, CFG, max_batch=2, n_pages=32, page_size=4, max_pages_per_seq=8,
+    )
+    reqs = [
+        batcher.submit(prompt, 3, sampling=sampling)
+        for prompt, sampling in zip((SHORT, LONG), MIXES[mix])
+    ]
+    batcher.run_to_completion()
+    spans = SpanSpy.spans
+    assert not SpanSpy.open_
+    # every span lies inside the one its name extends, and in no other
+    for name, _, parents in spans:
+        want = name.rpartition(".")[0]
+        assert parents == ((want,) if want != "serve" else ()), (name, parents)
+
+    admits = [stats for name, stats, _ in spans if name == "serve.admit"]
+    assert admits == [
+        {"req": reqs[0], "prompt_tokens": len(SHORT), "pages": 2},
+        {"req": reqs[1], "prompt_tokens": len(LONG), "pages": 6},
+    ]
+    names = [name for name, _, _ in spans]
+    assert names[:5] == [
+        "serve.admit", "serve.admit.prefill", "serve.admit.seed_pool",
+        "serve.admit.pull", "serve.admit.activate",
+    ]
+    steps = [stats for name, stats, _ in spans if name == "serve.step"]
+    assert [s["n"] for s in steps] == list(range(1, len(steps) + 1))
+    assert steps[0]["rows"] == 2
+    assert names[10:16] == ["serve.step"] + [
+        f"serve.step.{phase}" for phase in TOP_PHASES
+    ]
+    # all greedy and no logprobs: the [B] argmax ids cross, no logits row
+    ids, rows = 4 * 2, 4 * 2 * CFG.vocab_size
+    pulls = {s["bytes"] for name, s, _ in spans if name == "serve.step.pull"}
+    assert pulls == ({ids} if mix == "greedy" else {ids + rows})
+    sampled = {
+        s["sampled_rows"] for name, s, _ in spans if name == "serve.step.sample"
+    }
+    assert sampled == ({0} if mix == "greedy" else {1})
+
+
+TRACKED = {
+    "_decode": "decode_step_paged", "_prefill": "prefill_forward",
+    "_prefill_chunked": "prefill_chunked", "_window": "decode_window_paged",
+    "_draft_decode": "draft_decode_step_paged",
+    "_draft_prefill": "draft_prefill_forward",
+    "_draft_window": "draft_decode_window_paged",
+}
+
+
+@pytest.mark.parametrize("attr", TRACKED)
+def test_jitted_programs_carry_their_tracked_names(attr):
+    batcher = ContinuousBatcher(
+        PARAMS, CFG, max_batch=2, n_pages=32, page_size=4, max_pages_per_seq=8,
+        draft_params=PARAMS, draft_config=CFG, gamma=2,
+    )
+    tracked = getattr(batcher, attr)
+    assert tracked.name == TRACKED[attr] == tracked.fn.__name__
+    if attr == "_decode":
+        # ...and so does the program the profiler's XLA Modules line names
+        lowered = tracked.lower(
+            batcher.params, jnp.asarray(batcher.current),
+            jnp.asarray(batcher.pos), batcher.cache,
+            jnp.asarray(batcher.block_table),
+        )
+        assert lowered.as_text().startswith("module @jit_decode_step_paged ")
+
+
+@pytest.mark.parametrize("with_phases", [True, False])
+def test_serving_top_prints_the_median_phase_times(with_phases):
+    import importlib.util
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "scripts" / "serving-top.py"
+    spec = importlib.util.spec_from_file_location("serving_top", path)
+    top = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(top)
+    steps = [dict(s) for s in decoded("mixed", True)[1]]
+    if not with_phases:  # an older replica's records
+        for s in steps:
+            del s["phase_ms"]
+    text = top.render_steps(
+        {"steps": {"recorded": len(steps), "retained": len(steps), "last": steps}}
+    )
+    assert len(text.splitlines()) == 2 + len(steps) + with_phases
+    if with_phases:
+        line = text.splitlines()[-1]
+        assert line.startswith("  phase p50: upload ")
+        assert [w for w in line.split() if w.isalpha() or "_" in w] == [
+            "phase", *TOP_PHASES, "sample_choose", "sample_logprob",
+        ]
 
 
 # ----------------------------------------------------------- bench trajectory
