@@ -128,14 +128,15 @@ SUPPRESSIONS: tuple[Suppression, ...] = (
         reason=(
             "the batcher's step-path transfers are the DESIGNED device/"
             "host split (module docstring): ONE bounded pull per compiled "
-            "step — greedy tokens reduce on device to [B] int32 before "
-            "crossing, the full logits rows cross only when some active "
-            "row samples/records logprobs/is steered, and the speculative "
-            "round pulls [B,gamma+1] predictions once per gamma+1 tokens "
-            "— plus per-WINDOW (page-aligned, never per-token) pulls on "
-            "the admission prefill paths; host-side numpy sampling is the "
-            "per-request heterogeneity the fixed-shape device program "
-            "deliberately excludes (tests/test_serving.py pins the split)"
+            "step — greedy and sampled tokens reduce on device to [B] "
+            "int32 before crossing, the full logits rows cross only when "
+            "some active row records logprobs/is steered, and the "
+            "speculative round pulls [B,gamma+1] predictions once per "
+            "gamma+1 tokens — plus per-WINDOW (page-aligned, never "
+            "per-token) pulls on the admission prefill paths; host-side "
+            "numpy selection is left to what is Python per request (bias, "
+            "constraints), which the fixed-shape device programs "
+            "deliberately exclude (tests/test_serving.py pins the split)"
         ),
     ),
 )

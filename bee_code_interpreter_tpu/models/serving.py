@@ -44,10 +44,15 @@ all E experts' MLPs — an inference-exactness configuration, not a
 training one.
 
 Sampling is PER REQUEST (temperature / top-k / top-p / seed — the
-heterogeneity serving actually needs) and runs host-side on the step's
-logits: the device program stays one fixed-shape greedy-agnostic forward,
-while each row draws from its own seeded ``numpy`` Generator — fully
-deterministic per request and independent of what shares the batch.
+heterogeneity serving actually needs). The decode program stays one
+fixed-shape greedy-agnostic forward; a second small program
+(``pick_tokens``), queued behind it, picks the token of every row that
+samples, with the rows' settings as arrays and one uniform draw a row from
+the request's own seeded ``numpy`` Generator — fully deterministic per
+request and independent of what shares the batch. Rows that are steered
+(``logit_bias``, ``allowed_tokens``: Python per row) pick on the host from
+the pulled logits row (``choose_host``), as do a request's first token and
+the speculative steps.
 
 The reference has no model serving at all (SURVEY §2); within this rebuild
 the batcher is the library-level analogue of the service's warm sandbox
@@ -66,6 +71,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from bee_code_interpreter_tpu.models.transformer import (
     TransformerConfig,
@@ -97,6 +103,17 @@ class SamplingParams:
     token), drawn from a per-request seeded generator so a request's
     output never depends on its batch-mates.
 
+    Where a selection runs is read off the request alone. In the plain
+    decode step a greedy row's token is the device's argmax; a row that
+    samples (``temperature > 0``) and is not ``steered`` is picked on the
+    device by ``pick_tokens``, from one uniform draw of the request's
+    generator a token; a ``steered`` row (``logit_bias``,
+    ``allowed_tokens``) is picked on the host by ``choose_host`` from its
+    pulled logits row, greedy or not. A request's first token (admission)
+    and every token of a speculative batcher are picked on the host too.
+    ``logprobs=True`` pulls the row for ``logprob_of`` wherever the token
+    was picked.
+
     ``stop_sequences`` are token-id sequences: generation retires the
     moment the output ends with any of them, and the matched sequence is
     TRIMMED from the result (the common serving-API contract; ``eos_id``
@@ -115,7 +132,7 @@ class SamplingParams:
     ids currently permitted, or None for "unconstrained this step" —
     everything else is masked to -inf. A grammar/JSON engine plugs in by
     closing over its own parser state. Both run host-side per row; the
-    device program stays constraint-agnostic and fixed-shape."""
+    device programs stay constraint-agnostic and fixed-shape."""
 
     temperature: float = 0.0
     top_k: int | None = None
@@ -206,6 +223,129 @@ def sample_host(
         return int(np.argmax(logits))
     probs = filtered_probs_host(logits, params)
     return int(rng.choice(logits.shape[0], p=probs))
+
+
+def _largest(holds, rows: int, bits: int) -> jax.Array:
+    """Per row the largest ``bits``-bit unsigned ``t`` for which ``holds(t)``
+    is true, for a ``holds`` that is true up to some ``t`` and false beyond
+    (0 where it never is): one pass over the row per bit, highest first.
+    ``holds`` takes ``t`` as [rows, 1] and answers [rows]."""
+
+    def set_bit(i, t):
+        higher = t | (jnp.uint32(1) << (bits - 1 - i).astype(jnp.uint32))
+        return jnp.where(holds(higher[:, None]), higher, t)
+
+    return lax.fori_loop(0, bits, set_bit, jnp.zeros(rows, jnp.uint32))
+
+
+def kept_tokens(
+    logits: jax.Array,  # [B, V] f32 — temperature-scaled
+    top_k: jax.Array,  # [B] int32; V keeps every token
+    top_p: jax.Array,  # [B] f32; +inf keeps every token
+) -> tuple[jax.Array, jax.Array]:
+    """``transformer.filter_logits`` with PER-ROW settings as arrays, so one
+    compiled program serves every mix of rows: the mask of the tokens the
+    filters keep, [B, V] bool, and every token's unnormalised probability
+    ``exp(logit - max)``, [B, V] f32. Same tie rules as ``filter_logits``
+    and ``filtered_probs_host``: top-k keeps every value ``>=`` the k-th
+    largest; the nucleus keeps a token while the mass BEFORE it in the
+    stable descending order (equal values by token id) is ``< top_p``, and
+    the top token always.
+
+    No sort (on a TPU one over a vocabulary costs a quarter of a minute to
+    compile): the k-th largest value and the value at the nucleus's edge
+    are each found bit by bit over the floats' order-preserving integer
+    keys, 32 masked sums a row; the last kept of the edge's equal values,
+    over the token ids. Arithmetic in float32, where the host's is float64:
+    the two can differ on a token whose mass-before is within float32
+    rounding of ``top_p`` (tests/test_serving.py pins the rest)."""
+    rows, vocab = logits.shape
+    x = jnp.where(logits == 0.0, 0.0, logits)  # -0.0 is 0.0's equal
+    bits = lax.bitcast_convert_type(x, jnp.uint32)
+    # unsigned order of the keys = order of the floats
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    ids = lax.broadcasted_iota(jnp.uint32, x.shape, 1)
+    id_bits = max(1, (vocab - 1).bit_length())
+
+    k = jnp.clip(top_k, 1, vocab)
+    kth = _largest(lambda t: jnp.sum(keys >= t, axis=1) >= k, rows, 32)
+    in_k = keys >= kth[:, None]
+    prob = jnp.where(
+        in_k, jnp.exp(x - jnp.max(x, axis=1, keepdims=True)), 0.0
+    )
+
+    def mass(mask):
+        return jnp.sum(jnp.where(mask, prob, 0.0), axis=1)
+
+    goal = top_p * jnp.sum(prob, axis=1)
+    # the smallest value whose own and larger values together reach top_p:
+    # larger values lie inside the nucleus whole, smaller ones outside
+    edge = _largest(lambda t: mass(keys >= t) >= goal, rows, 32)
+    above = keys > edge[:, None]
+    at_edge = in_k & (keys == edge[:, None])
+    before = mass(above)
+    last = _largest(
+        lambda i: before + mass(at_edge & (ids < i)) < goal, rows, id_bits
+    )
+    top = jnp.argmax(x, axis=1).astype(jnp.uint32)  # first of the largest
+    keep = in_k & (above | (at_edge & (ids <= last[:, None])))
+    return keep | (ids == top[:, None]), prob
+
+
+def pick_tokens(
+    logits: jax.Array,  # [B, 1, V] f32 — the decode step's, as it leaves them
+    temperature: jax.Array,  # [B] f32 > 0
+    top_k: jax.Array,  # [B] int32; V for none
+    top_p: jax.Array,  # [B] f32; +inf for none
+    draw: jax.Array,  # [B] f32 in (0, 1]: one uniform a row
+    replicated=None,
+) -> jax.Array:
+    """The sampled rows' next tokens in one device program: temperature,
+    then ``kept_tokens``' filters, then the token at which the kept
+    probabilities, summed from the last token id down, first reach ``draw``
+    of their total (an inverse-CDF pick: token ``i`` with probability
+    ``p_i``, whatever the order of summation). Every argument is an array:
+    no setting and no number of sampling rows compiles a second program,
+    and a row that does not sample passes ``temperature`` 1 and ignores its
+    answer. The token is always one ``kept_tokens`` kept. Under a mesh the
+    logits arrive sharded over the vocabulary: ``replicated`` (a sharding)
+    gathers them once, so that the sums are local. Returns [B] int32."""
+    if replicated is not None:
+        logits = lax.with_sharding_constraint(logits, replicated)
+    x = logits[:, -1, :] / temperature[:, None]
+    rows, vocab = x.shape
+    keep, prob = kept_tokens(x, top_k, top_p)
+    ids = lax.broadcasted_iota(jnp.uint32, x.shape, 1)
+
+    def tail(i):
+        return jnp.sum(jnp.where(keep & (ids >= i), prob, 0.0), axis=1)
+
+    goal = draw * tail(jnp.zeros((rows, 1), jnp.uint32))
+    token = _largest(
+        lambda i: tail(i) >= goal, rows, max(1, (vocab - 1).bit_length())
+    )
+    return token.astype(jnp.int32)
+
+
+def pick_settings(samplings, rows, vocab_size: int) -> tuple:
+    """``pick_tokens``' per-row setting arrays, on the host (the call
+    uploads them), for a batch whose ``samplings[row]`` sample in ``rows``:
+    (temperature, top_k, top_p), "none" as the value that keeps every
+    token. The other rows get settings that filter nothing; their answers
+    are not read."""
+    temperature = np.ones(len(samplings), dtype=np.float32)
+    top_k = np.full(len(samplings), vocab_size, dtype=np.int32)
+    top_p = np.full(len(samplings), np.inf, dtype=np.float32)
+    for row in rows:
+        sp = samplings[row]
+        temperature[row] = sp.temperature
+        if sp.top_k is not None:
+            top_k[row] = min(sp.top_k, vocab_size)
+        # from 1 up the nucleus is everything (the host's float64 sums
+        # agree; float32's would cut a tail of 1e-7 of the mass)
+        if sp.top_p is not None and sp.top_p < 1.0:
+            top_p[row] = sp.top_p
+    return temperature, top_k, top_p
 
 
 class ConstraintExhausted(Exception):
@@ -565,6 +705,21 @@ class ContinuousBatcher:
             "decode_window_paged",
             donate_argnums=(3,),
         )
+        # The token of every row that samples and is not steered, queued
+        # behind the decode program in the plain step. Every setting is an
+        # array and the logits go in whole, so this is one program at one
+        # shape per batcher. Under a mesh the decode program leaves the
+        # logits sharded over the vocabulary (lm_head is column-parallel):
+        # they are taken as they are and gathered once inside.
+        replicated = None
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            replicated = NamedSharding(mesh, PartitionSpec())
+        self._pick = self._track(
+            functools.partial(pick_tokens, replicated=replicated),
+            "pick_tokens",
+        )
         if draft_config is not None:
             # the draft's own paged pool, addressed by the SAME block
             # tables/pages (one allocation covers both models' K/V)
@@ -614,6 +769,8 @@ class ContinuousBatcher:
         self._prefill_tokens = 0
         self._spec_accepted = 0
         self._spec_rejected = 0
+        self._device_picked = 0
+        self._host_picked = 0
         self._n_steps = 0
         # the step being recorded's phase -> ms (see _phase); a dict only
         # inside a step with a lifecycle monitor attached, None otherwise
@@ -1692,6 +1849,8 @@ class ContinuousBatcher:
         prefill_before = self._prefill_tokens
         spec_acc_before = self._spec_accepted
         spec_rej_before = self._spec_rejected
+        device_picked_before = self._device_picked
+        host_picked_before = self._host_picked
         alloc_before = self._pages_allocated
         released_before = self._pages_released
         phase_ms = self._phase_ms = {} if self._monitor is not None else None
@@ -1736,6 +1895,12 @@ class ContinuousBatcher:
                     "prefill_tokens": self._prefill_tokens - prefill_before,
                     "spec_accepted": self._spec_accepted - spec_acc_before,
                     "spec_rejected": self._spec_rejected - spec_rej_before,
+                    # rows of the plain step whose token pick_tokens drew /
+                    # choose_host chose (steered); the rest are greedy
+                    "device_picked_rows": (
+                        self._device_picked - device_picked_before
+                    ),
+                    "host_picked_rows": self._host_picked - host_picked_before,
                     "pages_allocated": self._pages_allocated - alloc_before,
                     "pages_released": self._pages_released - released_before,
                     "free_pages": len(self.free_pages),
@@ -1766,50 +1931,79 @@ class ContinuousBatcher:
                 self.params, current, pos, self.cache, block_table, **lora
             )
             active_rows = np.flatnonzero(self.active)
-            # rows that pick their token on the host, from the row's logits
-            sampled_rows = sum(
-                self.row_sampling[row].temperature > 0.0
-                or self.row_sampling[row].steered
-                for row in active_rows
-            )
-            # the common all-greedy-no-logprobs case reduces on device and
-            # moves B int32s; the full [max_batch, V] logits cross to host
-            # only when some active row samples, records logprobs, or is
-            # steered by bias/constraints
-            need_rows = sampled_rows > 0 or any(
+            # where each row's token is picked, read off its request:
+            # steered rows on the host from the row's logits (bias, or a
+            # constraint that is Python), other sampling rows by the pick
+            # program, the rest by the device's argmax
+            host_rows = [
+                row for row in active_rows if self.row_sampling[row].steered
+            ]
+            device_rows = [
+                row for row in active_rows
+                if self.row_sampling[row].temperature > 0.0
+                and not self.row_sampling[row].steered
+            ]
+            self._host_picked += len(host_rows)
+            self._device_picked += len(device_rows)
+            # the common case moves [B] int32s; the full [max_batch, V]
+            # logits cross to host only when some active row is steered or
+            # records logprobs
+            need_rows = bool(host_rows) or any(
                 self.row_sampling[row].logprobs for row in active_rows
             )
             # ...and the device argmax + its [B] pull only runs when some
-            # active row actually decodes greedily (sampled/steered rows
-            # pick from lg): an all-sampled batch was paying an argmax
-            # kernel and a host sync per token for an array nobody read —
-            # found by the jaxlint host-sync audit (docs/analysis.md
-            # "Accelerator lint"), A/B'd with serving_bench(temperature>0)
-            need_greedy = sampled_rows < len(active_rows)
-            # the small eager programs that cut the answer down to what the
-            # host reads are queued behind the decode step before anything
+            # active row actually decodes greedily: an all-sampled batch
+            # was paying an argmax kernel and a host sync per token for an
+            # array nobody read — found by the jaxlint host-sync audit
+            # (docs/analysis.md "Accelerator lint")
+            need_greedy = len(host_rows) + len(device_rows) < len(active_rows)
+            # the small programs that cut the answer down to what the host
+            # reads are queued behind the decode step before anything
             # waits: the device runs them back to back, and their dispatch
             # (a dozen tiny programs for one negative index) hides under it
-            last = logits[:, -1, :]
+            picked = None
+            if device_rows:
+                # one uniform a row from the request's own generator: its
+                # tokens depend on its seed and on how many it has drawn,
+                # never on its row or its batch-mates
+                draw = np.ones(self.active.shape[0], dtype=np.float32)
+                for row in device_rows:
+                    draw[row] = 1.0 - self.row_rng[row].random()  # (0, 1]
+                picked = self._pick(
+                    logits,
+                    *pick_settings(
+                        self.row_sampling, device_rows, self.config.vocab_size
+                    ),
+                    draw,
+                )
+            last = logits[:, -1, :] if need_greedy or need_rows else None
             greedy = jnp.argmax(last, axis=-1) if need_greedy else None
             lg = last if need_rows else None
         with self._phase("serve.step.wait"):
-            jax.block_until_ready((greedy, lg))
+            jax.block_until_ready((greedy, picked, lg))
         with self._phase(
             "serve.step.pull",
-            bytes=sum(x.nbytes for x in (greedy, lg) if x is not None),
+            bytes=sum(
+                x.nbytes for x in (greedy, picked, lg) if x is not None
+            ),
         ):
             if need_greedy:
                 greedy = np.asarray(greedy, dtype=np.int32)
+            if device_rows:
+                picked = np.asarray(picked, dtype=np.int32)
             if need_rows:
                 lg = np.asarray(lg, dtype=np.float32)
-        with self._phase("serve.step.sample", sampled_rows=sampled_rows):
+        with self._phase(
+            "serve.step.sample",
+            device_picked_rows=len(device_rows),
+            host_picked_rows=len(host_rows),
+        ):
             choose = self._clocked(choose_host, "sample_choose")
             logprob = self._clocked(logprob_of, "sample_logprob")
             for row in active_rows:
                 sp = self.row_sampling[row]
                 req_row = int(self.row_request[row])
-                if sp.temperature > 0.0 or sp.steered:
+                if sp.steered:
                     try:
                         nxt = choose(
                             lg[row], sp, self.row_rng[row],
@@ -1828,6 +2022,8 @@ class ContinuousBatcher:
                         self.errors[req_row] = repr(e)
                         self._retire(int(row), "error")
                         continue
+                elif sp.temperature > 0.0:
+                    nxt = int(picked[row])
                 else:
                     nxt = int(greedy[row])
                 self.pos[row] += 1

@@ -690,3 +690,260 @@ def test_snapshot_geometry_checks_behavioral_fields():
     other = ContinuousBatcher(params, config, eos_id=None, **kw)
     with pytest.raises(ValueError, match="eos_id"):
         other.load_state_dict(snap)
+
+
+# ----------------------------------- the device pick (``pick_tokens``)
+
+PICK_V = 64
+# (top_k, top_p) as a request states them
+PICK_FILTERS = {
+    "neither": (None, None),
+    "top_k": (7, None),
+    "top_p": (None, 0.8),
+    "both": (12, 0.8),
+}
+PICK_TEMPERATURES = (0.5, 0.8, 1.0, 1.3)
+
+
+def _scatter(values):
+    """``values`` (descending) dealt onto token ids in a fixed shuffled
+    order, so that equal values are told apart by id, not by position."""
+    row = np.empty(PICK_V, dtype=np.float32)
+    row[np.random.default_rng(5).permutation(PICK_V)] = values
+    return row
+
+
+def _pick_rows(kind):
+    rng = np.random.default_rng(11)
+    if kind == "seeded":
+        return (rng.normal(size=(4, PICK_V)) * 2.0).astype(np.float32)
+    if kind == "ties_at_kth":
+        # ranks 5..9 and 10..14 hold one value each: the 7th and the 12th
+        # largest both sit inside a run of equals, which top-k keeps whole
+        rows = []
+        for _ in range(4):
+            values = np.sort(rng.normal(size=PICK_V) * 2.0)[::-1].copy()
+            values[5:10] = values[5]
+            values[10:15] = values[10]
+            rows.append(_scatter(values))
+        return np.stack(rows)
+    # ties_at_nucleus: one token of 0.5, six of 0.07, the rest share 0.08.
+    # Mass before the six: .50 .57 .64 .71 .78 .85, so top_p 0.8 cuts
+    # INSIDE the run of equals (after top-k 12 too: .54 .61 .69 .76 .84),
+    # and which of them stay is decided by token id alone
+    probs = np.r_[0.5, [0.07] * 6, np.full(PICK_V - 7, 0.08 / (PICK_V - 7))]
+    return np.stack([_scatter(np.log(probs))] * 4) * np.asarray(
+        PICK_TEMPERATURES, dtype=np.float32
+    )[:, None]  # the temperature gives the probabilities back
+
+
+def _pick_arrays(samplings, draws):
+    """``pick_tokens``' arguments after the logits, every row sampling."""
+    from bee_code_interpreter_tpu.models.serving import pick_settings
+
+    settings = pick_settings(samplings, range(len(samplings)), PICK_V)
+    return *settings, np.asarray(draws, dtype=np.float32)
+
+
+@pytest.mark.parametrize("rows", ["seeded", "ties_at_kth", "ties_at_nucleus"])
+@pytest.mark.parametrize("filters", PICK_FILTERS)
+def test_device_pick_keeps_the_hosts_tokens(filters, rows):
+    # The device's filters keep EXACTLY the tokens the host's keep, ties at
+    # the k-th value and inside the nucleus's edge included, with every
+    # row's own settings in one program; and whatever the draw, the picked
+    # token is a kept one, every kept one reachable.
+    from bee_code_interpreter_tpu.models.serving import (
+        filtered_probs_host,
+        kept_tokens,
+        pick_tokens,
+    )
+
+    top_k, top_p = PICK_FILTERS[filters]
+    logits = _pick_rows(rows)
+    samplings = [
+        SamplingParams(temperature=t, top_k=top_k, top_p=top_p)
+        for t in PICK_TEMPERATURES
+    ]
+    host = np.stack([
+        filtered_probs_host(row, sp) for row, sp in zip(logits, samplings)
+    ])
+    temperature, k, p, _ = _pick_arrays(samplings, [1.0] * 4)
+    keep, prob = jax.jit(kept_tokens)(logits / temperature[:, None], k, p)
+    keep, prob = np.asarray(keep), np.asarray(prob)
+    assert (keep == (host > 0)).all(), (keep.sum(1), (host > 0).sum(1))
+    if rows != "seeded":
+        # the planted ties really straddle an edge: some row keeps a part
+        # of a run of equal values, or more than k because of one
+        assert filters == "neither" or any(
+            len(np.unique(row[kept])) < kept.sum()
+            for row, kept in zip(logits, keep)
+        )
+    kept_prob = np.where(keep, prob, 0.0)
+    np.testing.assert_allclose(
+        kept_prob / kept_prob.sum(1, keepdims=True), host, atol=1e-6
+    )
+    pick = jax.jit(pick_tokens)
+    seen = np.zeros_like(keep)
+    for draw in np.linspace(1.0, 1e-6, 400):
+        _, _, _, draws = _pick_arrays(samplings, [draw] * 4)
+        tokens = np.asarray(pick(logits[:, None, :], temperature, k, p, draws))
+        seen[np.arange(4), tokens] = True
+    assert not (seen & ~keep).any()
+    assert (seen | (host < 1.0 / 300)).all()
+
+
+def test_device_pick_draws_from_the_hosts_distribution():
+    # chi-square on a small vocabulary: tokens picked on the device from
+    # uniform draws follow filtered_probs_host's probabilities
+    from bee_code_interpreter_tpu.models.serving import (
+        filtered_probs_host,
+        pick_tokens,
+    )
+
+    rng = np.random.default_rng(3)
+    sp = SamplingParams(temperature=1.1, top_k=24, top_p=0.9)
+    row = rng.normal(size=PICK_V).astype(np.float32)
+    want = filtered_probs_host(row, sp)
+    n_rows, n_calls = 250, 80
+    pick = jax.jit(pick_tokens)
+    logits = np.repeat(row[None, None, :], n_rows, axis=0)
+    counts = np.zeros(PICK_V)
+    for _ in range(n_calls):
+        draws = 1.0 - rng.random(n_rows)
+        tokens = pick(logits, *_pick_arrays([sp] * n_rows, draws))
+        counts += np.bincount(np.asarray(tokens), minlength=PICK_V)
+    n = n_rows * n_calls
+    support = want > 0
+    assert counts[~support].sum() == 0
+    assert 10 <= support.sum() <= 24
+    expected = n * want[support]
+    chi2 = ((counts[support] - expected) ** 2 / expected).sum()
+    dof = support.sum() - 1
+    # mean dof, standard deviation sqrt(2 dof): five of them is p < 1e-5
+    assert chi2 < dof + 5.0 * np.sqrt(2.0 * dof), (chi2, dof)
+
+
+def _mixed_batcher(params, config, **kw):
+    return ContinuousBatcher(
+        params, config, max_batch=4, n_pages=48, page_size=4,
+        max_pages_per_seq=6, **kw,
+    )
+
+
+MATES = [
+    SamplingParams(temperature=1.2, top_p=0.7, seed=1),
+    SamplingParams(),
+    SamplingParams(temperature=0.6, top_k=5, seed=2, logprobs=True),
+]
+
+
+@pytest.mark.parametrize("place", ["alone", "first_row", "last_row"])
+def test_sampled_tokens_do_not_depend_on_row_or_batch_mates(place):
+    # a request's draws come from its own generator, one a token: the same
+    # tokens alone, in a full batch, and in another row of one
+    config = cfg()
+    params = T.init_params(config, jax.random.PRNGKey(0))
+    prompt = np.asarray([9, 2, 6, 5])
+    hot = SamplingParams(temperature=1.0, top_k=30, top_p=0.95, seed=123)
+
+    def run(where):
+        b = _mixed_batcher(params, config)
+        mates = [] if where == "alone" else MATES
+        order = [hot] + mates if where != "last_row" else mates + [hot]
+        reqs = {
+            id(sp): b.submit(
+                prompt if sp is hot else [3, 1, 4, 1, 5], 8, sampling=sp
+            )
+            for sp in order
+        }
+        assert b.row_request[0 if where != "last_row" else 3] == reqs[id(hot)]
+        b.run_to_completion()
+        return b.result(reqs[id(hot)])
+
+    want = run("alone")
+    assert len(want) == 8 and len(set(want)) > 2  # it did sample
+    assert run(place) == want
+
+
+def test_steered_and_logprob_rows_beside_device_picked_rows():
+    # in one batch with device-picked rows: a steered row still gets
+    # choose_host's token (the host's stream: what it gets alone), and a
+    # logprobs row logprob_of's report of the token the device picked
+    from bee_code_interpreter_tpu.models import serving
+
+    config = cfg()
+    params = T.init_params(config, jax.random.PRNGKey(0))
+    prompt = [3, 1, 4, 1, 5]
+    steered = SamplingParams(
+        temperature=0.9, seed=8, logit_bias={7: 4.0},
+        allowed_tokens=lambda out: range(0, config.vocab_size, 2),
+    )
+    reported = SamplingParams(temperature=0.9, top_p=0.9, seed=9, logprobs=True)
+
+    alone = _mixed_batcher(params, config)
+    r = alone.submit(prompt, 6, sampling=steered)
+    alone.run_to_completion()
+    want_steered = alone.result(r)
+    assert all(t % 2 == 0 for t in want_steered)
+
+    chosen = []
+    real = serving.choose_host
+
+    def spy(logits, sp, rng, generated):
+        chosen.append(sp)
+        return real(logits, sp, rng, generated)
+
+    b = _mixed_batcher(params, config)
+    reqs = [
+        b.submit(prompt, 6, sampling=sp)
+        for sp in (MATES[0], steered, reported, MATES[1])
+    ]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(serving, "choose_host", spy)
+        b.run_to_completion()
+    # only the steered row chose on the host, once a step
+    assert chosen == [steered] * 5
+    assert b.result(reqs[1]) == want_steered
+    # the model's own log-probability of each token the device picked: a
+    # plain forward over prompt + output, float32 end to end
+    out = b.result(reqs[2])
+    logits = T.forward(params, jnp.asarray(prompt + out)[None, :], config)[0]
+    logp = np.asarray(jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1))
+    want = [logp[len(prompt) - 1 + j, t] for j, t in enumerate(out)]
+    np.testing.assert_allclose(b.result_logprobs(reqs[2]), want, atol=2e-4)
+    assert len(set(out)) > 1
+
+
+def test_pick_program_compiles_once_over_a_mixed_run():
+    # greedy, sampled, top-k, top-p, steered and logprobs requests coming
+    # and going: every setting is an array, so TrackedJit sees ONE compile
+    config = cfg()
+    params = T.init_params(config, jax.random.PRNGKey(0))
+
+    class Compiles:
+        names: list = []
+
+        def on_compile(self, name, **_):
+            self.names.append(name)
+
+        def record_step(self, *_, **__):
+            pass
+
+    b = _mixed_batcher(params, config)
+    b.set_device_monitor(Compiles())
+    kinds = MATES + [
+        SamplingParams(temperature=0.7, seed=4),
+        SamplingParams(temperature=1.5, top_k=3, top_p=0.5, seed=5),
+        SamplingParams(temperature=0.9, seed=6, logit_bias={2: 1.0}),
+    ]
+    pending = [(kind, 2 + i % 4) for i, kind in enumerate(kinds * 2)]
+    live = []
+    while pending or live:
+        while pending and b.has_free_row():
+            kind, n = pending.pop()
+            live.append(b.submit([5, 3, 7, 2], n, sampling=kind))
+        b.step()
+        live = [r for r in live if not b.is_done(r)]
+    assert Compiles.names.count("pick_tokens") == 1
+    assert Compiles.names.count("decode_step_paged") == 1
+    assert b._pick._cache_size() == 1

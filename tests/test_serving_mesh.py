@@ -60,6 +60,34 @@ def test_tp_batcher_matches_unsharded_solo_decode():
     assert len(b.cache["k"].sharding.device_set) == 2
 
 
+def test_tp_sampled_rows_match_the_unsharded_batcher():
+    # the pick program takes the decode step's logits as the mesh leaves
+    # them (sharded over the vocabulary) and draws the tokens the unsharded
+    # batcher draws: same seed, same settings, same stream
+    from bee_code_interpreter_tpu.models.serving import SamplingParams
+
+    config = cfg()
+    params = T.init_params(config, jax.random.PRNGKey(0))
+    kinds = [
+        SamplingParams(temperature=0.9, top_p=0.9, seed=3),
+        SamplingParams(temperature=1.2, top_k=20, seed=4),
+    ]
+
+    def run(**kw):
+        b = ContinuousBatcher(
+            params, config, max_batch=2, n_pages=16, page_size=4,
+            max_pages_per_seq=4, **kw,
+        )
+        reqs = [b.submit(PROMPT, 6, sampling=sp) for sp in kinds]
+        b.run_to_completion()
+        assert b._device_picked == 2 * 5  # the first token is the admission's
+        return [b.result(r) for r in reqs]
+
+    want = run()
+    assert all(len(set(out)) > 2 for out in want)  # they did sample
+    assert run(mesh=tp_mesh()) == want
+
+
 def test_tp_int8_pool_matches_unsharded_solo():
     config = cfg(kv_cache_dtype="int8")
     params = T.init_params(config, jax.random.PRNGKey(0))

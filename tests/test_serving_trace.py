@@ -429,6 +429,10 @@ MIXES = {
     "greedy_logprobs": [SamplingParams(logprobs=True), GREEDY],
     "sampled": [SAMPLED, dataclasses.replace(SAMPLED, seed=4)],
     "mixed": [GREEDY, SAMPLED],
+    # a steered row picks on the host from its pulled logits row
+    "steered": [
+        GREEDY, SamplingParams(temperature=0.8, seed=5, logit_bias={3: 2.0}),
+    ],
 }
 TOP_PHASES = ("upload", "dispatch", "wait", "pull", "sample")
 
@@ -461,8 +465,9 @@ def test_phase_ms_splits_every_decode_step(mix):
     _, steps, batcher = decoded(mix, True)
     decode = [s for s in steps if s["decode_tokens"]]
     assert decode and batcher._phase_ms is None
-    picks_on_host = sum(
-        sp.temperature > 0 for sp in MIXES[mix]
+    picks_on_host = sum(sp.steered for sp in MIXES[mix])
+    picks_on_device = sum(
+        sp.temperature > 0 and not sp.steered for sp in MIXES[mix]
     )
     for record in decode:
         phases = record["phase_ms"]
@@ -472,8 +477,11 @@ def test_phase_ms_splits_every_decode_step(mix):
         assert sum(phases[k] for k in TOP_PHASES) <= record["duration_ms"]
         inside = phases["sample_choose"] + phases["sample_logprob"]
         assert inside <= phases["sample"]
-        # a greedy row's token comes off the device: nothing is chosen here
+        # a greedy row's token and an unsteered sampling row's come off the
+        # device: nothing is chosen here
         assert (phases["sample_choose"] > 0.0) == (picks_on_host > 0)
+        assert record["host_picked_rows"] == picks_on_host
+        assert record["device_picked_rows"] == picks_on_device
         assert (phases["sample_logprob"] > 0.0) == any(
             sp.logprobs for sp in MIXES[mix]
         )
@@ -505,7 +513,7 @@ class SpanSpy:
         assert SpanSpy.open_.pop() == self.name
 
 
-@pytest.mark.parametrize("mix", ["greedy", "mixed"])
+@pytest.mark.parametrize("mix", ["greedy", "mixed", "steered"])
 def test_serve_spans_nest_and_carry_their_stats(monkeypatch, mix):
     monkeypatch.setattr(SpanSpy, "spans", [])
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", SpanSpy)
@@ -542,12 +550,19 @@ def test_serve_spans_nest_and_carry_their_stats(monkeypatch, mix):
     ]
     # all greedy and no logprobs: the [B] argmax ids cross, no logits row
     ids, rows = 4 * 2, 4 * 2 * CFG.vocab_size
+    # mixed: the argmax ids, the picked ids and (logprobs) the logits rows;
+    # steered: the argmax ids and the rows the host picks from
     pulls = {s["bytes"] for name, s, _ in spans if name == "serve.step.pull"}
-    assert pulls == ({ids} if mix == "greedy" else {ids + rows})
-    sampled = {
-        s["sampled_rows"] for name, s, _ in spans if name == "serve.step.sample"
+    assert pulls == {
+        {"greedy": ids, "mixed": 2 * ids + rows, "steered": ids + rows}[mix]
     }
-    assert sampled == ({0} if mix == "greedy" else {1})
+    picked = {
+        (s["device_picked_rows"], s["host_picked_rows"])
+        for name, s, _ in spans if name == "serve.step.sample"
+    }
+    assert picked == {
+        {"greedy": (0, 0), "mixed": (1, 0), "steered": (0, 1)}[mix]
+    }
 
 
 TRACKED = {
@@ -556,6 +571,7 @@ TRACKED = {
     "_draft_decode": "draft_decode_step_paged",
     "_draft_prefill": "draft_prefill_forward",
     "_draft_window": "draft_decode_window_paged",
+    "_pick": "pick_tokens",
 }
 
 
