@@ -177,6 +177,7 @@ class Engine:
         pages_needed = self.batcher.validate_request(
             prompt, max_new_tokens, sampling=sampling, adapter=adapter,
             interleave_admission=interleave_admission,
+            prefill_chunk=prefill_chunk,
         )
         if prefill_chunk is not None and prefill_chunk < 1:
             raise ValueError(f"chunk must be >= 1, got {prefill_chunk}")

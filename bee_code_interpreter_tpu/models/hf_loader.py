@@ -44,8 +44,16 @@ Params = dict[str, Any]
 
 
 def config_from_hf(hf_config, dtype=jnp.bfloat16) -> TransformerConfig:
-    """Our TransformerConfig for an HF ``LlamaConfig``. Refuses silently
-    unloadable settings instead of approximating them."""
+    """Our TransformerConfig for an HF ``LlamaConfig``, or for a
+    ``GraniteMoeHybridConfig`` without routed experts (``model_type``
+    ``granitemoehybrid``, ``num_local_experts`` 0: Mamba-2 layers beside
+    attention layers). A plain dict of the published keys (a ``config.json``)
+    is taken like the object. Refuses silently unloadable settings instead
+    of approximating them."""
+    if isinstance(hf_config, dict):
+        import types
+
+        hf_config = types.SimpleNamespace(**hf_config)
     eps = getattr(hf_config, "rms_norm_eps", 1e-5)
     if abs(eps - 1e-5) > 1e-12:
         raise ValueError(
@@ -62,6 +70,8 @@ def config_from_hf(hf_config, dtype=jnp.bfloat16) -> TransformerConfig:
             f"hidden_act {act!r} unsupported (our MLP is SwiGLU/silu); "
             "refusing a silently wrong load"
         )
+    if getattr(hf_config, "model_type", None) == "granitemoehybrid":
+        return _granite_hybrid_config(hf_config, dtype)
     scaling = getattr(hf_config, "rope_scaling", None)
     rope_scaling = 1.0
     if scaling is not None:
@@ -95,6 +105,54 @@ def config_from_hf(hf_config, dtype=jnp.bfloat16) -> TransformerConfig:
         rope_theta=float(getattr(hf_config, "rope_theta", 10000.0)),
         rope_scaling=rope_scaling,
         dtype=dtype,
+    )
+
+
+def _granite_hybrid_config(hf_config, dtype) -> TransformerConfig:
+    """``granitemoehybrid`` without routed experts: every published key the
+    program has a field for, and a refusal by name for what it computes
+    otherwise."""
+    experts = getattr(hf_config, "num_local_experts", 0)
+    if experts:
+        raise ValueError(
+            f"num_local_experts {experts} unsupported for granitemoehybrid: "
+            "a layer pattern runs the shared SwiGLU MLP only (no routed "
+            "experts beside it)"
+        )
+    refused = {
+        "mamba_proj_bias": (False, "the mixer's projections have no bias"),
+        "mamba_conv_bias": (True, "the mixer's conv has a bias"),
+        "normalization_function": ("rmsnorm", "every norm is an RMSNorm"),
+    }
+    for key, (fixed, why) in refused.items():
+        value = getattr(hf_config, key, fixed)
+        if value != fixed:
+            raise ValueError(f"{key} {value!r} unsupported: {why}")
+    d_ff = getattr(hf_config, "shared_intermediate_size", hf_config.intermediate_size)
+    return TransformerConfig(
+        vocab_size=hf_config.vocab_size,
+        d_model=hf_config.hidden_size,
+        n_layers=hf_config.num_hidden_layers,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        d_ff=d_ff,
+        max_seq_len=hf_config.max_position_embeddings,
+        rope_theta=getattr(hf_config, "rope_theta", 10000.0),
+        dtype=dtype,
+        layer_types=tuple(hf_config.layer_types),
+        mamba_n_heads=hf_config.mamba_n_heads,
+        mamba_d_head=hf_config.mamba_d_head,
+        mamba_d_state=hf_config.mamba_d_state,
+        mamba_n_groups=hf_config.mamba_n_groups,
+        mamba_d_conv=hf_config.mamba_d_conv,
+        mamba_expand=hf_config.mamba_expand,
+        mamba_chunk_size=hf_config.mamba_chunk_size,
+        embedding_multiplier=hf_config.embedding_multiplier,
+        residual_multiplier=hf_config.residual_multiplier,
+        attention_multiplier=hf_config.attention_multiplier,
+        logits_scaling=hf_config.logits_scaling,
+        position_embedding=hf_config.position_embedding_type,
+        tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
     )
 
 

@@ -84,6 +84,7 @@ from bee_code_interpreter_tpu.ops.paged_kv_cache import (
     alloc_paged_cache,
     seed_from_contiguous,
     seed_prefill,
+    seed_state,
 )
 from bee_code_interpreter_tpu.parallel.mesh import mesh_shape_key
 from bee_code_interpreter_tpu.utils.jitwatch import TrackedJit
@@ -179,13 +180,24 @@ class SamplingParams:
         return bool(self.logit_bias) or self.allowed_tokens is not None
 
 
-def logprob_of(logits: np.ndarray, token: int) -> float:
-    """log P(token) under the raw (unfiltered) logits row — stable
-    log-softmax in f64, the one copy both the plain and speculative steps
-    use so reported logprobs cannot drift between paths."""
-    lg = logits.astype(np.float64)
-    m = lg.max()
-    return float(lg[token] - m - np.log(np.exp(lg - m).sum()))
+def logprob_of(logits: np.ndarray, token: int, log_z: float) -> float:
+    """log P(token) under the raw (unfiltered) logits row: the token's
+    logit less the row's normaliser ``log_z``, which ``log_normalizers``
+    computed on the device. The one rule of the admission's first token,
+    the plain step and the speculative rounds, so that reported logprobs
+    cannot drift between paths."""
+    return float(logits[token]) - float(log_z)
+
+
+def log_normalizers(logits: jax.Array) -> jax.Array:
+    """log sum exp over the vocabulary of logits rows [..., V], float32
+    [...]: what ``logprob_of`` subtracts, computed where the logits are and
+    queued behind the program that made them, wherever a row records
+    log-probabilities. (A float64 log-softmax on the host cost 0.6 ms a row
+    at a vocabulary of 100,352, so a step's host share followed the number
+    of such rows: the largest source of run-to-run spread; PERF.md, PR
+    29.)"""
+    return jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
 
 
 def filtered_probs_host(
@@ -518,9 +530,22 @@ class ContinuousBatcher:
         bar holds WITHIN a mesh (row independence is sharding-invariant);
         cross-mesh token equality additionally holds in the pinned test
         configs but reduction-order ulps make it environment-pinned, not
-        guaranteed (tests/test_serving_mesh.py)."""
+        guaranteed (tests/test_serving_mesh.py).
+
+        A config with MAMBA layers (``config.layer_types``) keeps each
+        row's recurrent state in the same pool as the K/V pages
+        (``ops.paged_kv_cache.alloc_paged_cache``): admission seeds the
+        row's state, whole, at the prompt's true length, and the decode
+        program advances every row's by a token. What cannot hold with
+        state kept by row is refused here by name (``_refuse_over_state``)
+        or at ``validate_request`` (chunked and interleaved admission)."""
+        self._refuse_over_state(
+            config, prefix_cache=prefix_cache, draft_params=draft_params,
+            adapters=adapters, mesh=mesh,
+        )
         self.params = params
         self.mesh = mesh
+        self.max_batch = max_batch
         # duck-typed observability.DeviceMonitor (compile/retrace tracking
         # + per-mesh-shape step telemetry); injected via
         # DeviceMonitor.attach -> set_device_monitor. None keeps every
@@ -673,6 +698,16 @@ class ContinuousBatcher:
             "decode_step_paged",
             donate_argnums=(3,),
         )
+        # what the mamba layers keep for a row, replaced whole at admission
+        self._seed_state = None
+        self._state_bytes_per_row = 0
+        if config.n_mamba_layers:
+            from bee_code_interpreter_tpu.models.mamba import state_bytes_per_row
+
+            self._seed_state = self._track(
+                seed_state, "seed_state", donate_argnums=(0,)
+            )
+            self._state_bytes_per_row = state_bytes_per_row(config)
         # Admission prefill. With a mesh the full forward runs under it —
         # in particular an ``sp`` axis shards the attention over the
         # sequence axis (ring or Ulysses per ``config.sp_attention``, via
@@ -720,6 +755,7 @@ class ContinuousBatcher:
             functools.partial(pick_tokens, replicated=replicated),
             "pick_tokens",
         )
+        self._log_normalizers = self._track(log_normalizers, "log_normalizers")
         if draft_config is not None:
             # the draft's own paged pool, addressed by the SAME block
             # tables/pages (one allocation covers both models' K/V)
@@ -824,6 +860,32 @@ class ContinuousBatcher:
             # (monotonic time, cumulative tokens) samples; the rate gauge
             # reads the spread so a scrape never pays more than a subtraction
             self._rate_samples: deque[tuple[float, int]] = deque(maxlen=512)
+
+    @staticmethod
+    def _refuse_over_state(config, *, prefix_cache, draft_params, adapters, mesh):
+        """What a config with mamba layers cannot be served with, each
+        refused by name: the features below assume that everything a row
+        keeps is K/V by position, which a page can share, a window can
+        overwrite and a mesh can split by head. Recurrent state is one
+        value a row, advanced in place."""
+        if not config.n_mamba_layers:
+            return
+        for asked, name, why in (
+            (prefix_cache, "prefix_cache",
+             "a shared page carries K/V of its tokens but not the recurrent "
+             "state after them"),
+            (draft_params is not None, "draft_params (speculative mode)",
+             "a rejected draft would have to roll the recurrent state back"),
+            (bool(adapters), "adapters",
+             "adapter admissions prefill through windows, which do not "
+             "carry the recurrent state"),
+            (mesh is not None, "mesh (tp > 1)",
+             "the recurrent state and the mixer are kept whole on one chip"),
+        ):
+            if asked:
+                raise NotImplementedError(
+                    f"{name} is not supported over mamba layers: {why}"
+                )
 
     # throughput gauge window: samples older than this are dropped at read
     # time, and a gauge whose newest sample is older reads 0 — an idle
@@ -963,6 +1025,11 @@ class ContinuousBatcher:
         }
         out["pages_allocated_total"] = self._pages_allocated
         out["pages_released_total"] = self._pages_released
+        # what mamba layers keep by row beside the pages (0 without them)
+        per_row = self._state_bytes_per_row
+        out["state_bytes_per_row"] = per_row
+        out["state_rows_live"] = int(self.active.sum()) if per_row else 0
+        out["state_bytes"] = per_row * self.max_batch
         return out
 
     # ----------------------------------------------------- snapshot/resume
@@ -1102,6 +1169,7 @@ class ContinuousBatcher:
         return alloc_paged_cache(
             config, n_pages, self.page_size,
             sharding=None if self.mesh is None else self._pool_sharding(),
+            max_batch=self.max_batch,
         )
 
     def _shard_pool(self, pool: dict) -> dict:
@@ -1129,6 +1197,7 @@ class ContinuousBatcher:
         sampling: SamplingParams | None = None,
         adapter: int | None = None,
         interleave_admission: int | None = None,
+        prefill_chunk: int | None = None,
     ) -> int:
         """Capacity-independent request validation; returns the page count
         the request will need. The ONE copy of the admission arithmetic:
@@ -1142,6 +1211,17 @@ class ContinuousBatcher:
         L = int(prompt.shape[0])
         if L < 1:
             raise ValueError("prompt must be non-empty")
+        if self.config.n_mamba_layers:
+            for asked, name in (
+                (prefill_chunk, "prefill_chunk"),
+                (interleave_admission, "interleave_admission"),
+            ):
+                if asked is not None:
+                    raise NotImplementedError(
+                        f"{name} is not supported over mamba layers: the "
+                        "chunks and windows of such an admission do not "
+                        "carry the recurrent state from one to the next"
+                    )
         if interleave_admission is not None and (
             interleave_admission < self.page_size
             or interleave_admission % self.page_size
@@ -1234,6 +1314,7 @@ class ContinuousBatcher:
         n_need = self.validate_request(
             prompt, max_new_tokens, sampling=sampling, adapter=adapter,
             interleave_admission=interleave_admission,
+            prefill_chunk=prefill_chunk,
         )
         L = int(prompt.shape[0])
         # internal index: 0 is the all-zeros base adapter in the bank
@@ -1368,6 +1449,7 @@ class ContinuousBatcher:
         """The blocking admission tail of ``submit``: run the prefill,
         release pages on failure, activate the row. Split out so ``submit``
         can activate the request's trace around the whole region."""
+        logprobs = sampling is not None and sampling.logprobs
         try:
             if matched or adapter_internal > 0:
                 # Window-prefill admissions: shared-prefix hits AND every
@@ -1389,13 +1471,14 @@ class ContinuousBatcher:
                         name: x.at[:, fresh_arr].set(0)
                         for name, x in self.draft_cache.items()
                     }
-                last_row = self._suffix_admit(
+                last = self._suffix_admit(
                     row, prompt, matched, speculative, prefill_chunk,
-                    adapter_internal,
+                    adapter_internal, logprobs=logprobs,
                 )
             else:
-                last_row = self._full_admit(
-                    prompt, pages, L, speculative, prefill_chunk
+                last = self._full_admit(
+                    row, prompt, pages, L, speculative, prefill_chunk,
+                    logprobs=logprobs,
                 )
         except BaseException as e:
             # a failed admission (prefill OOM, bad sampling params, ...)
@@ -1415,21 +1498,32 @@ class ContinuousBatcher:
         self._t_submit = t_submit
         with self._phase("serve.admit.activate"):
             return self._activate_row(
-                row, last_row, prompt, pages, hashes, L, sampling,
+                row, last, prompt, pages, hashes, L, sampling,
                 max_new_tokens, adapter_internal, req=req, propagate=True,
             )
 
+    def _pull_last_row(self, logits_row, logprobs: bool):
+        """The last prompt token's logits row [V] off the device, with its
+        normaliser where the request records log-probabilities (else
+        None): what ``_activate_row`` picks and reports the first token
+        from."""
+        log_z = self._log_normalizers(logits_row) if logprobs else None
+        row = np.asarray(logits_row, dtype=np.float32)
+        return row, None if log_z is None else float(log_z)
+
     def _activate_row(
-        self, row, last_row, prompt, pages, hashes, L, sampling,
+        self, row, last, prompt, pages, hashes, L, sampling,
         max_new_tokens, adapter_internal, req, propagate=False,
     ) -> int:
         """Admission epilogue, shared by the blocking path and interleaved
-        finalization: register prefix pages, sample the first token,
-        activate the row. ``req`` was allocated by ``submit``;
+        finalization: register prefix pages, sample the first token (from
+        ``last``, ``_pull_last_row``'s pair), activate the row. ``req`` was
+        allocated by ``submit``;
         ``propagate`` re-raises first-token failures (the blocking path —
         the caller never received the id) instead of recording them on the
         ticket (interleaved finalization — submit returned long ago)."""
         sampling = sampling or SamplingParams()
+        last_row, log_z = last
         try:
             # rng construction INSIDE the protected region: a bad seed
             # must release the pages like any other first-token failure
@@ -1523,7 +1617,7 @@ class ContinuousBatcher:
                 self._t_submit = None
             self._sync_token_counter()
         if sampling.logprobs:
-            self.results_logprobs[req] = [logprob_of(last_row, first)]
+            self.results_logprobs[req] = [logprob_of(last_row, first, log_z)]
         self.done[req] = False
         self.active[row] = True
         self._retire_if_done(row)
@@ -1565,7 +1659,10 @@ class ContinuousBatcher:
                     )
             idx = rec["L"] - 1 - rec["pos"]  # last REAL token in window?
             if 0 <= idx < win.shape[0]:
-                rec["last_row"] = np.asarray(logits[0, idx], dtype=np.float32)
+                rec["last_row"] = self._pull_last_row(
+                    logits[0, idx],
+                    rec["sampling"] is not None and rec["sampling"].logprobs,
+                )
             rec["pos"] += int(win.shape[0])
             self._prefill_tokens += int(win.shape[0])
             if self._monitor is not None:
@@ -1589,10 +1686,12 @@ class ContinuousBatcher:
                 )
 
     # ------------------------------------------------- admission sub-paths
-    def _full_admit(self, prompt, pages, L, speculative, prefill_chunk):
+    def _full_admit(self, row, prompt, pages, L, speculative, prefill_chunk,
+                    logprobs=False):
         """Whole-prompt BASE admission (no prefix hit, no adapters — those
         route through ``_suffix_admit``): one-shot or chunked prefill into
-        this row's pages; returns the last prompt token's logits row."""
+        this row's pages (and, over mamba layers, the row's state); returns
+        the last prompt token's logits row as ``_pull_last_row`` gives it."""
         n_prompt_pages = -(-L // self.page_size)
         pages_arr = jnp.asarray(pages[:n_prompt_pages], dtype=jnp.int32)
         # the prompt padded to a whole number of pages — shared by the
@@ -1632,7 +1731,7 @@ class ContinuousBatcher:
                     {name: x[:, 0] for name, x in contig.items()},
                 )
             with self._phase("serve.admit.pull"):
-                last_row = np.asarray(last_logits[0], dtype=np.float32)
+                last = self._pull_last_row(last_logits[0], logprobs)
         else:
             # one-shot prefill: exact O(L^2) forward, then the shared
             # one-scatter-per-leaf page seeding (seed_prefill — the
@@ -1643,17 +1742,30 @@ class ContinuousBatcher:
             # prompt lengths share a program per page count instead of
             # one per length.
             with self._phase("serve.admit.prefill"):
-                logits, (k_pre, v_pre) = self._prefill(
-                    self.params, padded[None, :]
-                )
+                if self._seed_state is None:
+                    logits, (k_pre, v_pre) = self._prefill(
+                        self.params, padded[None, :]
+                    )
+                else:  # the state it hands back is that of the L real tokens
+                    logits, (k_pre, v_pre, ssm, conv) = self._prefill(
+                        self.params, padded[None, :], length=np.int32(L)
+                    )
             with self._phase("serve.admit.seed_pool"):
                 self.cache = seed_prefill(
                     self.cache, pages_arr,
                     k_pre[:, 0, :, :L, :], v_pre[:, 0, :, :L, :],
                 )
+            if self._seed_state is not None:
+                with self._phase(
+                    "serve.admit.seed_state", rows=1,
+                    bytes=ssm.nbytes + conv.nbytes,
+                ):
+                    self.cache = self._seed_state(
+                        self.cache, np.int32(row), ssm, conv
+                    )
             # waits for the device (prefill and seeding), then copies
             with self._phase("serve.admit.pull"):
-                last_row = np.asarray(logits[0, L - 1, :], dtype=np.float32)
+                last = self._pull_last_row(logits[0, L - 1, :], logprobs)
         if speculative:
             # draft prefill into ITS pool at the same pages (the draft
             # is small — the padded one-shot prefill is fine even when
@@ -1665,10 +1777,10 @@ class ContinuousBatcher:
                 self.draft_cache, pages_arr,
                 dk[:, 0, :, :L, :], dv[:, 0, :, :L, :],
             )
-        return last_row
+        return last
 
     def _suffix_admit(self, row, prompt, matched, speculative, prefill_chunk,
-                      adapter_internal=0):
+                      adapter_internal=0, logprobs=False):
         """Window-prefill admission — prefix-cache hits (``matched`` > 0:
         only the suffix runs through the model) AND every adapter
         admission (``matched`` == 0: the whole prompt is the suffix) — as
@@ -1687,7 +1799,8 @@ class ContinuousBatcher:
         could see it. In speculative mode the draft pool replays the same
         windows so both caches stay in lockstep.
 
-        Returns the last prompt token's logits row."""
+        Returns the last prompt token's logits row as ``_pull_last_row``
+        gives it."""
         ps = self.page_size
         L = int(prompt.shape[0])
         start = matched * ps
@@ -1700,7 +1813,7 @@ class ContinuousBatcher:
         suffix = np.zeros((-(-(L - start) // ps)) * ps, dtype=np.int32)
         suffix[: L - start] = prompt[start:]
         bt_row = jnp.asarray(self.block_table[row:row + 1])
-        last_row = None
+        last = None
         pos = start
         for off in range(0, len(suffix), chunk_pages * ps):
             win = suffix[off: off + chunk_pages * ps]
@@ -1717,9 +1830,9 @@ class ContinuousBatcher:
                 )
             idx = L - 1 - pos  # last REAL token's index within this window
             if 0 <= idx < win.shape[0]:
-                last_row = np.asarray(logits[0, idx], dtype=np.float32)
+                last = self._pull_last_row(logits[0, idx], logprobs)
             pos += int(win.shape[0])
-        return last_row
+        return last
 
     # ------------------------------------------------------------ multi-LoRA
     def _lora_kwargs(self, adapter_rows: np.ndarray) -> dict:
@@ -1948,9 +2061,10 @@ class ContinuousBatcher:
             # the common case moves [B] int32s; the full [max_batch, V]
             # logits cross to host only when some active row is steered or
             # records logprobs
-            need_rows = bool(host_rows) or any(
+            logprob_rows = any(
                 self.row_sampling[row].logprobs for row in active_rows
             )
+            need_rows = bool(host_rows) or logprob_rows
             # ...and the device argmax + its [B] pull only runs when some
             # active row actually decodes greedily: an all-sampled batch
             # was paying an argmax kernel and a host sync per token for an
@@ -1977,16 +2091,19 @@ class ContinuousBatcher:
                     draw,
                 )
             last = logits[:, -1, :] if need_greedy or need_rows else None
+            log_z = self._log_normalizers(last) if logprob_rows else None
             greedy = jnp.argmax(last, axis=-1) if need_greedy else None
             lg = last if need_rows else None
         with self._phase("serve.step.wait"):
-            jax.block_until_ready((greedy, picked, lg))
+            jax.block_until_ready((greedy, picked, lg, log_z))
         with self._phase(
             "serve.step.pull",
             bytes=sum(
-                x.nbytes for x in (greedy, picked, lg) if x is not None
+                x.nbytes for x in (greedy, picked, lg, log_z) if x is not None
             ),
         ):
+            if logprob_rows:
+                log_z = np.asarray(log_z, dtype=np.float64)
             if need_greedy:
                 greedy = np.asarray(greedy, dtype=np.int32)
             if device_rows:
@@ -2032,7 +2149,7 @@ class ContinuousBatcher:
                 self.n_tokens_generated += 1
                 if sp.logprobs:
                     self.results_logprobs[req_row].append(
-                        logprob(lg[row], nxt)
+                        logprob(lg[row], nxt, log_z[row])
                     )
                 self._retire_if_done(int(row))
 
@@ -2094,19 +2211,18 @@ class ContinuousBatcher:
         # full verify logits cross to host only when some row records
         # logprobs (commit[j]'s distribution is t_logits[row, j] — the
         # target's prediction for the token following window position j)
-        t_np = (
-            np.asarray(t_logits, dtype=np.float32)
-            if any(self.row_sampling[row].logprobs for row in active_rows)
-            else None
-        )
+        t_np = t_log_z = None
+        if any(self.row_sampling[row].logprobs for row in active_rows):
+            t_log_z = np.asarray(self._log_normalizers(t_logits))
+            t_np = np.asarray(t_logits, dtype=np.float32)
 
         for row in active_rows:
             match = drafts_np[row] == t_pred[row, : self.gamma]
             n = int(np.argmin(match)) if not match.all() else self.gamma
             commit = [*drafts_np[row, :n].tolist(), int(t_pred[row, n])]
-            self._commit_row(row, commit, n, t_np)
+            self._commit_row(row, commit, n, t_np, t_log_z)
 
-    def _commit_row(self, row, commit, n, t_np) -> None:
+    def _commit_row(self, row, commit, n, t_np, t_log_z) -> None:
         """Land one speculative round's committed tokens for a row —
         per-token stop checks, logprobs off the verify logits, cursor
         advance by accepted+1, retirement. The ONE copy shared by the
@@ -2125,7 +2241,9 @@ class ContinuousBatcher:
             out.append(int(tok_committed))
             self.n_tokens_generated += 1
             if lp is not None:
-                lp.append(logprob_of(t_np[row, j], int(tok_committed)))
+                lp.append(logprob_of(
+                    t_np[row, j], int(tok_committed), t_log_z[row, j]
+                ))
             if self._done_reason(row, out) is not None:
                 break  # later commits would exceed the stop — drop them
         self.pos[row] += n + 1
@@ -2188,6 +2306,11 @@ class ContinuousBatcher:
             self.params, window, pos_dev, self.cache, bt,
             **self._lora_kwargs(self.row_adapter),
         )
+        t_log_z = (
+            np.asarray(self._log_normalizers(t_logits))
+            if any(self.row_sampling[row].logprobs for row in active_rows)
+            else None
+        )
         t_np = np.asarray(t_logits, dtype=np.float32)  # [B, gamma+1, V]
 
         for row in active_rows:
@@ -2207,7 +2330,7 @@ class ContinuousBatcher:
                     ),
                     rng,
                 )
-            self._commit_row(row, commit, n, t_np)
+            self._commit_row(row, commit, n, t_np, t_log_z)
 
     def _done_reason(self, row: int, out: list[int]) -> tuple[str, int] | None:
         """(finish_reason, tokens_to_trim) once a row's output is complete,

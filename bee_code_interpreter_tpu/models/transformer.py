@@ -43,6 +43,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from bee_code_interpreter_tpu.parallel.ring_attention import ring_attention
 
 Params = dict[str, Any]
+# an attention layer's own leaves (the norms and the MLP are every layer's)
+ATTENTION_LEAVES = ("wq", "wk", "wv", "wo")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,6 +103,98 @@ class TransformerConfig:
     # (W == 1) on bf16 pools with full causal attention; other shapes and
     # the int8 pool keep the einsum path.
     paged_attention_kernel: bool = False
+    # A declared layer pattern: one of "attention" / "mamba" per layer (the
+    # published ``layer_types`` list; frozen to a tuple, since the config is
+    # a static jit argument). None = attention in every layer, the one
+    # ``lax.scan`` below. With a pattern the stack is scanned a PERIOD at a
+    # time (``layer_period``) and each kind's leaves are stacked over the
+    # layers of that kind alone. Every layer keeps its SwiGLU MLP.
+    layer_types: tuple[str, ...] | None = None
+    # Mamba-2 mixer sizes (published ``mamba_*`` keys): heads x head size is
+    # the inner width (``mamba_expand`` x d_model), B and C are shared by
+    # the heads of a group, the depthwise conv spans ``mamba_d_conv``
+    # positions, and the prefill scans in chunks of ``mamba_chunk_size``.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    # Granite's scalars: h = embedding_multiplier * embed[tokens]; every
+    # residual branch is scaled by residual_multiplier; attention scores by
+    # attention_multiplier (None = 1/sqrt(head_dim)); logits are divided by
+    # logits_scaling.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None
+    logits_scaling: float = 1.0
+    # "rope", or the published "nope": no position embedding at all
+    position_embedding: str = "rope"
+    # the output head is the embedding transposed: no ``lm_head`` leaf
+    tie_embeddings: bool = False
+
+    def __post_init__(self) -> None:
+        if self.position_embedding not in ("rope", "nope"):
+            raise ValueError(
+                f"position_embedding must be 'rope' or 'nope', got "
+                f"{self.position_embedding!r}"
+            )
+        if self.layer_types is None:
+            return
+        types_ = tuple(self.layer_types)
+        object.__setattr__(self, "layer_types", types_)
+        unknown = set(types_) - {"attention", "mamba"}
+        if unknown or len(types_) != self.n_layers:
+            raise ValueError(
+                f"layer_types must name 'attention' or 'mamba' for each of "
+                f"{self.n_layers} layers, got {len(types_)} entries"
+                + (f" with {sorted(unknown)}" if unknown else "")
+            )
+        if self.n_experts:
+            raise ValueError("a layer pattern with expert MLPs is unsupported")
+        if "mamba" in types_ and (
+            self.mamba_n_heads * self.mamba_d_head
+            != self.mamba_expand * self.d_model
+            or self.mamba_d_state < 1
+            or self.mamba_n_heads % self.mamba_n_groups
+        ):
+            raise ValueError(
+                f"mamba sizes do not agree: {self.mamba_n_heads} heads of "
+                f"{self.mamba_d_head} against mamba_expand "
+                f"{self.mamba_expand} x d_model {self.d_model}, state "
+                f"{self.mamba_d_state}, groups {self.mamba_n_groups}"
+            )
+
+    @property
+    def n_attention_layers(self) -> int:
+        if self.layer_types is None:
+            return self.n_layers
+        return self.layer_types.count("attention")
+
+    @property
+    def n_mamba_layers(self) -> int:
+        return 0 if self.layer_types is None else self.layer_types.count("mamba")
+
+    @property
+    def layer_period(self) -> int:
+        """The shortest period of ``layer_types`` (n_layers where it has
+        none): the layers one iteration of the scan unrolls."""
+        types_ = self.layer_types
+        n = len(types_)
+        for p in range(1, n + 1):
+            if n % p == 0 and all(types_[i] == types_[i % p] for i in range(n)):
+                return p
+        return n
+
+    @property
+    def mamba_d_inner(self) -> int:
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_channels(self) -> int:
+        """x, B and C go through the conv together."""
+        return self.mamba_d_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
     def kv_heads(self) -> int:
@@ -209,42 +303,74 @@ def qeinsum(spec: str, x: jax.Array, leaf, dtype) -> jax.Array:
 
 
 def init_params(config: TransformerConfig, key: jax.Array) -> Params:
-    """f32 master params; stacked [n_layers, ...] leading axis for lax.scan."""
+    """f32 master params; stacked [n_layers, ...] leading axis for lax.scan.
+
+    With a layer pattern (``config.layer_types``) every layer-stacked leaf
+    still sits under ``layers``, each stacked over the layers that have it:
+    the norms and the MLP over all of them, ``wq wk wv wo`` over the
+    attention layers, the mixer's leaves over the mamba layers. A tied head
+    (``tie_embeddings``) has no ``lm_head`` leaf."""
     c = config
     k_embed, k_layers, k_out = jax.random.split(key, 3)
 
     def dense(key, fan_in, *shape):
         return jax.random.normal(key, shape, dtype=jnp.float32) / math.sqrt(fan_in)
 
-    def layer(key):
-        ks = jax.random.split(key, 7)
+    def attention(ks):
         dh, kvh = c.head_dim, c.kv_heads
-        out = {
-            "ln1": jnp.ones((c.d_model,), jnp.float32),
+        return {
             "wq": dense(ks[0], c.d_model, c.d_model, c.n_heads * dh),
             "wk": dense(ks[1], c.d_model, c.d_model, kvh * dh),
             "wv": dense(ks[2], c.d_model, c.d_model, kvh * dh),
             "wo": dense(ks[3], c.n_heads * dh, c.n_heads * dh, c.d_model),
+        }
+
+    def mlp(ks):
+        out = {
+            "ln1": jnp.ones((c.d_model,), jnp.float32),
             "ln2": jnp.ones((c.d_model,), jnp.float32),
         }
         if c.n_experts:
             from bee_code_interpreter_tpu.models.moe import init_moe_params
 
-            out["moe"] = init_moe_params(ks[4], c.d_model, c.ff_dim, c.n_experts)
+            out["moe"] = init_moe_params(ks[0], c.d_model, c.ff_dim, c.n_experts)
         else:
-            out["w_gate"] = dense(ks[4], c.d_model, c.d_model, c.ff_dim)
-            out["w_up"] = dense(ks[5], c.d_model, c.d_model, c.ff_dim)
-            out["w_down"] = dense(ks[6], c.ff_dim, c.ff_dim, c.d_model)
+            out["w_gate"] = dense(ks[0], c.d_model, c.d_model, c.ff_dim)
+            out["w_up"] = dense(ks[1], c.d_model, c.d_model, c.ff_dim)
+            out["w_down"] = dense(ks[2], c.ff_dim, c.ff_dim, c.d_model)
         return out
 
-    layer_keys = jax.random.split(k_layers, c.n_layers)
-    stacked = jax.vmap(layer)(layer_keys)
-    return {
+    def per_layer(part, n_keys, key, n):
+        return jax.vmap(lambda k: part(jax.random.split(k, n_keys)))(
+            jax.random.split(key, n)
+        )
+
+    if c.layer_types is None:
+        def layer(key):
+            ks = jax.random.split(key, 7)
+            return {**attention(ks[:4]), **mlp(ks[4:])}
+
+        stacked = jax.vmap(layer)(jax.random.split(k_layers, c.n_layers))
+    else:
+        k_attn, k_mamba, k_mlp = jax.random.split(k_layers, 3)
+        stacked = {
+            **per_layer(mlp, 3, k_mlp, c.n_layers),
+            **per_layer(attention, 4, k_attn, c.n_attention_layers),
+        }
+        if c.n_mamba_layers:
+            from bee_code_interpreter_tpu.models.mamba import init_mixer_params
+
+            stacked.update(jax.vmap(
+                functools.partial(init_mixer_params, c)
+            )(jax.random.split(k_mamba, c.n_mamba_layers)))
+    params = {
         "embed": dense(k_embed, c.d_model, c.vocab_size, c.d_model),
         "layers": stacked,
         "ln_f": jnp.ones((c.d_model,), jnp.float32),
-        "lm_head": dense(k_out, c.d_model, c.d_model, c.vocab_size),
     }
+    if not c.tie_embeddings:
+        params["lm_head"] = dense(k_out, c.d_model, c.d_model, c.vocab_size)
+    return params
 
 
 def param_specs(config: TransformerConfig, mesh: Mesh) -> Params:
@@ -273,12 +399,20 @@ def param_specs(config: TransformerConfig, mesh: Mesh) -> Params:
         layer["w_gate"] = _stack(col)
         layer["w_up"] = _stack(col)
         layer["w_down"] = _stack(row)
-    return {
+    if config.n_mamba_layers:
+        # the mixer is replicated: its heads share B and C, and the state it
+        # keeps by row is whole on every chip (serving refuses tp over it)
+        from bee_code_interpreter_tpu.models.mamba import MIXER_LEAVES
+
+        layer.update({name: P() for name in MIXER_LEAVES})
+    specs = {
         "embed": P(tp, None),     # vocab-sharded embedding
         "layers": layer,
         "ln_f": rep,
-        "lm_head": P(None, tp),   # column-parallel output projection
     }
+    if not config.tie_embeddings:
+        specs["lm_head"] = P(None, tp)   # column-parallel output projection
+    return specs
 
 
 def _stack(spec: P) -> P:
@@ -315,20 +449,27 @@ def shard_params(params: Params, config: TransformerConfig, mesh: Mesh) -> Param
 # ------------------------------------------------------------------- forward
 
 
-def _local_attention(q, k, v, causal: bool = True, window: int | None = None):
+def _local_attention(
+    q, k, v, causal: bool = True, window: int | None = None,
+    sm_scale: float | None = None,
+):
     """Single-shard attention — the shared ops-level platform dispatch
     (Pallas flash on TPU, reference elsewhere; GQA-native)."""
     from bee_code_interpreter_tpu.ops.flash_attention import local_attention
 
-    return local_attention(q, k, v, causal=causal, window=window)
+    return local_attention(
+        q, k, v, causal=causal, window=window, sm_scale=sm_scale
+    )
 
 
 def _attention(
     q, k, v, mesh: Mesh | None, sp_attention: str = "ring",
     causal: bool = True, window: int | None = None,
+    sm_scale: float | None = None,
 ):
     """Attention (causal by default; ``causal=False`` for encoders — the
-    ViT path); q [B, H, L, D], k/v [B, KVH, L, D] (KVH ≤ H).
+    ViT path); q [B, H, L, D], k/v [B, KVH, L, D] (KVH ≤ H). ``sm_scale``
+    scales the scores (None = 1/sqrt(D)).
 
     K/V stay compact through the whole path (flash kernel index-maps KV
     heads, the ring rotates KVH-sized blocks) — GQA never materializes the
@@ -344,7 +485,7 @@ def _attention(
             f"sp_attention must be 'ring' or 'ulysses', got {sp_attention!r}"
         )
     if mesh is None:
-        return _local_attention(q, k, v, causal, window)
+        return _local_attention(q, k, v, causal, window, sm_scale)
     axes = mesh.axis_names
     tp = "tp" if "tp" in axes else None
     has_sp = "sp" in axes and mesh.shape["sp"] > 1
@@ -362,6 +503,11 @@ def _attention(
     spec = P(_batch_axes(mesh), tp, sp, None)
 
     if has_sp:
+        if sm_scale is not None:
+            raise NotImplementedError(
+                "a caller-given attention scale is not threaded through the "
+                "sp ring / Ulysses paths"
+            )
         # sliding_window rides both sp strategies: the ring masks per hop in
         # global offsets (parallel/ring_attention.py), Ulysses applies the
         # ordinary local mask after its sequence gather (parallel/ulysses.py)
@@ -379,7 +525,9 @@ def _attention(
                 ring_attention, axis_name="sp", causal=causal, window=window
             )
     else:
-        local = functools.partial(_local_attention, causal=causal, window=window)
+        local = functools.partial(
+            _local_attention, causal=causal, window=window, sm_scale=sm_scale
+        )
     # pallas_call under shard_map's vma checking hits a jax-internal lowering
     # limitation (see tests/test_parallel.py flash-ring cases); every
     # uses_flash() branch here runs the kernel (local, flash-hop ring, or
@@ -393,6 +541,22 @@ def _attention(
         check_vma=not uses_pallas,
     )
     return fn(q, k, v)
+
+
+def _positioned(x, positions, config: TransformerConfig):
+    """q or k with the configuration's position embedding applied: rotary,
+    or nothing at all (the published "nope")."""
+    if config.position_embedding == "nope":
+        return x
+    return rope(x, positions, config.rope_theta, config.rope_scaling)
+
+
+def _residual(h, branch, config: TransformerConfig):
+    """h + residual_multiplier * branch (the multiplier is 1 for every
+    architecture but Granite's, and then nothing is multiplied)."""
+    if config.residual_multiplier == 1.0:
+        return h + branch
+    return h + branch * jnp.asarray(config.residual_multiplier, branch.dtype)
 
 
 def _layer_apply(
@@ -418,19 +582,40 @@ def _layer_apply(
         out = qeinsum("bld,dk->blk", x, w, c.dtype)
         return out.reshape(B, L, heads, dh).transpose(0, 2, 1, 3)
 
-    q = rope(proj(layer["wq"], nh), positions, c.rope_theta, c.rope_scaling)
-    k = rope(proj(layer["wk"], kvh), positions, c.rope_theta, c.rope_scaling)
+    q = _positioned(proj(layer["wq"], nh), positions, c)
+    k = _positioned(proj(layer["wk"], kvh), positions, c)
     v = proj(layer["wv"], kvh)
     kv_out = (k, v) if return_kv else None
     # GQA-native: compact k/v go in as-is
-    attn = _attention(q, k, v, mesh, c.sp_attention, window=c.sliding_window)
+    attn = _attention(
+        q, k, v, mesh, c.sp_attention, window=c.sliding_window,
+        sm_scale=c.attention_multiplier,
+    )
     attn = attn.transpose(0, 2, 1, 3).reshape(B, L, nh * dh)
-    h = h + constrain(qeinsum("blk,kd->bld", attn, layer["wo"], c.dtype))
-
-    y = rms_norm(h, layer["ln2"])
-    mlp, aux = _mlp_block(y, layer, c)
-    h = h + constrain(mlp)
+    h = _residual(
+        h, constrain(qeinsum("blk,kd->bld", attn, layer["wo"], c.dtype)), c
+    )
+    h, aux = _mlp_residual(h, layer, c, constrain)
     return h, kv_out, aux
+
+
+def _mlp_residual(h, layer, config, constrain=lambda x: x):
+    """The MLP half of every layer kind: (h + mlp(rmsnorm(h)), aux)."""
+    y = rms_norm(h, layer["ln2"])
+    mlp, aux = _mlp_block(y, layer, config)
+    return _residual(h, constrain(mlp), config), aux
+
+
+def _mamba_layer_apply(h, layer, config, length, constrain=lambda x: x):
+    """One mamba layer over whole sequences: (h, (state at ``length``, conv
+    tail before it)). See models/mamba.py."""
+    from bee_code_interpreter_tpu.models.mamba import mixer_prefill
+
+    mix, state, tail = mixer_prefill(
+        rms_norm(h, layer["ln1"]), layer, config, length
+    )
+    h, _ = _mlp_residual(_residual(h, constrain(mix), config), layer, config, constrain)
+    return h, (state, tail)
 
 
 def _mlp_block(
@@ -464,6 +649,73 @@ def _batch_axes(mesh: Mesh | None):
     return batch_axes(mesh)
 
 
+def _embed(params: Params, tokens, config: TransformerConfig):
+    h = params["embed"].astype(config.dtype)[tokens]
+    if config.embedding_multiplier != 1.0:
+        h = h * jnp.asarray(config.embedding_multiplier, h.dtype)
+    return h
+
+
+def _head(params: Params, h, config: TransformerConfig):
+    """Final norm and output head: logits [B, L, vocab] in float32. A tied
+    head multiplies by the embedding transposed; ``logits_scaling`` divides
+    the result."""
+    c = config
+    h = rms_norm(h, params["ln_f"])
+    if c.tie_embeddings:
+        logits = qeinsum("bld,vd->blv", h, params["embed"], c.dtype)
+    else:
+        logits = qeinsum("bld,dv->blv", h, params["lm_head"], c.dtype)
+    logits = logits.astype(jnp.float32)
+    if c.logits_scaling != 1.0:
+        logits = logits / c.logits_scaling
+    return logits
+
+
+def _pattern_layer(layers: Params, config: TransformerConfig, period_index, j):
+    """Layer ``j`` of period ``period_index`` (traced) of a declared
+    pattern: its kind, its index among the layers of that kind, and its
+    leaves taken out of the stacks (the norms and MLP are stacked over all
+    layers, a kind's own leaves over the layers of that kind)."""
+    from bee_code_interpreter_tpu.models.mamba import MIXER_LEAVES
+
+    c = config
+    period = c.layer_types[:c.layer_period]
+    kind = period[j]
+    of_kind = period_index * period.count(kind) + period[:j].count(kind)
+    own = MIXER_LEAVES if kind == "mamba" else ATTENTION_LEAVES
+
+    def take(name, index):
+        return lax.dynamic_index_in_dim(layers[name], index, 0, keepdims=False)
+
+    layer = {name: take(name, of_kind) for name in own}
+    layer.update({
+        name: take(name, period_index * len(period) + j)
+        for name in layers if name not in MIXER_LEAVES + ATTENTION_LEAVES
+    })
+    return kind, of_kind, layer
+
+
+def _n_periods(config: TransformerConfig) -> int:
+    return config.n_layers // config.layer_period
+
+
+def _one_layer_kind(config: TransformerConfig, what: str) -> None:
+    if config.layer_types is not None:
+        raise NotImplementedError(
+            f"{what} runs one layer kind under its scan; a declared layer "
+            "pattern runs through forward and decode_step_paged"
+        )
+
+
+def _scaled_scores(scores, config: TransformerConfig):
+    """Decode-path attention scores, scaled: by ``attention_multiplier``
+    where the configuration gives one, else divided by sqrt(head_dim)."""
+    if config.attention_multiplier is not None:
+        return scores * config.attention_multiplier
+    return scores / math.sqrt(config.head_dim)
+
+
 def forward(
     params: Params,
     tokens: jax.Array,  # [B, L] int32
@@ -471,12 +723,20 @@ def forward(
     mesh: Mesh | None = None,
     return_kv: bool = False,
     return_aux: bool = False,
+    length: jax.Array | None = None,
 ) -> jax.Array | tuple:
     """Returns logits [B, L, vocab] (f32).
 
     With ``return_kv`` (the prefill half of cached decoding), also returns the
     per-layer post-RoPE K/V stacked [n_layers, B, kv_heads, L, head_dim] —
-    pre-GQA-broadcast, so the cache stores kv_heads not n_heads.
+    pre-GQA-broadcast, so the cache stores kv_heads not n_heads. Under a
+    layer pattern with mamba layers K and V are stacked over the attention
+    layers alone and the tuple goes on with what the mamba layers keep:
+    (k, v, ssm [mamba layers, B, heads, head size, state] f32, conv [mamba
+    layers, B, d_conv - 1, channels]), both at the sequences' true
+    ``length`` (a traced int32 scalar; None = L): positions at or beyond it
+    leave the state untouched, so a prompt padded to a page multiple seeds
+    the state of its real tokens.
     With ``return_aux`` (MoE training), also returns the summed per-layer
     load-balancing auxiliary loss (0.0 for dense configs).
     """
@@ -500,22 +760,44 @@ def forward(
     batch_ax = _batch_axes(mesh)
     positions = jnp.broadcast_to(jnp.arange(L, dtype=jnp.int32), (B, L))
 
-    h = params["embed"].astype(c.dtype)[tokens]  # [B, L, D]
+    h = _embed(params, tokens, c)  # [B, L, D]
     h = constrain(h, batch_ax, sp, None)
+    constrain_h = lambda x: constrain(x, batch_ax, sp, None)  # noqa: E731
 
     def layer_step(h, layer):
         h, kv_out, aux = _layer_apply(
             h, layer, c, positions,
             mesh=mesh,
-            constrain=lambda x: constrain(x, batch_ax, sp, None),
+            constrain=constrain_h,
             return_kv=return_kv,
         )
         return h, (kv_out, aux)
 
-    h, (kv, aux_layers) = lax.scan(layer_step, h, params["layers"])
-    h = rms_norm(h, params["ln_f"])
-    logits = qeinsum("bld,dv->blv", h, params["lm_head"], c.dtype)
-    logits = logits.astype(jnp.float32)
+    def period_step(h, period_index):
+        """One period of a declared pattern, its layers unrolled."""
+        kv, state = [], []
+        for j in range(c.layer_period):
+            kind, _, layer = _pattern_layer(params["layers"], c, period_index, j)
+            if kind == "mamba":
+                h, kept = _mamba_layer_apply(h, layer, c, length, constrain_h)
+                state.append(kept)
+            else:
+                h, kept, _ = _layer_apply(
+                    h, layer, c, positions, mesh=mesh, constrain=constrain_h,
+                    return_kv=return_kv,
+                )
+                kv.append(kept)
+        stack = lambda xs: tuple(jnp.stack(x) for x in zip(*xs))  # noqa: E731
+        return h, (stack(kv), stack(state)) if return_kv else None
+
+    if c.layer_types is None:
+        h, (kv, aux_layers) = lax.scan(layer_step, h, params["layers"])
+    else:
+        h, kept = lax.scan(period_step, h, jnp.arange(_n_periods(c)))
+        aux_layers = jnp.zeros((), jnp.float32)
+        if return_kv:  # [periods, a period's layers of a kind, ...] -> [layers, ...]
+            kv = tuple(x.reshape(-1, *x.shape[2:]) for part in kept for x in part)
+    logits = _head(params, h, c)
     extras = []
     if return_kv:
         extras.append(kv)
@@ -564,13 +846,14 @@ def forward_pipelined(
             "MoE configs require return_aux=True on forward_pipelined: the "
             "load-balancing aux loss must reach the objective"
         )
+    _one_layer_kind(c, "forward_pipelined")
     B, L = tokens.shape
     if B % n_microbatches != 0:
         raise ValueError(
             f"batch {B} not divisible into {n_microbatches} microbatches"
         )
 
-    h = params["embed"].astype(c.dtype)[tokens]  # [B, L, D]
+    h = _embed(params, tokens, c)  # [B, L, D]
 
     batch_axes = _batch_axes(mesh) or ()
 
@@ -588,9 +871,7 @@ def forward_pipelined(
         mesh=mesh, n_microbatches=n_microbatches, batch_axes=batch_axes,
         with_aux=True,
     )
-    h = rms_norm(h, params["ln_f"])
-    logits = qeinsum("bld,dv->blv", h, params["lm_head"], c.dtype)
-    logits = logits.astype(jnp.float32)
+    logits = _head(params, h, c)
     if return_aux:
         return logits, aux
     return logits
@@ -684,6 +965,7 @@ def decode_window(
     so a chunked prefill on a sharded model lays out like the decode loop.
     """
     c = config
+    _one_layer_kind(c, "decode_window")
     B, W = tokens.shape
     max_len = cache["k"].shape[3]
     positions = pos0 + jnp.arange(W, dtype=jnp.int32)[None, :]  # [1, W]
@@ -696,7 +978,7 @@ def decode_window(
             x, NamedSharding(mesh, P(_batch_axes(mesh), None, None))
         )
 
-    h = constrain(params["embed"].astype(c.dtype)[tokens])  # [B, W, D]
+    h = constrain(_embed(params, tokens, c))  # [B, W, D]
 
     def layer_step(h, scanned):
         layer, c_layer = scanned
@@ -707,10 +989,8 @@ def decode_window(
             out = qeinsum("bld,dk->blk", x, w, c.dtype)
             return out.reshape(B, W, heads, dh).transpose(0, 2, 1, 3)
 
-        q = rope(
-            proj(layer["wq"], nh), positions, c.rope_theta, c.rope_scaling
-        )  # [B,nh,W,Dh]
-        k_new = rope(proj(layer["wk"], kvh), positions, c.rope_theta, c.rope_scaling)
+        q = _positioned(proj(layer["wq"], nh), positions, c)  # [B,nh,W,Dh]
+        k_new = _positioned(proj(layer["wk"], kvh), positions, c)
         v_new = proj(layer["wv"], kvh)
         from bee_code_interpreter_tpu.ops.kv_cache import (
             cache_append,
@@ -722,7 +1002,7 @@ def decode_window(
 
         rep = nh // kvh
         qg = q.reshape(B, kvh, rep, W, dh).astype(jnp.float32)
-        scores = jnp.einsum("bgrwd,bgsd->bgrws", qg, kf) / math.sqrt(dh)
+        scores = _scaled_scores(jnp.einsum("bgrwd,bgsd->bgrws", qg, kf), c)
         # row w (position pos0+w) sees cache positions s <= pos0+w (and
         # within the sliding window when configured)
         row_pos = (pos0 + jnp.arange(W))[:, None]  # [W, 1]
@@ -737,19 +1017,14 @@ def decode_window(
         weights = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
         attn = jnp.einsum("bgrws,bgsd->bgrwd", weights, vf)
         attn = attn.transpose(0, 3, 1, 2, 4).reshape(B, W, nh * dh)
-        h = h + constrain(
-            qeinsum("blk,kd->bld", attn, layer["wo"], c.dtype)
+        h = _residual(
+            h, constrain(qeinsum("blk,kd->bld", attn, layer["wo"], c.dtype)), c
         )
-
-        y = rms_norm(h, layer["ln2"])
-        mlp, _ = _mlp_block(y, layer, c)
-        h = h + constrain(mlp)
+        h, _ = _mlp_residual(h, layer, c, constrain)
         return h, c_layer
 
     h, cache = lax.scan(layer_step, h, (params["layers"], cache))
-    h = rms_norm(h, params["ln_f"])
-    logits = qeinsum("bld,dv->blv", h, params["lm_head"], c.dtype)
-    return logits.astype(jnp.float32), cache
+    return _head(params, h, c), cache
 
 
 def decode_step_paged(
@@ -809,10 +1084,7 @@ def decode_window_paged(
     ``lora_bank is None`` is a static (trace-time) branch: the base path
     is untouched. Pinned by tests/test_multilora_serving.py.
     """
-    from bee_code_interpreter_tpu.ops.paged_kv_cache import (
-        paged_append,
-        paged_read,
-    )
+    from bee_code_interpreter_tpu.ops.paged_kv_cache import paged_append
 
     c = config
     B, W = tokens.shape
@@ -826,21 +1098,16 @@ def decode_window_paged(
                 "decode path (attention projections only)"
             )
     page_size = cache["k"].shape[3]
-    S = block_table.shape[1] * page_size
     positions = pos0[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]  # [B, W]
     page_idx = jnp.take_along_axis(
         block_table, positions // page_size, axis=1
     )  # [B, W]
     slot_idx = positions % page_size
 
-    h = params["embed"].astype(c.dtype)[tokens]  # [B, W, D]
+    h = _embed(params, tokens, c)  # [B, W, D]
 
-    def layer_step(h, scanned):
-        if lora_bank is None:
-            layer, c_layer = scanned  # pool slices [n_pages, kvh, ps, dh]
-            lora_layer = {}
-        else:
-            layer, c_layer, lora_layer = scanned
+    def attention_layer(h, layer, c_layer, lora_layer):
+        """``c_layer``: one layer's pool slices [n_pages, kvh, ps, dh]."""
         x = rms_norm(h, layer["ln1"])
         dh, nh, kvh = c.head_dim, c.n_heads, c.kv_heads
 
@@ -860,8 +1127,8 @@ def decode_window_paged(
                 out = out + delta
             return out.reshape(B, W, heads, dh).transpose(0, 2, 1, 3)
 
-        q = rope(proj(layer["wq"], nh, "wq"), positions, c.rope_theta, c.rope_scaling)
-        k_new = rope(proj(layer["wk"], kvh, "wk"), positions, c.rope_theta, c.rope_scaling)
+        q = _positioned(proj(layer["wq"], nh, "wq"), positions, c)
+        k_new = _positioned(proj(layer["wk"], kvh, "wk"), positions, c)
         v_new = proj(layer["wv"], kvh, "wv")
         c_layer = paged_append(
             c_layer,
@@ -869,59 +1136,125 @@ def decode_window_paged(
             v_new.transpose(0, 2, 1, 3),
             page_idx, slot_idx,
         )
-        if (
-            c.paged_attention_kernel and W == 1
-            and "k_s" not in c_layer and c.sliding_window is None
-        ):
-            # in-place page reads: no gathered cache copy (see the config
-            # field / ops/paged_attention.py)
-            from bee_code_interpreter_tpu.ops.paged_attention import (
-                paged_decode_attention,
-            )
-
-            attn = paged_decode_attention(
-                q[:, :, 0, :], c_layer["k"], c_layer["v"], block_table,
-                positions[:, 0] + 1,
-            ).reshape(B, 1, nh * dh).astype(c.dtype)
-        else:
-            kf, vf = paged_read(c_layer, block_table, c.dtype)  # [B,kvh,S,dh]
-
-            rep = nh // kvh
-            qg = q.reshape(B, kvh, rep, W, dh).astype(jnp.float32)
-            scores = jnp.einsum("bgrwd,bgsd->bgrws", qg, kf) / math.sqrt(dh)
-            # row (b, w) sees cache positions s <= pos0_b + w (and within
-            # the sliding window when configured)
-            visible = (
-                jnp.arange(S)[None, None, :] <= positions[:, :, None]
-            )  # [B, W, S]
-            if c.sliding_window is not None:
-                visible &= (
-                    jnp.arange(S)[None, None, :]
-                    > positions[:, :, None] - c.sliding_window
-                )
-            scores = jnp.where(visible[:, None, None, :, :], scores, -jnp.inf)
-            weights = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
-            attn = jnp.einsum("bgrws,bgsd->bgrwd", weights, vf)
-            attn = attn.transpose(0, 3, 1, 2, 4).reshape(B, W, nh * dh)
+        attn = _attend_paged(q, c_layer, block_table, positions, c)
         o = qeinsum("blk,kd->bld", attn, layer["wo"], c.dtype)
         delta_o = lora_delta(attn, "wo")
         if delta_o is not None:
             o = o + delta_o
-        h = h + o
-
-        y = rms_norm(h, layer["ln2"])
-        mlp, _ = _mlp_block(y, layer, c)
-        h = h + mlp
+        h, _ = _mlp_residual(_residual(h, o, c), layer, c)
         return h, c_layer
+
+    if c.layer_types is not None:
+        if lora_bank is not None or (W != 1 and c.n_mamba_layers):
+            raise NotImplementedError(
+                "a layer pattern with mamba layers decodes one token a row "
+                "and takes no adapters: its state advances a token at a time"
+            )
+        h, cache = _decode_pattern(params, h, cache, c, attention_layer)
+        return _head(params, h, c), cache
+
+    def layer_step(h, scanned):
+        if lora_bank is None:
+            layer, c_layer = scanned
+            lora_layer = {}
+        else:
+            layer, c_layer, lora_layer = scanned
+        return attention_layer(h, layer, c_layer, lora_layer)
 
     scanned = (
         (params["layers"], cache) if lora_bank is None
         else (params["layers"], cache, lora_bank)
     )
     h, cache = lax.scan(layer_step, h, scanned)
-    h = rms_norm(h, params["ln_f"])
-    logits = qeinsum("bld,dv->blv", h, params["lm_head"], c.dtype)
-    return logits.astype(jnp.float32), cache
+    return _head(params, h, c), cache
+
+
+def _attend_paged(q, c_layer, block_table, positions, config: TransformerConfig):
+    """Attention of ``q`` [B, nh, W, dh] (at ``positions`` [B, W]) over one
+    layer's pages as each row's block table maps them: [B, W, nh * dh]."""
+    from bee_code_interpreter_tpu.ops.paged_kv_cache import paged_read
+
+    c = config
+    B, nh, W, dh = q.shape
+    kvh = c.kv_heads
+    if (
+        c.paged_attention_kernel and W == 1
+        and "k_s" not in c_layer and c.sliding_window is None
+    ):
+        # in-place page reads: no gathered cache copy (see the config
+        # field / ops/paged_attention.py)
+        from bee_code_interpreter_tpu.ops.paged_attention import (
+            paged_decode_attention,
+        )
+
+        return paged_decode_attention(
+            q[:, :, 0, :], c_layer["k"], c_layer["v"], block_table,
+            positions[:, 0] + 1,
+        ).reshape(B, 1, nh * dh).astype(c.dtype)
+    kf, vf = paged_read(c_layer, block_table, c.dtype)  # [B,kvh,S,dh]
+    S = kf.shape[2]
+
+    rep = nh // kvh
+    qg = q.reshape(B, kvh, rep, W, dh).astype(jnp.float32)
+    scores = _scaled_scores(jnp.einsum("bgrwd,bgsd->bgrws", qg, kf), c)
+    # row (b, w) sees cache positions s <= pos0_b + w (and within
+    # the sliding window when configured)
+    visible = (
+        jnp.arange(S)[None, None, :] <= positions[:, :, None]
+    )  # [B, W, S]
+    if c.sliding_window is not None:
+        visible &= (
+            jnp.arange(S)[None, None, :]
+            > positions[:, :, None] - c.sliding_window
+        )
+    scores = jnp.where(visible[:, None, None, :, :], scores, -jnp.inf)
+    weights = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
+    attn = jnp.einsum("bgrws,bgsd->bgrwd", weights, vf)
+    return attn.transpose(0, 3, 1, 2, 4).reshape(B, W, nh * dh)
+
+
+def _decode_pattern(params, h, cache, config: TransformerConfig, attention_layer):
+    """The layers of a declared pattern for one decode step, scanned a
+    period at a time with the whole pool as the carry: each layer reads its
+    own slice of the pool (K/V pages of its attention layer, or the rows'
+    state of its mamba layer) and writes it back in place, once."""
+    from bee_code_interpreter_tpu.models.mamba import mixer_step
+
+    c = config
+    kv_names = [name for name in cache if name not in ("ssm", "conv")]
+
+    def take(x, index):
+        return lax.dynamic_index_in_dim(x, index, 0, keepdims=False)
+
+    def put(x, new, index):
+        return lax.dynamic_update_index_in_dim(x, new.astype(x.dtype), index, 0)
+
+    def period_step(carry, period_index):
+        h, cache = carry
+        for j in range(c.layer_period):
+            kind, of_kind, layer = _pattern_layer(
+                params["layers"], c, period_index, j
+            )
+            if kind == "mamba":
+                mix, ssm, conv = mixer_step(
+                    rms_norm(h, layer["ln1"]), layer, c,
+                    take(cache["ssm"], of_kind), take(cache["conv"], of_kind),
+                )
+                h, _ = _mlp_residual(_residual(h, mix, c), layer, c)
+                new = {"ssm": ssm, "conv": conv}
+            else:
+                h, new = attention_layer(
+                    h, layer, {n: take(cache[n], of_kind) for n in kv_names}, {}
+                )
+            cache = {**cache, **{
+                n: put(cache[n], x, of_kind) for n, x in new.items()
+            }}
+        return (h, cache), None
+
+    (h, cache), _ = lax.scan(
+        period_step, (h, cache), jnp.arange(_n_periods(c))
+    )
+    return h, cache
 
 
 def prefill_chunked(

@@ -653,17 +653,23 @@ def uses_flash() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
-def local_attention(q, k, v, causal: bool = True, window: int | None = None):
+def local_attention(
+    q, k, v, causal: bool = True, window: int | None = None,
+    sm_scale: float | None = None,
+):
     """Single-device attention with platform dispatch: the Pallas flash
     kernel on TPU, the dense reference elsewhere (CPU tests). Both are
-    GQA-native (K/V may carry fewer heads than q). The ONE home for this
+    GQA-native (K/V may carry fewer heads than q) and take the caller's
+    ``sm_scale`` (None = 1/sqrt(head size)). The ONE home for this
     dispatch — models/transformer.py and parallel/ulysses.py both route
     through it, so backend policy can't silently diverge between the
     sp-attention strategies."""
     if uses_flash():
-        return flash_attention(q, k, v, causal, window=window)
+        return flash_attention(q, k, v, causal, sm_scale, window=window)
     from bee_code_interpreter_tpu.parallel.ring_attention import (
         reference_attention,
     )
 
-    return reference_attention(q, k, v, causal=causal, window=window)
+    return reference_attention(
+        q, k, v, causal=causal, window=window, sm_scale=sm_scale
+    )

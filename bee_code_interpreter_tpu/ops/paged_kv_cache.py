@@ -34,6 +34,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from bee_code_interpreter_tpu.ops.kv_cache import quantize
 
@@ -90,9 +91,15 @@ def pool_telemetry(
 
 
 def alloc_paged_cache(
-    config, n_pages: int, page_size: int, sharding=None
+    config, n_pages: int, page_size: int, sharding=None,
+    max_batch: int | None = None,
 ) -> dict:
-    """Zeroed page pool: k/v [n_layers, n_pages, kvh, page_size, dh].
+    """Zeroed page pool: k/v [attention layers, n_pages, kvh, page_size, dh]
+    and, for a configuration with mamba layers, what those keep by ROW of
+    the batch beside it (``models/mamba.alloc_state``: ``ssm`` and ``conv``
+    over [mamba layers, max_batch, ...]). One tree holds everything a
+    request keeps on the device between steps, and the decode program
+    donates it whole. ``max_batch`` is read only where state is kept by row.
 
     ``sharding`` (one ``jax.sharding.Sharding`` for every leaf — they share
     the leading dims) places the pool where it will live, from host zeros,
@@ -117,7 +124,7 @@ def alloc_paged_cache(
     c = config
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    shape = (c.n_layers, n_pages, c.kv_heads, page_size, c.head_dim)
+    shape = (c.n_attention_layers, n_pages, c.kv_heads, page_size, c.head_dim)
 
     def zeros(shape, dtype):
         if sharding is None:
@@ -125,13 +132,28 @@ def alloc_paged_cache(
         return jax.device_put(np.zeros(shape, dtype), sharding)
 
     if c.kv_cache_dtype == "int8":
-        return {
+        pool = {
             "k": zeros(shape, jnp.int8),
             "v": zeros(shape, jnp.int8),
             "k_s": zeros(shape[:-1] + (1,), jnp.float32),
             "v_s": zeros(shape[:-1] + (1,), jnp.float32),
         }
-    return {"k": zeros(shape, c.dtype), "v": zeros(shape, c.dtype)}
+    else:
+        pool = {"k": zeros(shape, c.dtype), "v": zeros(shape, c.dtype)}
+    if c.n_mamba_layers:
+        from bee_code_interpreter_tpu.models.mamba import alloc_state
+
+        if max_batch is None:
+            raise ValueError(
+                "a configuration with mamba layers keeps state by row: "
+                "alloc_paged_cache needs max_batch"
+            )
+        if sharding is not None:
+            raise NotImplementedError(
+                "state kept by row is not sharded: no mesh over mamba layers"
+            )
+        pool.update(alloc_state(c, max_batch, zeros))
+    return pool
 
 
 def paged_append(
@@ -248,6 +270,19 @@ def seed_prefill(
 
     cache = put(cache, "k", "k_s", k_pre)
     return put(cache, "v", "v_s", v_pre)
+
+
+def seed_state(cache: dict, row: jax.Array, ssm: jax.Array, conv: jax.Array) -> dict:
+    """Replace, whole and in place, what the mamba layers keep for one
+    ``row`` (a traced int32 scalar) with a one-sequence prefill's ``ssm``
+    [mamba layers, 1, heads, head size, state] and ``conv`` [mamba layers,
+    1, d_conv - 1, channels]: whatever the row's last tenant left is gone.
+    To be jitted with ``cache`` donated (the batcher's ``seed_state``
+    program): an eager ``.at[].set`` would hold the state leaf twice."""
+    def put(leaf, new):
+        return lax.dynamic_update_slice_in_dim(leaf, new.astype(leaf.dtype), row, 1)
+
+    return {**cache, "ssm": put(cache["ssm"], ssm), "conv": put(cache["conv"], conv)}
 
 
 def seed_from_contiguous(

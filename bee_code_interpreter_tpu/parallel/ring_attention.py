@@ -360,11 +360,12 @@ def ring_attention_sharded(
     return fn(q, k, v)
 
 
-def reference_attention(q, k, v, *, causal=True, window=None):
+def reference_attention(q, k, v, *, causal=True, window=None, sm_scale=None):
     """O(L²)-memory reference for tests. Accepts grouped-query K/V
     ([B, KVH, L, D] with KVH dividing q's head count) by broadcasting;
     ``window`` masks keys more than window-1 positions behind the query
-    (sliding-window attention; requires causal)."""
+    (sliding-window attention; requires causal); ``sm_scale`` scales the
+    scores (None = 1/sqrt(D))."""
     if k.shape[1] != q.shape[1]:
         rep = q.shape[1] // k.shape[1]
         k = jnp.repeat(k, rep, axis=1)
@@ -372,7 +373,7 @@ def reference_attention(q, k, v, *, causal=True, window=None):
     scores = jnp.einsum(
         "bhqd,bhkd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32),
         preferred_element_type=jnp.float32,
-    ) * (q.shape[-1] ** -0.5)
+    ) * (q.shape[-1] ** -0.5 if sm_scale is None else sm_scale)
     if window is not None and not causal:
         # mirror the flash kernel's validation: local_attention must behave
         # identically across platforms

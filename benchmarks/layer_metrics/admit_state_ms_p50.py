@@ -1,0 +1,26 @@
+"""What seeding a row's recurrent state costs an admission: the median
+length of the ``serve.admit.seed_state`` spans inside the traced slice
+(``ContinuousBatcher._phase``: the dispatch of the donating program that
+replaces the row's state, inside ``serve.admit``). ``None`` where the
+program has no such span (a configuration without mamba layers, a parent
+commit) or the slice holds no admission."""
+
+import statistics
+
+LAYER = "scheduler"
+UNIT = "ms"
+MOVES = "ttft_ms_p50_mix"
+SOURCE = "trace"
+
+SPAN = "serve.admit.seed_state"
+
+
+def read(run):
+    if run.trace is None or run.slice is None:
+        return None
+    lo, hi = run.slice
+    spans = [
+        e.seconds for e in run.trace.host
+        if e.name == SPAN and e.start >= lo and e.end <= hi
+    ]
+    return 1000.0 * statistics.median(spans) if spans else None

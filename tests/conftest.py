@@ -158,9 +158,34 @@ def _bound_xla_jit_accumulation():
     jax.clear_caches()
 
 
+# A case of the benchmark's frozen tests that asks, in its own words, to be
+# retired: it asserts that ``TransformerConfig`` has NO ``tie_embeddings``
+# field ("the program has the field now: retire this case"). PR 29 brought
+# the field, which a tied-head configuration's file needs
+# (``benchmarks/lib/harness.py``: a published ``tie_word_embeddings: true``
+# is refused until the program has it). The file is under the benchmark's
+# ``paths``, which only a benchmark PR may edit, so the case is marked here
+# as the expected failure it is until such a PR deletes it (with this
+# mechanism). What the case's second half says of a program that has the
+# field is held against the program itself meanwhile:
+# ``tests/benchmark/test_benchmark_granite.py``
+# ``test_a_tied_head_is_accepted_where_the_group_states_it_and_refused_elsewhere``.
+RETIRED_CASES = {
+    "tests/benchmark/test_benchmark_counts.py::"
+    "test_what_the_program_fixes_is_refused_until_it_has_the_field"
+    "[tie_word_embeddings-tie_embeddings-True-False-untied embeddings only]":
+        "TransformerConfig has tie_embeddings since PR 29; the case asks to "
+        "be retired and its file is frozen outside a benchmark PR",
+}
+
+
 def pytest_collection_modifyitems(config, items):
     for item in items:
         module = item.nodeid.split("::", 1)[0]
         name = Path(module).stem
         if name in SLOW_TEST_MODULES or "/e2e/" in module:
             item.add_marker(pytest.mark.slow)
+        if item.nodeid in RETIRED_CASES:
+            item.add_marker(
+                pytest.mark.xfail(reason=RETIRED_CASES[item.nodeid], strict=True)
+            )

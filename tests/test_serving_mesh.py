@@ -88,6 +88,53 @@ def test_tp_sampled_rows_match_the_unsharded_batcher():
     assert run(mesh=tp_mesh()) == want
 
 
+@pytest.mark.parametrize("speculative", [False, True])
+def test_tp_logprobs_match_the_unsharded_batcher(speculative):
+    # a row that records log-probabilities takes its normaliser from the
+    # device (log_normalizers) at admission, in the plain step and in a
+    # speculative round: under a mesh the logits reach that program sharded
+    # over the vocabulary, and it reports what a float64 log-softmax of the
+    # unsharded model's logits gives
+    from bee_code_interpreter_tpu.models.serving import SamplingParams
+
+    config = cfg()
+    params = T.init_params(config, jax.random.PRNGKey(0))
+    extra = {}
+    if speculative:
+        draft_config = cfg(n_layers=1)
+        extra = dict(
+            draft_params=T.init_params(draft_config, jax.random.PRNGKey(1)),
+            draft_config=draft_config, gamma=3,
+        )
+    kinds = [
+        SamplingParams(logprobs=True),
+        SamplingParams(temperature=0.9, seed=3, logprobs=True),
+    ]
+
+    def run(**kw):
+        b = ContinuousBatcher(
+            params, config, max_batch=2, n_pages=32, page_size=4,
+            max_pages_per_seq=6, **extra, **kw,
+        )
+        reqs = [b.submit(PROMPT, 6, sampling=sp) for sp in kinds]
+        b.run_to_completion()
+        return [(b.result(r), b.result_logprobs(r)) for r in reqs]
+
+    want, got = run(), run(mesh=tp_mesh())
+    for (tokens, logprobs), (m_tokens, m_logprobs), prompt in zip(
+        want, got, [PROMPT, PROMPT]
+    ):
+        assert m_tokens == tokens
+        np.testing.assert_allclose(m_logprobs, logprobs, atol=2e-5)
+        logits = np.asarray(T.forward(
+            params, jnp.asarray(prompt + tokens[:-1])[None, :], config=config
+        )[0, len(prompt) - 1:], np.float64)
+        exact = logits - np.log(np.exp(logits).sum(-1, keepdims=True))
+        np.testing.assert_allclose(
+            logprobs, exact[np.arange(len(tokens)), tokens], atol=2e-5
+        )
+
+
 def test_tp_int8_pool_matches_unsharded_solo():
     config = cfg(kv_cache_dtype="int8")
     params = T.init_params(config, jax.random.PRNGKey(0))

@@ -550,11 +550,12 @@ def test_serve_spans_nest_and_carry_their_stats(monkeypatch, mix):
     ]
     # all greedy and no logprobs: the [B] argmax ids cross, no logits row
     ids, rows = 4 * 2, 4 * 2 * CFG.vocab_size
-    # mixed: the argmax ids, the picked ids and (logprobs) the logits rows;
-    # steered: the argmax ids and the rows the host picks from
+    # mixed: the argmax ids, the picked ids and (logprobs) the logits rows
+    # with their [B] float32 normalisers; steered: the argmax ids and the
+    # rows the host picks from
     pulls = {s["bytes"] for name, s, _ in spans if name == "serve.step.pull"}
     assert pulls == {
-        {"greedy": ids, "mixed": 2 * ids + rows, "steered": ids + rows}[mix]
+        {"greedy": ids, "mixed": 3 * ids + rows, "steered": ids + rows}[mix]
     }
     picked = {
         (s["device_picked_rows"], s["host_picked_rows"])
