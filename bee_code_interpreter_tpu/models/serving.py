@@ -80,6 +80,7 @@ from bee_code_interpreter_tpu.models.transformer import (
     forward,
     prefill_chunked,
 )
+from bee_code_interpreter_tpu.ops.paged_attention import reads_pages_in_place
 from bee_code_interpreter_tpu.ops.paged_kv_cache import (
     alloc_paged_cache,
     seed_from_contiguous,
@@ -693,10 +694,18 @@ class ContinuousBatcher:
         # a full page-pool HBM copy (precedent: make_train_step's donation)
         self._decode = self._track(
             functools.partial(
-                decode_step_paged, config=config, lora_scale=self.lora_scale
+                decode_step_paged, config=config, lora_scale=self.lora_scale,
+                mesh=mesh,
             ),
             "decode_step_paged",
             donate_argnums=(3,),
+        )
+        # which way that program attends (kv_telemetry): the predicate it is
+        # traced under, over the same pool, window of one token and mesh
+        self._decode_attention = (
+            "pages_in_place"
+            if reads_pages_in_place(self.cache, 1, config.sliding_window, mesh)
+            else "gathered"
         )
         # what the mamba layers keep for a row, replaced whole at admission
         self._seed_state = None
@@ -735,7 +744,8 @@ class ContinuousBatcher:
         # per page-aligned window width, bounded by max_pages_per_seq
         self._window = self._track(
             functools.partial(
-                decode_window_paged, config=config, lora_scale=self.lora_scale
+                decode_window_paged, config=config, lora_scale=self.lora_scale,
+                mesh=mesh,
             ),
             "decode_window_paged",
             donate_argnums=(3,),
@@ -765,7 +775,9 @@ class ContinuousBatcher:
                     draft_params, draft_config, mesh
                 )
             self._draft_decode = self._track(
-                functools.partial(decode_step_paged, config=draft_config),
+                functools.partial(
+                    decode_step_paged, config=draft_config, mesh=mesh
+                ),
                 "draft_decode_step_paged",
                 donate_argnums=(3,),
             )
@@ -780,7 +792,9 @@ class ContinuousBatcher:
             # happens to equal gamma+1 reuses the compiled program
             self._verify = self._window
             self._draft_window = self._track(
-                functools.partial(decode_window_paged, config=draft_config),
+                functools.partial(
+                    decode_window_paged, config=draft_config, mesh=mesh
+                ),
                 "draft_decode_window_paged",
                 donate_argnums=(3,),
             )
@@ -1030,6 +1044,10 @@ class ContinuousBatcher:
         out["state_bytes_per_row"] = per_row
         out["state_rows_live"] = int(self.active.sum()) if per_row else 0
         out["state_bytes"] = per_row * self.max_batch
+        # how the plain decode step's attention reads the pool: a row's live
+        # pages where they lie (the Pallas kernel) or the table's width
+        # gathered (ops.paged_attention.reads_pages_in_place)
+        out["decode_attention"] = self._decode_attention
         return out
 
     # ----------------------------------------------------- snapshot/resume

@@ -96,13 +96,6 @@ class TransformerConfig:
     # flash kernels skip fully-out-of-window blocks; single-shard/tp meshes
     # only (the sp ring/Ulysses paths don't thread the window).
     sliding_window: int | None = None
-    # Single-token paged decode through the Pallas paged-attention kernel
-    # (ops/paged_attention.py): pages read IN PLACE via scalar-prefetched
-    # block tables instead of paged_read's gather (which materializes a
-    # contiguous cache copy every step). Applies to decode_step_paged
-    # (W == 1) on bf16 pools with full causal attention; other shapes and
-    # the int8 pool keep the einsum path.
-    paged_attention_kernel: bool = False
     # A declared layer pattern: one of "attention" / "mamba" per layer (the
     # published ``layer_types`` list; frozen to a tuple, since the config is
     # a static jit argument). None = attention in every layer, the one
@@ -716,6 +709,13 @@ def _scaled_scores(scores, config: TransformerConfig):
     return scores / math.sqrt(config.head_dim)
 
 
+def _score_scale(config: TransformerConfig) -> float:
+    """``_scaled_scores`` as the one factor a kernel multiplies by."""
+    if config.attention_multiplier is not None:
+        return config.attention_multiplier
+    return 1.0 / math.sqrt(config.head_dim)
+
+
 def forward(
     params: Params,
     tokens: jax.Array,  # [B, L] int32
@@ -1037,6 +1037,7 @@ def decode_step_paged(
     lora_bank: dict | None = None,
     adapter_idx: jax.Array | None = None,
     lora_scale: float = 1.0,
+    mesh: Mesh | None = None,
 ) -> tuple[jax.Array, dict]:
     """One incremental decode step over the PAGED cache — the serving-side
     sibling of ``decode_step``. This IS ``decode_window_paged`` with W=1
@@ -1044,7 +1045,7 @@ def decode_step_paged(
     unification)."""
     return decode_window_paged(
         params, token, pos, cache, block_table, config,
-        lora_bank, adapter_idx, lora_scale,
+        lora_bank, adapter_idx, lora_scale, mesh=mesh,
     )
 
 
@@ -1058,6 +1059,7 @@ def decode_window_paged(
     lora_bank: dict | None = None,  # {target: {A: [n_layers, n_adapters, d, r], B: ...}}
     adapter_idx: jax.Array | None = None,  # [B] int32 per-row adapter
     lora_scale: float = 1.0,
+    mesh: Mesh | None = None,  # the mesh the params and the pool lie under
 ) -> tuple[jax.Array, dict]:
     """Multi-token cached decode over the PAGED pool with PER-ROW window
     positions — the verify primitive for speculative decoding INSIDE
@@ -1073,6 +1075,12 @@ def decode_window_paged(
     per-row scale planes per page and append/read quantize exactly like
     the contiguous strategy. Rows whose slots would exceed the table's
     page budget are a scheduler bug (the scatter clamps).
+
+    A window of ONE token attends through the Pallas kernel that reads each
+    row's live pages where they lie, wherever
+    ``ops.paged_attention.reads_pages_in_place`` says it can (``mesh`` is
+    read for that alone: the kernel runs in ``shard_map`` over the KV
+    heads); everything else gathers the table's width (``_attend_paged``).
 
     ``lora_bank`` enables MULTI-LoRA serving (S-LoRA style): a stacked
     bank of adapters for the attention projections, with ``adapter_idx``
@@ -1136,7 +1144,7 @@ def decode_window_paged(
             v_new.transpose(0, 2, 1, 3),
             page_idx, slot_idx,
         )
-        attn = _attend_paged(q, c_layer, block_table, positions, c)
+        attn = _attend_paged(q, c_layer, block_table, positions, c, mesh)
         o = qeinsum("blk,kd->bld", attn, layer["wo"], c.dtype)
         delta_o = lora_delta(attn, "wo")
         if delta_o is not None:
@@ -1169,27 +1177,23 @@ def decode_window_paged(
     return _head(params, h, c), cache
 
 
-def _attend_paged(q, c_layer, block_table, positions, config: TransformerConfig):
+def _attend_paged(
+    q, c_layer, block_table, positions, config: TransformerConfig,
+    mesh: Mesh | None = None,
+):
     """Attention of ``q`` [B, nh, W, dh] (at ``positions`` [B, W]) over one
     layer's pages as each row's block table maps them: [B, W, nh * dh]."""
+    from bee_code_interpreter_tpu.ops import paged_attention
     from bee_code_interpreter_tpu.ops.paged_kv_cache import paged_read
 
     c = config
     B, nh, W, dh = q.shape
     kvh = c.kv_heads
-    if (
-        c.paged_attention_kernel and W == 1
-        and "k_s" not in c_layer and c.sliding_window is None
-    ):
-        # in-place page reads: no gathered cache copy (see the config
-        # field / ops/paged_attention.py)
-        from bee_code_interpreter_tpu.ops.paged_attention import (
-            paged_decode_attention,
-        )
-
-        return paged_decode_attention(
+    if paged_attention.reads_pages_in_place(c_layer, W, c.sliding_window, mesh):
+        # the row's live pages, where they lie: nothing gathered
+        return paged_attention.paged_decode_attention(
             q[:, :, 0, :], c_layer["k"], c_layer["v"], block_table,
-            positions[:, 0] + 1,
+            positions[:, 0] + 1, sm_scale=_score_scale(c), mesh=mesh,
         ).reshape(B, 1, nh * dh).astype(c.dtype)
     kf, vf = paged_read(c_layer, block_table, c.dtype)  # [B,kvh,S,dh]
     S = kf.shape[2]

@@ -1,32 +1,40 @@
-"""Pallas paged-attention decode kernel: K/V pages read IN PLACE.
+"""Pallas paged-attention decode kernel: a row's LIVE K/V pages read where
+they lie.
 
-The einsum decode path (``ops/paged_kv_cache.paged_read`` +
-``models/transformer.decode_window_paged``) gathers each row's pages into
-a contiguous [B, kvh, S, dh] view before the attention einsums — on TPU
-that gather MATERIALIZES a full copy of the visible cache in HBM every
-decode step, doubling the traffic of the already-bandwidth-bound loop.
-This kernel removes the copy: the page pool is an input whose BlockSpec
-index map reads the block table through Pallas SCALAR PREFETCH
-(``pltpu.PrefetchScalarGridSpec``), so each grid step DMAs exactly one
-physical page from wherever it lives — the indirection costs an index
-lookup, not a gather.
+The plain decode step (``models/transformer._attend_paged``, a window of
+one token) attends through this kernel wherever ``reads_pages_in_place``
+says it can; everything else keeps ``ops/paged_kv_cache.paged_read`` and
+the grouped einsums, which are also this kernel's oracle in the tests.
+The gather costs what the block TABLE is wide (every slot of every row,
+live or not, copied and transposed in HBM each step); the kernel costs
+what is LIVE.
 
-Structure — the flash forward kernel's online softmax specialized to
-decode (one query token per row):
+Structure — the flash kernel's online softmax, for one query token a row:
 
-- grid (B, kvh, P): pages sequential innermost, the per-(row, kv-head)
-  running max/normalizer/accumulator in VMEM scratch across page steps;
-- GQA-native: the ``rep = nh/kvh`` query heads sharing a KV head form the
-  kernel's row block (padded to the 8-row sublane tile when rep < 8);
-- per-row visible lengths ride the second scalar-prefetch operand: pages
-  at or beyond a row's length are skipped by predication, slots past the
-  length inside the boundary page are masked to -inf.
+- grid ``(B,)``, one row a step. The pool leaf ``[n_pages, kvh, ps, dh]``
+  stays in HBM (``memory_space`` any) in the layout everything else reads;
+  a page with all its KV heads is one contiguous block there, and the
+  kernel copies ``PAGE_BLOCK_TOKENS // ps`` such pages a block into VMEM
+  with its own async copies, double-buffered: block i+1 is in flight
+  while block i is computed;
+- the loop runs to ``ceil(length / ps)`` pages, from the scalar-prefetched
+  lengths and block table: a table entry past the live count is never
+  read (so a sentinel there is harmless), slots past the length in the
+  boundary page are masked, and a row of length 0 copies nothing and
+  gives zeros;
+- per KV head, the ``rep = nh / kvh`` query heads that share it are the
+  matmul's rows (padded to the sublane tile). Operands enter the MXU in
+  the pool's dtype and accumulate in float32; scores, running max,
+  normaliser and accumulator are float32; the probabilities are rounded
+  to the pool's dtype for the PV product, as the einsum path rounds them;
+- a row's result depends on its own pages and length alone, block by
+  block in logical order at a fixed block size: the same bits alone and
+  in any batch.
 
-bf16/f32 pools only — the int8 pool's per-slot scale planes stay on the
-einsum path (dequantization there rides the gather it already pays).
-CPU tests run the kernel in Pallas interpreter mode against the grouped
-einsum oracle (tests/test_paged_attention.py); Mosaic lowering and the
-HBM win are measured on hardware by scripts/bench-decode.py.
+bf16/f32 pools only: the int8 pool's scale planes stay on the einsum path.
+CPU tests run the kernel in Pallas interpreter mode
+(tests/test_paged_decode_kernel.py); ``chip_smoke.py`` lowers it with
+Mosaic at the benchmark's shapes and ``scripts/bench-decode.py`` times it.
 
 The reference has no kernels at all (SURVEY §2); within this rebuild the
 kernel is the serving-side sibling of ops/flash_attention.py.
@@ -38,63 +46,150 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
+# K/V slots a block holds: the grain of the copies and of the online
+# softmax. Fixed, so that a row's sums are taken in one order everywhere.
+PAGE_BLOCK_TOKENS = 512
+LANES = 128  # the lane tile: Mosaic copies no page whose head is narrower
+
+
+def on_tpu() -> bool:
+    """The backend's part of ``reads_pages_in_place``. Tests patch this, as
+    they patch ``flash_attention.uses_flash``; the kernel itself still asks
+    the real backend whether to lower or to interpret."""
+    return jax.devices()[0].platform == "tpu"
+
+
+def reads_pages_in_place(
+    c_layer: dict, window: int, sliding_window: int | None, mesh=None
+) -> bool:
+    """THE predicate of the decode step's attention path, over what the
+    traced program can see: the kernel where the backend is a TPU, the
+    window is one token, the pool has no scale planes, nothing slides, the
+    head fills the lane tile and, under a mesh, the KV heads divide over
+    tp; ``paged_read`` and the einsums otherwise."""
+    kvh, _, dh = c_layer["k"].shape[-3:]
+    tp = 1 if mesh is None else dict(mesh.shape).get("tp", 1)
+    return (
+        on_tpu()
+        and window == 1
+        and "k_s" not in c_layer
+        and sliding_window is None
+        and dh % LANES == 0
+        and kvh % tp == 0
+    )
 
 
 def _kernel(
     bt_ref,        # scalar prefetch: [B, P] block table (int32)
     len_ref,       # scalar prefetch: [B] visible lengths (int32)
-    q_ref,         # [1, 1, rep_p, dh]
-    k_ref,         # [1, 1, ps, dh] — the page selected by the index map
-    v_ref,         # [1, 1, ps, dh]
-    o_ref,         # [1, 1, rep_p, dh]
-    m_s, l_s, acc_s,  # VMEM f32: [rep_p, 1], [rep_p, 1], [rep_p, dh]
-    *, ps: int, sm_scale: float,
+    q_ref,         # VMEM [1, kvh, rep_p, dh]
+    k_hbm,         # HBM [n_pages, kvh, ps, dh]: the pool leaf as it lies
+    v_hbm,
+    o_ref,         # VMEM [1, kvh, rep_p, dh]
+    k_buf,         # VMEM [2, ppb, kvh, ps, dh]: two blocks of pages
+    v_buf,
+    sems,          # DMA semaphores [2 (k, v), 2 (buffer)]
+    *, sm_scale: float,
 ):
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    num_pages = pl.num_programs(2)
-
-    @pl.when(p == 0)
-    def _init():
-        m_s[:] = jnp.full_like(m_s, NEG_INF)
-        l_s[:] = jnp.zeros_like(l_s)
-        acc_s[:] = jnp.zeros_like(acc_s)
-
+    _, ppb, kvh, ps, dh = k_buf.shape
+    rep_p = q_ref.shape[2]
+    block_tokens = ppb * ps
     length = len_ref[b]
-    base = p * ps
+    n_live = (length + ps - 1) // ps           # pages with a visible slot
+    n_blocks = (n_live + ppb - 1) // ppb
 
-    @pl.when(base < length)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)        # [rep_p, dh]
-        k = k_ref[0, 0].astype(jnp.float32)        # [ps, dh]
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale                               # [rep_p, ps]
-        slot = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(slot < length, s, NEG_INF)
-
-        m_prev, l_prev = m_s[:], l_s[:]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        pexp = jnp.exp(s - m_new)
-        l_s[:] = l_prev * alpha + pexp.sum(axis=-1, keepdims=True)
-        acc_s[:] = acc_s[:] * alpha + jax.lax.dot_general(
-            pexp, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+    def page_copies(page, buf, j):
+        return (
+            pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, j], sems.at[0, buf]),
+            pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, j], sems.at[1, buf]),
         )
-        m_s[:] = m_new
 
-    @pl.when(p == num_pages - 1)
-    def _finalize():
-        o_ref[0, 0] = (
-            acc_s[:] / jnp.maximum(l_s[:], 1e-30)
-        ).astype(o_ref.dtype)
+    def live_in(block):  # pages of this block that hold a visible slot
+        return jnp.minimum(ppb, n_live - block * ppb)
+
+    def fetch(block, buf):
+        def start(j, carry):
+            for copy in page_copies(bt_ref[b, block * ppb + j], buf, j):
+                copy.start()
+            return carry
+
+        # a dead page of the boundary block is not copied: its scores are
+        # masked, but 0 * whatever VMEM held must still be 0
+        def clear(j, carry):
+            v_buf[buf, j] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
+            return carry
+
+        live = live_in(block)
+        lax.fori_loop(0, live, start, 0)
+        lax.fori_loop(live, ppb, clear, 0)
+
+    def wait(block, buf):
+        def one(j, carry):
+            for copy in page_copies(0, buf, j):  # the page is not read
+                copy.wait()
+            return carry
+
+        lax.fori_loop(0, live_in(block), one, 0)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        fetch(0, 0)
+
+    def block_step(i, carry):
+        buf = i % 2
+
+        @pl.when(i + 1 < n_blocks)
+        def _next():
+            fetch(i + 1, 1 - buf)
+
+        wait(i, buf)
+        slot = i * block_tokens + lax.broadcasted_iota(
+            jnp.int32, (rep_p, block_tokens), 1
+        )
+        visible = slot < length
+        out = []
+        # the heads unrolled, their running sums carried as values: the
+        # scheduler overlaps one head's matmuls with another's softmax
+        # (rolled, or with the sums in VMEM, a layer took 219-254 us where
+        # this takes 188 in mistral7b_chat; my chip runs, PR 30)
+        for g, (m_prev, l_prev, acc) in enumerate(carry):
+            k = k_buf[buf, :, g].reshape(block_tokens, dh)
+            v = v_buf[buf, :, g].reshape(block_tokens, dh)
+            s = lax.dot_general(
+                q_ref[0, g], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * sm_scale                           # [rep_p, block_tokens]
+            s = jnp.where(visible, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            out.append((
+                m_new,
+                l_prev * alpha + p.sum(axis=-1, keepdims=True),
+                acc * alpha + lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ),
+            ))
+        return tuple(out)
+
+    start = tuple(
+        (
+            jnp.full((rep_p, 1), NEG_INF, jnp.float32),
+            jnp.zeros((rep_p, 1), jnp.float32),
+            jnp.zeros((rep_p, dh), jnp.float32),
+        )
+        for _ in range(kvh)
+    )
+    for g, (_, l, acc) in enumerate(lax.fori_loop(0, n_blocks, block_step, start)):
+        o_ref[0, g] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -105,73 +200,71 @@ def paged_decode_attention(
     lengths: jax.Array,      # [B] int32 visible length per row (pos + 1)
     sm_scale: float | None = None,
     interpret: bool | None = None,
+    mesh=None,
 ) -> jax.Array:              # [B, nh, dh]
-    """Single-token paged attention with in-place page reads (module
-    docstring). GQA-native: ``nh % kvh == 0``; bf16/f32 pools."""
+    """Single-token paged attention over each row's live pages (module
+    docstring). GQA-native: ``nh % kvh == 0``; bf16/f32 pools.
+
+    Under ``mesh`` each device runs the kernel over its own KV heads in
+    ``shard_map`` (GSPMD cannot partition a ``pallas_call``): axis 1 of the
+    pool leaf over tp, as ``ContinuousBatcher._pool_sharding`` lays it, and
+    q's heads the same way, being group-major. Heads are independent, so
+    there is no collective; every other axis sees replicas."""
+    if mesh is not None:
+        tp = "tp" if "tp" in mesh.axis_names else None
+        heads, pool = P(None, tp, None), P(None, tp, None, None)
+        return jax.shard_map(
+            functools.partial(
+                paged_decode_attention, sm_scale=sm_scale, interpret=interpret
+            ),
+            mesh=mesh,
+            in_specs=(heads, pool, pool, P(), P()),
+            out_specs=heads,
+            check_vma=False,  # vma checking cannot lower a pallas_call yet
+        )(q, k_pages, v_pages, block_table, lengths)
     B, nh, dh = q.shape
     n_pages, kvh, ps, _ = k_pages.shape
-    P = block_table.shape[1]
     if nh % kvh:
         raise ValueError(f"n_heads {nh} not a multiple of kv_heads {kvh}")
     rep = nh // kvh
-    rep_p = max(8, -(-rep // 8) * 8)  # query rows padded to the sublane tile
+    # query rows padded to the sublane tile of the pool's dtype
+    sublanes = 32 // k_pages.dtype.itemsize
+    rep_p = -(-rep // sublanes) * sublanes
     if sm_scale is None:
         sm_scale = dh ** -0.5
     if interpret is None:
         interpret = jax.devices()[0].platform != "tpu"
+    ppb = max(1, min(PAGE_BLOCK_TOKENS // ps, block_table.shape[1]))
 
     # group-major view [B, kvh, rep, dh], zero-padded to rep_p rows
-    qg = q.reshape(B, kvh, rep, dh)
+    qg = q.reshape(B, kvh, rep, dh).astype(k_pages.dtype)
     if rep_p != rep:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rep_p - rep), (0, 0)))
 
-    grid = (B, kvh, P)
+    q_spec = pl.BlockSpec((1, kvh, rep_p, dh), lambda b, bt, lens: (b, 0, 0, 0))
     out = pl.pallas_call(
-        functools.partial(_kernel, ps=ps, sm_scale=float(sm_scale)),
+        functools.partial(_kernel, sm_scale=float(sm_scale)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(B,),
             in_specs=[
-                pl.BlockSpec(
-                    (1, 1, rep_p, dh), lambda b, h, p, bt, lens: (b, h, 0, 0)
-                ),
-                # THE point: the page index comes from the prefetched
-                # block table, over the pool's NATIVE layout — the DMA
-                # reads the physical page in place (any relayout of the
-                # pool here would itself be the copy this kernel exists
-                # to avoid)
-                # the index is clamped to the pool: entries at/past a
-                # row's visible length have their compute predicated off
-                # but the DMA still issues, and a sentinel like -1 (a
-                # common block-table convention) would read out of bounds
-                # in the Mosaic path while passing interpreter-mode tests
-                pl.BlockSpec(
-                    (1, 1, ps, dh),
-                    lambda b, h, p, bt, lens: (
-                        jnp.clip(bt[b, p], 0, n_pages - 1), h, 0, 0
-                    ),
-                ),
-                pl.BlockSpec(
-                    (1, 1, ps, dh),
-                    lambda b, h, p, bt, lens: (
-                        jnp.clip(bt[b, p], 0, n_pages - 1), h, 0, 0
-                    ),
-                ),
+                q_spec,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
-            out_specs=pl.BlockSpec(
-                (1, 1, rep_p, dh), lambda b, h, p, bt, lens: (b, h, 0, 0)
-            ),
+            out_specs=q_spec,
             scratch_shapes=[
-                pltpu.VMEM((rep_p, 1), jnp.float32),
-                pltpu.VMEM((rep_p, 1), jnp.float32),
-                pltpu.VMEM((rep_p, dh), jnp.float32),
+                pltpu.VMEM((2, ppb, kvh, ps, dh), k_pages.dtype),
+                pltpu.VMEM((2, ppb, kvh, ps, dh), v_pages.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, kvh, rep_p, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            dimension_semantics=("arbitrary",),
         ) if not interpret else None,
         interpret=interpret,
+        name="paged_decode_attention",  # the trace's ``XLA Ops`` line shows it
     )(
         block_table.astype(jnp.int32), lengths.astype(jnp.int32),
         qg, k_pages, v_pages,

@@ -12,18 +12,26 @@ batching).
 
 TPU-first constraints shape the layout:
 
-- **Static shapes everywhere.** The pool, the block table, and the gather
-  in ``paged_read`` are all fixed-size; "allocation" is host-side integer
+- **Static shapes everywhere.** The pool, the block table and every
+  program over them are fixed-size; "allocation" is host-side integer
   bookkeeping between steps, never a traced shape change.
-- **Gather/scatter ride XLA.** ``paged_read`` is one advanced-indexing
-  gather (lowered to a single dynamic-gather HLO) producing the same
-  [B, kvh, S, dh] view the contiguous attention einsums consume — the
-  decode layer math is UNCHANGED (models/transformer.decode_step_paged
-  reuses the grouped-query einsums), so paged-vs-contiguous equality is a
-  pure indexing property, pinned by tests/test_paged_kv_cache.py.
-- **Page size is a multiple of the lane tile.** Pages are [kvh, page_size,
-  dh] slabs; dh is contiguous and page_size defaults to a multiple of 8 so
-  gathered slabs keep the (8, 128) tiling XLA wants.
+- **The served decode step does not gather.** A window of one token
+  attends through ``ops/paged_attention.py``, a Pallas kernel that copies
+  each row's LIVE pages from the pool leaf where they lie, wherever
+  ``paged_attention.reads_pages_in_place`` holds (a TPU, no scale planes,
+  no sliding window, a head that fills the lane tile). ``paged_read``
+  below is the other path: speculative windows, int8 pools, sliding
+  windows and the CPU of the tests gather the whole block-table width
+  into the [B, kvh, S, dh] view the contiguous attention einsums consume
+  (one advanced-indexing gather), so paged-vs-contiguous equality is a
+  pure indexing property, pinned by tests/test_paged_kv_cache.py, and the
+  einsum path is the kernel's oracle (tests/test_paged_decode_kernel.py).
+  ``paged_append`` scatters the new tokens either way.
+- **A page with all its KV heads is one contiguous block.** Pages are
+  [kvh, page_size, dh] slabs of the leaf [n_pages, kvh, page_size, dh]:
+  the kernel copies whole pages, ``seed_prefill`` scatters whole pages,
+  the mesh shards the kvh axis. dh is contiguous and page_size defaults to
+  a multiple of 8 so slabs keep the (8, 128) tiling XLA wants.
 
 The reference has no serving stack at all (SURVEY §2); this module is part
 of the rebuild's decode family next to the int8 cache (ops/kv_cache.py).

@@ -31,7 +31,8 @@ by the repo's own means. It claims no rate: every time it prints is labelled
   plane.
 - **kernels** — the Pallas flash (forward and backward) and paged-decode
   kernels lowered by Mosaic (``interpret=False`` passed explicitly) against
-  their references.
+  their references; the paged-decode kernel at the benchmark's table width,
+  with all KV heads and with a tp=4 shard's.
 
 This parent never imports jax: a chip belongs to one process at a time, so
 each phase runs in a child of its own, strictly one after another. Children
@@ -422,7 +423,7 @@ def phase_kernels(
     batch: int = 2,
     seq_len: int = 2048,
     page_size: int = 16,
-    pages_per_seq: int = 16,
+    pages_per_seq: int = 288,
     tol: float = 2e-2,
     grad_tol: float = 5e-2,
 ) -> dict:
@@ -432,6 +433,7 @@ def phase_kernels(
     head geometry. Tolerances are bf16's: outputs are O(1), one bf16 ulp
     there is 2^-8 ~ 4e-3, and P is rounded to bf16 before the PV matmul."""
     import math
+    from unittest import mock
 
     t0 = time.monotonic()
     device, init_s = backend_up(platform)
@@ -439,6 +441,7 @@ def phase_kernels(
     import jax.numpy as jnp
     import numpy as np
 
+    from bee_code_interpreter_tpu.ops import paged_attention
     from bee_code_interpreter_tpu.ops.flash_attention import flash_attention
     from bee_code_interpreter_tpu.ops.paged_attention import (
         paged_decode_attention,
@@ -508,15 +511,14 @@ def phase_kernels(
             "compile_s": round(seconds, 1),
         }
 
-    # ---- paged decode attention: pages read in place through the block
-    # table; ragged lengths including a partial boundary page, an exact page
-    # multiple and a single token
+    # ---- paged decode attention: a row's live pages read where they lie,
+    # at the benchmark's table width (288 pages of 16 slots) with all KV
+    # heads and with the two a tp=4 shard holds; ragged lengths: a single
+    # token, a partial boundary page, an exact page multiple, nearly the
+    # whole table
     rep = nh // kvh
     rows = 4
     n_pool = rows * pages_per_seq + 8
-    k_pages = jax.random.normal(keys[4], (n_pool, kvh, page_size, dh), dtype)
-    v_pages = jax.random.normal(keys[5], (n_pool, kvh, page_size, dh), dtype)
-    qd = jax.random.normal(keys[6], (rows, nh, dh), dtype)
     table = jnp.asarray(
         np.random.default_rng(SEED).permutation(n_pool - 1)[
             : rows * pages_per_seq
@@ -534,35 +536,51 @@ def phase_kernels(
         )
 
     def paged_einsum(qd, k_pages, v_pages, table, lengths):
+        heads = k_pages.shape[1]
         kf, vf = paged_read({"k": k_pages, "v": v_pages}, table, dtype)
-        qg = qd.reshape(rows, kvh, rep, dh).astype(jnp.float32)
+        qg = qd.reshape(rows, heads, rep, dh).astype(jnp.float32)
         scores = jnp.einsum("bgrd,bgsd->bgrs", qg, kf) / math.sqrt(dh)
         visible = jnp.arange(span)[None, :] < lengths[:, None]
         scores = jnp.where(visible[:, None, None, :], scores, -jnp.inf)
         weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
         out = jnp.einsum("bgrs,bgsd->bgrd", weights, vf)
-        return out.reshape(rows, nh, dh)
+        return out.reshape(rows, heads * rep, dh)
 
-    args = (qd, k_pages, v_pages, table, lengths)
-    compiled, seconds = _timed_compile(paged_kernel, *args)
-    compile_s += seconds
-    t_run = time.monotonic()
-    got = jax.block_until_ready(compiled(*args))
-    run_s += time.monotonic() - t_run
-    paged_err = err(got, jax.jit(paged_einsum)(*args))
-    check(
-        math.isfinite(paged_err) and paged_err <= tol,
-        f"paged_decode_attention disagrees with the paged_read einsum path: "
-        f"rel err {paged_err} > {tol}",
-    )
-    results["paged_decode"] = {
-        "shape": {"rows": rows, "nh": nh, "kvh": kvh, "rep": rep, "dh": dh,
-                  "page_size": page_size, "pages_per_seq": pages_per_seq},
-        "lengths": [int(x) for x in lengths],
-        "rel_err": round(paged_err, 5),
-        "tolerance": tol,
-        "compile_s": round(seconds, 1),
-    }
+    for name, heads in (
+        ("paged_decode", kvh), ("paged_decode_tp4_shard", max(1, kvh // 4)),
+    ):
+        k_pages = jax.random.normal(keys[4], (n_pool, heads, page_size, dh), dtype)
+        v_pages = jax.random.normal(keys[5], (n_pool, heads, page_size, dh), dtype)
+        qd = jax.random.normal(keys[6], (rows, heads * rep, dh), dtype)
+        args = (qd, k_pages, v_pages, table, lengths)
+        compiled, seconds = _timed_compile(paged_kernel, *args)
+        compile_s += seconds
+        t_run = time.monotonic()
+        got = jax.block_until_ready(compiled(*args))
+        run_s += time.monotonic() - t_run
+        paged_err = err(got, jax.jit(paged_einsum)(*args))
+        check(
+            math.isfinite(paged_err) and paged_err <= tol,
+            f"{name}: paged_decode_attention disagrees with the paged_read "
+            f"einsum path: rel err {paged_err} > {tol}",
+        )
+        results[name] = {
+            "shape": {"rows": rows, "nh": heads * rep, "kvh": heads, "rep": rep,
+                      "dh": dh, "page_size": page_size,
+                      "pages_per_seq": pages_per_seq},
+            "lengths": [int(x) for x in lengths],
+            "rel_err": round(paged_err, 5),
+            "tolerance": tol,
+            "compile_s": round(seconds, 1),
+        }
+    # a head of 64 (granite-4.0-h-micro) does not fill the lane tile: Mosaic
+    # refuses the copy of such a page, and the predicate keeps the gather
+    narrow = {"k": jax.ShapeDtypeStruct((n_pool, kvh, page_size, 64), dtype)}
+    with mock.patch.object(paged_attention, "on_tpu", lambda: True):
+        check(
+            not paged_attention.reads_pages_in_place(narrow, 1, None),
+            "a head of 64 would take the paged decode kernel",
+        )
     total_s = time.monotonic() - t0
     return {
         "device": device,

@@ -147,8 +147,9 @@ def run_measurements(emit) -> None:
 
     # --- paged cache: the serving layout's cost vs the contiguous cache ----
     # Same config, same step count; the delta prices the block-table
-    # gather/scatter indirection (the capacity win — densely shared pages
-    # across heterogeneous requests — is free only if this tax is small).
+    # indirection (the capacity win — densely shared pages across
+    # heterogeneous requests — is free only if this tax is small). The
+    # step attends the way the predicate picks (below).
     from bee_code_interpreter_tpu.models.transformer import decode_step_paged
     from bee_code_interpreter_tpu.ops.paged_kv_cache import (
         alloc_paged_cache,
@@ -189,28 +190,29 @@ def run_measurements(emit) -> None:
         ),
     })
 
-    # --- paged-attention kernel: in-place page reads vs the gather -------
-    # (ops/paged_attention.py — the gather materializes a contiguous cache
-    # copy per step; the kernel's speedup measures that copy's cost)
-    kernel_cfg = dataclasses.replace(config, paged_attention_kernel=True)
+    # --- the gather against the path the predicate picked ----------------
+    # (ops/paged_attention.reads_pages_in_place: on a TPU, at this head of
+    # 128, ``paged_decode`` above ran the Pallas kernel over each row's live
+    # pages; with the predicate's backend part patched off, the same step
+    # gathers the table's width through paged_read)
+    from unittest import mock
 
-    def decode_paged_kernel_n(n_steps):
-        return decode_chain(
-            lambda tok, pos, cache: decode_step_paged(
-                params, tok, jnp.full((B,), pos), cache, bt, kernel_cfg
-            ),
-            n_steps,
-        )
+    from bee_code_interpreter_tpu.ops import paged_attention
 
-    t_kn = best_of(decode_paged_kernel_n(N), first, paged0)
-    t_k1 = best_of(decode_paged_kernel_n(1), first, paged0)
-    per_step_kernel = chain_diff(t_kn, t_k1, N)
-    emit("paged_attention_kernel", {
-        "per_step_ms": round(per_step_kernel * 1e3, 3),
-        "tokens_per_sec": round(B / per_step_kernel, 1),
-        "speedup_vs_gather_path": round(
-            per_step_paged / per_step_kernel, 2
-        ),
+    picked = (
+        "pages_in_place"
+        if paged_attention.reads_pages_in_place(paged0, 1, None)
+        else "gathered"
+    )
+    with mock.patch.object(paged_attention, "on_tpu", lambda: False):
+        t_gn = best_of(decode_paged_n(N), first, paged0)
+        t_g1 = best_of(decode_paged_n(1), first, paged0)
+    per_step_gather = chain_diff(t_gn, t_g1, N)
+    emit("paged_decode_attention_path", {
+        "picked": picked,
+        "picked_per_step_ms": round(per_step_paged * 1e3, 3),
+        "gathered_per_step_ms": round(per_step_gather * 1e3, 3),
+        "speedup_vs_gather_path": round(per_step_gather / per_step_paged, 2),
     })
 
     # --- weight-only int8: decode streams half the parameter bytes ------

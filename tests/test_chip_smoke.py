@@ -111,8 +111,11 @@ def test_phase_kernels_tiny_cpu():
     )
     assert out["device"]["platform"] == "cpu"
     assert out["lowering"] == "pallas interpreter"
-    assert set(out["kernels"]) == {"flash_fwd", "flash_bwd", "paged_decode"}
+    assert set(out["kernels"]) == {
+        "flash_fwd", "flash_bwd", "paged_decode", "paged_decode_tp4_shard",
+    }
     assert out["kernels"]["paged_decode"]["lengths"] == [1, 21, 32, 58]
+    assert out["kernels"]["paged_decode_tp4_shard"]["shape"]["kvh"] == 1
 
 
 def test_phase_serving_tiny_cpu(tmp_path):

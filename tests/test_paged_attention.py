@@ -1,11 +1,12 @@
 """Pallas paged-attention decode kernel (ops/paged_attention.py) — pinned
 against the grouped-einsum oracle (the exact math the gather path
-computes), and wired end-to-end through the batcher behind
-``TransformerConfig(paged_attention_kernel=True)``.
+computes), and wired end-to-end through the batcher wherever the predicate
+``paged_attention.reads_pages_in_place`` holds (patched on here: the CPU
+is not a TPU). The slow lane's copy: ``tests/test_paged_decode_kernel.py``
+holds the tier-1 cases.
 
-CPU runs the kernel in Pallas interpreter mode; the Mosaic lowering and
-the in-place-read HBM win are hardware-battery territory
-(scripts/bench-decode.py)."""
+CPU runs the kernel in Pallas interpreter mode; ``chip_smoke.py`` lowers it
+with Mosaic and ``scripts/bench-decode.py`` times it."""
 
 import dataclasses
 import math
@@ -21,9 +22,17 @@ from bee_code_interpreter_tpu.models.transformer import (
     TransformerConfig,
     init_params,
 )
+from bee_code_interpreter_tpu.ops import paged_attention
 from bee_code_interpreter_tpu.ops.paged_attention import (
     paged_decode_attention,
 )
+
+
+def head_of_128(**kw):
+    """A tiny model whose head fills the lane tile (the predicate's size)."""
+    return dataclasses.replace(
+        TransformerConfig.tiny(), d_model=256, n_heads=2, n_kv_heads=1, **kw
+    )
 
 
 def oracle(q, k_pages, v_pages, bt, lengths):
@@ -105,23 +114,22 @@ def test_masked_slots_cannot_influence_output():
                                atol=1e-5, rtol=1e-5)
 
 
-def test_batcher_kernel_flag_matches_einsum_path():
-    """End to end: the batcher with paged_attention_kernel=True produces
-    the exact token streams of the einsum path (f32 config — the kernel
-    keeps f32 statistics where the einsum path rounds weights to the
-    compute dtype, so bf16 near-ties could differ; determinism at bf16 is
-    pinned separately below)."""
-    cfg = dataclasses.replace(
-        TransformerConfig.tiny(), n_kv_heads=2, dtype=jnp.float32,
-        paged_attention_kernel=True,
-    )
+def test_batcher_kernel_path_matches_einsum_path(monkeypatch):
+    """End to end: the batcher through the kernel produces the exact token
+    streams of the einsum path (f32 config — the kernel sums a row's slots
+    block by block where the einsums sum them at once, so bf16 near-ties
+    could differ; determinism at bf16 is pinned separately below)."""
+    cfg = head_of_128(dtype=jnp.float32)
     params = init_params(cfg, jax.random.PRNGKey(0))
     prompts = [[5, 3, 7, 2, 9, 4, 1, 8], [3, 1, 4, 1, 5]]
 
-    def run(flag):
-        c = dataclasses.replace(cfg, paged_attention_kernel=flag)
-        b = ContinuousBatcher(params, c, max_batch=2,
+    def run(engaged):
+        monkeypatch.setattr(paged_attention, "on_tpu", lambda: engaged)
+        b = ContinuousBatcher(params, cfg, max_batch=2,
                               n_pages=24, page_size=4, max_pages_per_seq=8)
+        assert b.kv_telemetry()["decode_attention"] == (
+            "pages_in_place" if engaged else "gathered"
+        )
         reqs = [b.submit(p, 6) for p in prompts]
         b.run_to_completion()
         return [b.result(r) for r in reqs]
@@ -129,10 +137,9 @@ def test_batcher_kernel_flag_matches_einsum_path():
     assert run(True) == run(False)
 
 
-def test_bf16_batcher_kernel_is_deterministic():
-    cfg = dataclasses.replace(
-        TransformerConfig.tiny(), n_kv_heads=2, paged_attention_kernel=True,
-    )
+def test_bf16_batcher_kernel_is_deterministic(monkeypatch):
+    monkeypatch.setattr(paged_attention, "on_tpu", lambda: True)
+    cfg = head_of_128()
     params = init_params(cfg, jax.random.PRNGKey(0))
 
     def run():
@@ -146,25 +153,23 @@ def test_bf16_batcher_kernel_is_deterministic():
     assert len(run()) == 6
 
 
-def test_int8_pool_and_windows_keep_the_einsum_path():
-    """The kernel gate: int8 pools and sliding windows fall back (the
-    flag is safe to leave on globally)."""
+def test_int8_pool_and_windows_keep_the_einsum_path(monkeypatch):
+    """The predicate: int8 pools and sliding windows keep the gather where
+    everything else about the program would take the kernel."""
     for extra in ({"kv_cache_dtype": "int8"}, {"sliding_window": 6}):
-        cfg = dataclasses.replace(
-            TransformerConfig.tiny(), n_kv_heads=2,
-            paged_attention_kernel=True, **extra,
-        )
+        cfg = head_of_128(**extra)
         params = init_params(cfg, jax.random.PRNGKey(0))
-        b = ContinuousBatcher(params, cfg, max_batch=1, n_pages=16,
-                              page_size=4, max_pages_per_seq=8)
-        r = b.submit([5, 3, 7, 2], 4)
-        b.run_to_completion()
-        base_cfg = dataclasses.replace(cfg, paged_attention_kernel=False)
-        b2 = ContinuousBatcher(params, base_cfg, max_batch=1, n_pages=16,
-                               page_size=4, max_pages_per_seq=8)
-        r2 = b2.submit([5, 3, 7, 2], 4)
-        b2.run_to_completion()
-        assert b.result(r) == b2.result(r2)
+
+        def run(engaged):
+            monkeypatch.setattr(paged_attention, "on_tpu", lambda: engaged)
+            b = ContinuousBatcher(params, cfg, max_batch=1, n_pages=16,
+                                  page_size=4, max_pages_per_seq=8)
+            assert b.kv_telemetry()["decode_attention"] == "gathered"
+            r = b.submit([5, 3, 7, 2], 4)
+            b.run_to_completion()
+            return b.result(r)
+
+        assert run(True) == run(False)
 
 
 def test_validation():
@@ -177,10 +182,10 @@ def test_validation():
 
 
 def test_sentinel_block_table_entries_are_harmless():
-    """-1 is a common block-table convention for 'no page'. Entries at or
-    beyond a row's visible length have their compute predicated off, but
-    the DMA still issues — the kernel clamps the index so a sentinel
-    reads in-bounds (identical output, no OOB in the Mosaic path)."""
+    """-1 is a common block-table convention for 'no page'. The kernel's
+    loop ends at the live page count, so an entry at or beyond a row's
+    visible length is never read (identical output, no OOB in the Mosaic
+    path)."""
     q, kp, vp, bt, lengths = make_case(
         jax.random.PRNGKey(7), B=2, nh=4, kvh=2, ps=8, P=4, n_pages=16
     )
