@@ -179,8 +179,6 @@ class Engine:
             interleave_admission=interleave_admission,
             prefill_chunk=prefill_chunk,
         )
-        if prefill_chunk is not None and prefill_chunk < 1:
-            raise ValueError(f"chunk must be >= 1, got {prefill_chunk}")
         if self.max_queue is not None and len(self._queued) >= self.max_queue:
             if self._metrics is not None:
                 self._rejected_total.inc()

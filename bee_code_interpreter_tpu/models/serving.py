@@ -78,12 +78,10 @@ from bee_code_interpreter_tpu.models.transformer import (
     decode_step_paged,
     decode_window_paged,
     forward,
-    prefill_chunked,
 )
 from bee_code_interpreter_tpu.ops.paged_attention import reads_pages_in_place
 from bee_code_interpreter_tpu.ops.paged_kv_cache import (
     alloc_paged_cache,
-    seed_from_contiguous,
     seed_prefill,
     seed_state,
 )
@@ -464,6 +462,19 @@ class ContinuousBatcher:
     size the shared pool; ``max_pages_per_seq`` is the block-table width
     (the static gather width per step, so it bounds prompt+generation
     length at ``max_pages_per_seq * page_size``).
+
+    A prompt's K/V reach its row's pages by one of TWO PROGRAMS, chosen at
+    one site in ``submit``: the one-shot forward over the padded prompt
+    with one scatter a pool leaf (a base row with no prefix hit and no
+    window width asked for: what every benchmark cell runs), or windows of
+    ``decode_window_paged`` over the row's own block table (prefix hits,
+    adapter rows, ``prefill_chunk``, ``interleave_admission``). One
+    admission record (``prefill_state`` holds those in flight) says which,
+    and ``_admit_next`` runs its next piece. TWO DRIVES call it:
+    ``_admit_blocking`` until the record is done, before ``submit``
+    returns, or ``_advance_prefills`` once a ``step`` for every prefilling
+    row (``interleave_admission``). Both end in ``_activate_row`` and share
+    one failure handler (``_admission``).
     """
 
     def __init__(
@@ -503,19 +514,18 @@ class ContinuousBatcher:
         ``prefix_cache=True`` turns on vLLM-style prompt prefix caching:
         full prompt pages are content-addressed by chain hash and shared
         across requests (refcounted, LRU-evicted under pool pressure, kept
-        alive past retirement for repeat prompts), and a hit admits through
-        a suffix-only prefill — per-request outputs are unchanged, pinned
-        by tests/test_prefix_cache.py.
+        alive past retirement for repeat prompts), and a hit admits its
+        suffix alone, through windows — per-request outputs are unchanged,
+        pinned by tests/test_prefix_cache.py.
 
         ``adapters`` turns on MULTI-LoRA serving (S-LoRA style): a list of
         LoRA pytrees (``models/lora.py``, attention-projection targets)
         stacked into one device bank; ``submit(adapter=i)`` serves request
         rows under adapter i — heterogeneous adapters decode together in
         one compiled program, the shared base weights streaming from HBM
-        once for the whole batch. Adapter admissions prefill through the
-        page-aligned window path (lora- AND quantization-aware — adapters
-        serve on a weight-only-int8 base too); decode applies the delta
-        unmerged per row; both use ``lora_scale`` (alpha/rank). The
+        once for the whole batch. Adapter admissions prefill through
+        windows; decode applies the delta unmerged per row; both use
+        ``lora_scale`` (alpha/rank). The
         prefix cache keys pages by (adapter, tokens), so requests under
         different adapters never share K/V. Pinned equal to solo decode
         on the merged params by tests/test_multilora_serving.py.
@@ -687,8 +697,8 @@ class ContinuousBatcher:
         self.prefix_stats = {
             "lookups": 0, "hits": 0, "pages_reused": 0, "evictions": 0,
         }
-        # row -> in-progress interleaved admission (see submit's
-        # interleave_admission): the row is occupied but not yet active
+        # row -> the record of an interleaved admission in flight (built in
+        # submit): the row is occupied but not yet active
         self.prefill_state: dict[int, dict] = {}
         # donate the pool: without aliasing, every decoded token would pay
         # a full page-pool HBM copy (precedent: make_train_step's donation)
@@ -717,12 +727,12 @@ class ContinuousBatcher:
                 seed_state, "seed_state", donate_argnums=(0,)
             )
             self._state_bytes_per_row = state_bytes_per_row(config)
-        # Admission prefill. With a mesh the full forward runs under it —
-        # in particular an ``sp`` axis shards the attention over the
-        # sequence axis (ring or Ulysses per ``config.sp_attention``, via
-        # transformer.forward), which is the LONG-CONTEXT admission path:
-        # prefill activation memory and attention FLOPs spread across sp,
-        # then the K/V reshards into the (tp-sharded) page pool. Decode
+        # The one-shot admission program. With a mesh the full forward runs
+        # under it — in particular an ``sp`` axis shards the attention over
+        # the sequence axis (ring or Ulysses per ``config.sp_attention``,
+        # via transformer.forward), which is the LONG-CONTEXT admission
+        # path: prefill activation memory and attention FLOPs spread across
+        # sp, then the K/V reshards into the (tp-sharded) page pool. Decode
         # itself stays single-token and ignores sp. ``prefill_chunk``
         # remains the single-chip activation-memory tool; sp admission is
         # the multi-chip one.
@@ -732,16 +742,8 @@ class ContinuousBatcher:
             ),
             "prefill_forward",
         )
-        # chunked admission compiles once per (total_len, chunk, L) shape —
-        # without the jit the remainder window would dispatch op-by-op
-        # eagerly on every submit
-        self._prefill_chunked = self._track(
-            functools.partial(prefill_chunked, config=config),
-            "prefill_chunked",
-            static_argnames=("total_len", "chunk"),
-        )
-        # suffix-only admission windows (prefix-cache hits); compiles once
-        # per page-aligned window width, bounded by max_pages_per_seq
+        # the admission window program; compiles once per page-aligned
+        # window width, bounded by max_pages_per_seq
         self._window = self._track(
             functools.partial(
                 decode_window_paged, config=config, lora_scale=self.lora_scale,
@@ -788,8 +790,8 @@ class ContinuousBatcher:
                 "draft_prefill_forward",
             )
             # the verify pass IS a window over the target pool — one jit
-            # wrapper (self._window) so a suffix-admission width that
-            # happens to equal gamma+1 reuses the compiled program
+            # wrapper (self._window) so an admission width that happens to
+            # equal gamma+1 reuses the compiled program
             self._verify = self._window
             self._draft_window = self._track(
                 functools.partial(
@@ -825,7 +827,6 @@ class ContinuousBatcher:
         # the step being recorded's phase -> ms (see _phase); a dict only
         # inside a step with a lifecycle monitor attached, None otherwise
         self._phase_ms: dict[str, float] | None = None
-        self._t_submit: float | None = None
         if metrics is not None:
             from bee_code_interpreter_tpu.utils.metrics import (
                 TOKEN_LATENCY_BUCKETS,
@@ -1153,7 +1154,6 @@ class ContinuousBatcher:
         # Prometheus, clear the throughput window, and drop TTFT anchors —
         # they are time.monotonic() values from the SNAPSHOTTING process's
         # clock, meaningless (possibly negative) against ours.
-        self._t_submit = None
         for rec in self.prefill_state.values():
             rec.pop("t_submit", None)
         if self._metrics is not None:
@@ -1248,6 +1248,14 @@ class ContinuousBatcher:
                 f"interleave_admission must be a positive multiple of "
                 f"page_size ({self.page_size}), got {interleave_admission}"
             )
+        if prefill_chunk is not None:
+            if prefill_chunk < 1:
+                raise ValueError(f"chunk must be >= 1, got {prefill_chunk}")
+            if interleave_admission is not None:
+                raise ValueError(
+                    "prefill_chunk and interleave_admission both name the "
+                    "admission window's width: pass one of them"
+                )
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
         if adapter is not None:
@@ -1304,26 +1312,28 @@ class ContinuousBatcher:
         if no free row or not enough free pages (callers queue and retry
         after a step frees capacity).
 
+        Without ``interleave_admission`` the call BLOCKS until the prompt
+        is in its pages and the first token is picked: through the one-shot
+        program, or (prefix hit, ``adapter``, ``prefill_chunk``) through
+        windows as wide as the block table. ``prefill_chunk`` bounds the
+        window to that many tokens, rounded down to whole pages and at
+        least one — activation memory bounded by the window, the
+        long-prompt admission — and is the same loop at another width, so
+        the result is that of the unbounded admission.
+
         ``interleave_admission`` (a page-multiple window width) admits the
         prompt INCREMENTALLY: submit allocates the row and pages but runs
-        no model; each subsequent ``step`` advances the prefill by one
-        window BEFORE decoding, so other rows keep producing tokens while
-        a long prompt admits (Sarathi-style chunked-prefill interleaving —
-        a one-shot admission stalls the whole batch for its prefill). The
-        windows are exactly the suffix-admission program family, so the
-        result is identical to the blocking admission; until the prefill
-        completes the request has no tokens and the row's block-table
-        entry stays on the scratch page (decode steps cannot touch the
-        half-written pages).
+        no model; each subsequent ``step`` runs one window BEFORE
+        decoding, so other rows keep producing tokens while a long prompt
+        admits (Sarathi-style chunked-prefill interleaving — a blocking
+        admission stalls the whole batch for its prefill). Until the
+        prefill completes the request has no tokens, and a failure lands
+        on the request (finish reason 'error') where the blocking drive
+        raises. Both widths name the one window, so giving both is refused.
 
-        ``prefill_chunk`` admits through ``prefill_chunked`` instead of the
-        one-shot O(L²) forward — activation memory bounded by the chunk,
-        the long-prompt admission path. The chunked cache is built in the
-        pool's own layout and copied into pages VERBATIM (int8 rows are
-        quantized once, never re-quantized), so a chunked admission decodes
-        exactly like prefill_chunked + contiguous decode. Trade-off: each
-        distinct (full-chunks, remainder) shape compiles once, vs the
-        padded one-shot path's max_pages_per_seq-bounded compile count.
+        On either drive the row's block-table entry stays on the scratch
+        page until activation (decode steps cannot touch the half-written
+        pages).
 
         ``adapter`` serves this request under the i-th LoRA adapter the
         batcher was constructed with (None = the base model)."""
@@ -1399,126 +1409,132 @@ class ContinuousBatcher:
                 interleaved=interleave_admission is not None,
             )
 
+        # The one site that chooses how the prompt's K/V reach the pages.
+        # A base row with no prefix hit takes the ONE-SHOT program (width
+        # None): the program family of generate_cached's prefill, which the
+        # solo-equality pins rely on bitwise at bf16, and bulk seeding.
+        # Everything else takes WINDOWS of decode_window_paged, which attend
+        # to a shared prefix through the block table and are lora- and
+        # quantization-aware (adapters serve on a weight-only-int8 base
+        # too; with no hit the whole prompt is the suffix). A window is as
+        # wide as the table unless the caller bounded it; whole pages keep
+        # the compile count bounded by max_pages_per_seq.
+        ps = self.page_size
         if interleave_admission is not None:
-            # Deferred admission: no model runs now. The block-table row
-            # stays on the scratch page so interleaved decode steps can't
-            # write into the half-filled pages; the windows carry their
-            # own table (see _advance_prefills). Speculative draft pages
-            # zero now for the same reason the blocking path zeros them.
-            if speculative:
-                # only the FRESH pages: matched prefix pages hold valid
-                # draft K/V that other rows may be sharing right now
-                fresh_arr = jnp.asarray(pages[matched:], dtype=jnp.int32)
-                self.draft_cache = {
-                    name: x.at[:, fresh_arr].set(0)
-                    for name, x in self.draft_cache.items()
-                }
-            start = matched * self.page_size
-            suffix = np.zeros(
-                (-(-(L - start) // self.page_size)) * self.page_size,
-                dtype=np.int32,
-            )
-            suffix[: L - start] = prompt[start:]
-            bt_row = np.full(
-                (1, self.block_table.shape[1]), _SCRATCH_PAGE, dtype=np.int32
-            )
-            bt_row[0, :n_need] = pages
+            width = interleave_admission
+        elif prefill_chunk is not None:
+            width = max(1, prefill_chunk // ps) * ps
+        elif matched or adapter_internal:
+            width = self.max_len
+        else:
+            width = None
+        rec = {
+            "req": req, "prompt": prompt, "pages": pages, "hashes": hashes,
+            "start": matched * ps, "pos": matched * ps, "width": width,
+            "sampling": sampling, "max_new_tokens": max_new_tokens,
+            "adapter_internal": adapter_internal, "last_row": None,
+            "t_submit": t_submit,
+        }
+        if interleave_admission is not None:
             self.results[req] = []
             self.done[req] = False
-            self.prefill_state[row] = {
-                "req": req, "prompt": prompt, "pages": pages,
-                "hashes": hashes, "suffix": suffix, "pos": start,
-                "start": start, "L": L,
-                "bt_row": bt_row, "width": interleave_admission,
-                "sampling": sampling, "max_new_tokens": max_new_tokens,
-                "adapter_internal": adapter_internal,
-                "speculative": speculative, "last_row": None,
-                "t_submit": t_submit,
-            }
-            return req
+            self.prefill_state[row] = rec  # step drives it (_advance_prefills)
+        else:
+            self._admit_blocking(row, rec)
+        return req
 
-        self.block_table[row, :] = _SCRATCH_PAGE
-        self.block_table[row, :n_need] = pages
+    def _admit_blocking(self, row: int, rec: dict) -> None:
+        """The blocking drive: every piece of the admission, then the
+        activation, before ``submit`` returns. It runs under the request's
+        serving trace (when a monitor is attached): a compile forced by a
+        new prefill shape lands as an ``xla.compile`` span inside THIS
+        request's span tree, so the TTFT it inflated is explained where
+        the operator looks for it (observability/device.py)."""
+        req = rec["req"]
+        with self._request_context(req), self._phase(
+            "serve.admit", req=req, prompt_tokens=int(rec["prompt"].shape[0]),
+            pages=len(rec["pages"]),
+        ), self._admission(row, rec, propagate=True):
+            while not self._admit_next(row, rec):
+                pass
+            with self._phase("serve.admit.activate"):
+                self._activate_row(row, rec)
 
-        # Admission runs under the request's serving trace (when a monitor
-        # is attached): a compile forced by a new prefill shape lands as an
-        # ``xla.compile`` span inside THIS request's span tree, so the TTFT
-        # it inflated is explained where the operator looks for it
-        # (observability/device.py).
-        admit_ctx = (
-            self._monitor.exemplar_context(req)
-            if self._monitor is not None
-            else nullcontext()
-        )
-        with admit_ctx, self._phase(
-            "serve.admit", req=req, prompt_tokens=L, pages=n_need
-        ):
-            return self._blocking_admit(
-                row, prompt, pages, hashes, matched, L, n_need, sampling,
-                max_new_tokens, adapter_internal, speculative,
-                prefill_chunk, req, t_submit,
-            )
+    def _request_context(self, req: int):
+        """The request's serving trace as the current context, while a
+        monitor is attached."""
+        if self._monitor is None:
+            return nullcontext()
+        return self._monitor.exemplar_context(req)
 
-    def _blocking_admit(
-        self, row, prompt, pages, hashes, matched, L, n_need, sampling,
-        max_new_tokens, adapter_internal, speculative, prefill_chunk,
-        req, t_submit,
-    ) -> int:
-        """The blocking admission tail of ``submit``: run the prefill,
-        release pages on failure, activate the row. Split out so ``submit``
-        can activate the request's trace around the whole region."""
-        logprobs = sampling is not None and sampling.logprobs
+    def _advance_prefills(self) -> None:
+        """The interleaved drive: one window of every prefilling row, at
+        the top of every ``step``."""
+        for row, rec in sorted(self.prefill_state.items()):
+            t_win, before = time.monotonic(), rec["pos"]
+            with self._request_context(rec["req"]), self._admission(
+                row, rec, propagate=False
+            ):
+                complete = self._admit_next(row, rec)
+                if self._monitor is not None:
+                    self._monitor.on_prefill_window(
+                        rec["req"],
+                        tokens=rec["pos"] - before,
+                        duration_s=time.monotonic() - t_win,
+                    )
+                if complete:
+                    del self.prefill_state[row]
+                    self._activate_row(row, rec)
+
+    @contextmanager
+    def _admission(self, row: int, rec: dict, *, propagate: bool):
+        """The one failure handler around an admission record's life, on
+        both drives. A failed admission (prefill OOM, a device error in a
+        window, a bad seed or a user callable at the first token) must not
+        leak its pages: the row never activated, so nothing else will ever
+        return them to the pool. The blocking drive then PROPAGATES (submit
+        is synchronous and the caller never receives the request id); the
+        interleaved drive, whose submit returned long ago, records the
+        error on the request and lets the step loop live (an interrupt or
+        an exit goes on up all the same)."""
         try:
-            if matched or adapter_internal > 0:
-                # Window-prefill admissions: shared-prefix hits AND every
-                # adapter admission (matched == 0 makes the whole prompt
-                # the suffix). decode_window_paged is lora- and
-                # quantization-aware, so ONE mechanism covers every
-                # combination — including adapters on a weight-only-int8
-                # base, which the old merge_lora-based admission could
-                # not serve. Base rows (adapter_internal == 0) without a
-                # hit keep the one-shot forward + bulk seeding
-                # (_full_admit): the same program family as
-                # generate_cached's prefill, which the solo-equality pins
-                # rely on bitwise at bf16.
-                # Zero only the FRESH draft pages — matched pages hold
-                # valid draft prefix K/V other rows may be sharing.
-                if speculative:
-                    fresh_arr = jnp.asarray(pages[matched:], dtype=jnp.int32)
-                    self.draft_cache = {
-                        name: x.at[:, fresh_arr].set(0)
-                        for name, x in self.draft_cache.items()
-                    }
-                last = self._suffix_admit(
-                    row, prompt, matched, speculative, prefill_chunk,
-                    adapter_internal, logprobs=logprobs,
-                )
-            else:
-                last = self._full_admit(
-                    row, prompt, pages, L, speculative, prefill_chunk,
-                    logprobs=logprobs,
-                )
+            yield
         except BaseException as e:
-            # a failed admission (prefill OOM, bad sampling params, ...)
-            # must not leak its pages: the row never activated, so nothing
-            # else will ever return them to the pool. Shared pages drop the
-            # acquired ref (back to the LRU if nobody else holds them);
-            # fresh ones go straight back to the free list. (Unlike
-            # mid-decode, a user-callable error here PROPAGATES: submit is
-            # synchronous and the caller never receives the request id.)
-            self.block_table[row, :] = _SCRATCH_PAGE
-            for page in reversed(pages):
-                self._release_page(page)
-            if self._monitor is not None:
-                self._monitor.on_done(req, "error", tokens=0, error=repr(e))
-            raise
-        self._prefill_tokens += L - matched * self.page_size
-        self._t_submit = t_submit
-        with self._phase("serve.admit.activate"):
-            return self._activate_row(
-                row, last, prompt, pages, hashes, L, sampling,
-                max_new_tokens, adapter_internal, req=req, propagate=True,
+            if "pages" not in rec:
+                raise  # the row is active and owns them: not an admission's
+            self._finish_unadmitted(
+                row, rec, "error", error=repr(e), record=not propagate
             )
+            if propagate or not isinstance(e, Exception):
+                raise
+
+    def _abandon(self, row: int, rec: dict) -> None:
+        """Give back what an admission record holds: the row, and its
+        pages (shared ones drop the acquired ref, back to the LRU if nobody
+        else holds them; fresh ones go straight back to the free list)."""
+        self.prefill_state.pop(row, None)
+        self.block_table[row, :] = _SCRATCH_PAGE
+        for page in reversed(rec.pop("pages")):
+            self._release_page(page)
+
+    def _finish_unadmitted(
+        self, row: int, rec: dict, reason: str, error=None, record=True
+    ) -> None:
+        """End a request whose row never activated, with no tokens:
+        ``_abandon`` its record and, unless the caller never received the
+        id (``record`` False), leave the empty result readable."""
+        req = rec["req"]
+        self._abandon(row, rec)
+        if record:
+            self.results[req] = []
+            self.done[req] = True
+            self.finish[req] = reason
+            if error is not None:
+                self.errors[req] = error
+            if rec["sampling"] is not None and rec["sampling"].logprobs:
+                self.results_logprobs[req] = []
+        if self._monitor is not None:
+            self._monitor.on_done(req, reason, tokens=0, error=error)
 
     def _pull_last_row(self, logits_row, logprobs: bool):
         """The last prompt token's logits row [V] off the device, with its
@@ -1529,58 +1545,27 @@ class ContinuousBatcher:
         row = np.asarray(logits_row, dtype=np.float32)
         return row, None if log_z is None else float(log_z)
 
-    def _activate_row(
-        self, row, last, prompt, pages, hashes, L, sampling,
-        max_new_tokens, adapter_internal, req, propagate=False,
-    ) -> int:
-        """Admission epilogue, shared by the blocking path and interleaved
-        finalization: register prefix pages, sample the first token (from
-        ``last``, ``_pull_last_row``'s pair), activate the row. ``req`` was
-        allocated by ``submit``;
-        ``propagate`` re-raises first-token failures (the blocking path —
-        the caller never received the id) instead of recording them on the
-        ticket (interleaved finalization — submit returned long ago)."""
-        sampling = sampling or SamplingParams()
-        last_row, log_z = last
+    def _activate_row(self, row: int, rec: dict) -> None:
+        """Admission epilogue of both drives: pick the first token (from
+        ``rec["last_row"]``, ``_pull_last_row``'s pair), register prefix
+        pages, hand the record's pages to the row and activate it. A
+        first-token failure (a bad seed, a user callable) is the
+        ``_admission`` handler's."""
+        sampling = rec["sampling"] or SamplingParams()
+        req, prompt, hashes = rec["req"], rec["prompt"], rec["hashes"]
+        L = int(prompt.shape[0])
+        last_row, log_z = rec["last_row"]
+        rng = np.random.default_rng(sampling.seed)
         try:
-            # rng construction INSIDE the protected region: a bad seed
-            # must release the pages like any other first-token failure
-            rng = np.random.default_rng(sampling.seed)
             first = choose_host(last_row, sampling, rng, [])
         except ConstraintExhausted:
             # the constraint permits no FIRST token: the request is
             # complete with an empty output (grammar terminal at step 0) —
             # a finished request, not an error; pages go straight back
-            self.block_table[row, :] = _SCRATCH_PAGE
-            for page in reversed(pages):
-                self._release_page(page)
-            self.results[req] = []
-            if sampling.logprobs:
-                self.results_logprobs[req] = []
-            self.done[req] = True
-            self.finish[req] = "constraint"
-            if self._monitor is not None:
-                self._monitor.on_done(req, "constraint", tokens=0)
-            return req
-        except BaseException as _activation_error:
-            # user-callable failure at the first token: release the pages
-            # either way; blocking submit PROPAGATES, interleaved
-            # finalization records the error on the ticket
-            self.block_table[row, :] = _SCRATCH_PAGE
-            for page in reversed(pages):
-                self._release_page(page)
-            if self._monitor is not None:
-                self._monitor.on_done(
-                    req, "error", tokens=0, error=repr(_activation_error)
-                )
-            if propagate:
-                raise
-            self.done[req] = True
-            self.finish[req] = "error"
-            if sampling.logprobs:
-                self.results_logprobs[req] = []
-            self.errors[req] = repr(_activation_error)
-            return req
+            self._finish_unadmitted(row, rec, "constraint")
+            return
+        # the row owns the pages from here: _retire gives them back
+        pages = rec.pop("pages")
         if self.prefix_cache_enabled:
             # index every page fully inside [0, L): those pages are
             # write-free for the rest of this request's life (the decode
@@ -1603,10 +1588,11 @@ class ContinuousBatcher:
                         self.free_pages.append(prev)
                 self.prefix_index[hashes[j]] = page
                 self.page_hash[page] = hashes[j]
+        self.block_table[row, : len(pages)] = pages  # the rest is scratch
         self.pos[row] = L
         self.current[row, 0] = first
-        self.budget[row] = max_new_tokens
-        self.row_adapter[row] = adapter_internal
+        self.budget[row] = rec["max_new_tokens"]
+        self.row_adapter[row] = rec["adapter_internal"]
         self.row_request[row] = req
         self.row_sampling[row] = sampling
         self.row_rng[row] = rng
@@ -1618,238 +1604,137 @@ class ContinuousBatcher:
             # the exemplar context below finds the live record.
             self._monitor.on_first_token(req)
         if self._metrics is not None:
-            if self._t_submit is not None:
+            # absent on a record restored from a snapshot: its anchor was
+            # another process's clock (load_state_dict)
+            if "t_submit" in rec:
                 # Observed under the request's serving trace (when a
                 # monitor is attached) so the OpenMetrics exemplar on
                 # bci_serving_ttft_seconds names the same trace_id the wide
                 # event and /v1/traces carry.
-                ctx = (
-                    self._monitor.exemplar_context(req)
-                    if self._monitor is not None
-                    else nullcontext()
-                )
-                with ctx:
+                with self._request_context(req):
                     self._ttft_seconds.observe(
-                        time.monotonic() - self._t_submit
+                        time.monotonic() - rec["t_submit"]
                     )
-                self._t_submit = None
             self._sync_token_counter()
         if sampling.logprobs:
             self.results_logprobs[req] = [logprob_of(last_row, first, log_z)]
         self.done[req] = False
         self.active[row] = True
         self._retire_if_done(row)
-        return req
 
-    def _advance_prefills(self) -> None:
-        """One window of interleaved admission per prefilling row, run at
-        the top of every ``step`` — the windows are the suffix-admission
-        program family over the record's OWN block table (the global table
-        keeps the row on the scratch page until activation)."""
-        for row in sorted(self.prefill_state):
-            rec = self.prefill_state[row]
-            # suffix-relative offset of the next window (pos is absolute;
-            # the suffix array starts at the absolute position rec["start"],
-            # i.e. right after any prefix-cache hit — NOT at L minus the
-            # padded suffix length)
-            done_tokens = rec["pos"] - rec["start"]
-            win = rec["suffix"][done_tokens: done_tokens + rec["width"]]
-            bt_row = jnp.asarray(rec["bt_row"])
-            win_arr = jnp.asarray(win[None, :])
-            pos_arr = jnp.asarray([rec["pos"]], dtype=np.int32)
-            t_win = time.monotonic()
-            # under the request's trace (monitor attached): a compile
-            # forced by a new window width attributes to THIS request
-            win_ctx = (
-                self._monitor.exemplar_context(rec["req"])
-                if self._monitor is not None
-                else nullcontext()
-            )
-            with win_ctx:
-                logits, self.cache = self._window(
-                    self.params, win_arr, pos_arr, self.cache, bt_row,
-                    **self._lora_kwargs(np.array([rec["adapter_internal"]])),
-                )
-                if rec["speculative"]:
-                    _, self.draft_cache = self._draft_window(
-                        self.draft_params, win_arr, pos_arr,
-                        self.draft_cache, bt_row,
-                    )
-            idx = rec["L"] - 1 - rec["pos"]  # last REAL token in window?
-            if 0 <= idx < win.shape[0]:
-                rec["last_row"] = self._pull_last_row(
-                    logits[0, idx],
-                    rec["sampling"] is not None and rec["sampling"].logprobs,
-                )
-            rec["pos"] += int(win.shape[0])
-            self._prefill_tokens += int(win.shape[0])
-            if self._monitor is not None:
-                self._monitor.on_prefill_window(
-                    rec["req"],
-                    tokens=int(win.shape[0]),
-                    duration_s=time.monotonic() - t_win,
-                )
-            if done_tokens + rec["width"] >= len(rec["suffix"]):
-                # prefill complete: publish the pages and activate
-                del self.prefill_state[row]
-                n_need = len(rec["pages"])
-                self.block_table[row, :] = _SCRATCH_PAGE
-                self.block_table[row, :n_need] = rec["pages"]
-                self._t_submit = rec.get("t_submit")
-                self._activate_row(
-                    row, rec["last_row"], rec["prompt"], rec["pages"],
-                    rec["hashes"], rec["L"], rec["sampling"],
-                    rec["max_new_tokens"], rec["adapter_internal"],
-                    req=rec["req"],
-                )
+    def _admit_next(self, row: int, rec: dict) -> bool:
+        """Run the next piece of ``rec``'s admission and move its cursor:
+        the whole prompt through the one-shot program (``width`` None), or
+        one window. True once the prompt's K/V are all in the row's pages;
+        ``rec["last_row"]`` then holds the last prompt token's logits row
+        as ``_pull_last_row`` gives it.
 
-    # ------------------------------------------------- admission sub-paths
-    def _full_admit(self, row, prompt, pages, L, speculative, prefill_chunk,
-                    logprobs=False):
-        """Whole-prompt BASE admission (no prefix hit, no adapters — those
-        route through ``_suffix_admit``): one-shot or chunked prefill into
-        this row's pages (and, over mamba layers, the row's state); returns
-        the last prompt token's logits row as ``_pull_last_row`` gives it."""
-        n_prompt_pages = -(-L // self.page_size)
-        pages_arr = jnp.asarray(pages[:n_prompt_pages], dtype=jnp.int32)
-        # the prompt padded to a whole number of pages — shared by the
-        # one-shot target prefill and the draft prefill (one copy: a
-        # divergent pad between the two would desync their caches)
-        Lp = n_prompt_pages * self.page_size
-        padded = np.zeros(Lp, dtype=np.int32)
-        padded[:L] = prompt
-        # zero the DRAFT pool's allocated pages: recycled pages hold a
-        # previous request's K/V, and only speculative drafting can
-        # read a not-yet-written slot inside its visible window (the
-        # full-accept gap below) — zeros make that read deterministic
-        # and pool-history-independent, matching the contiguous
-        # speculative_generate's zero-initialized cache. The target
-        # pool needs no zeroing: plain decode and the verify only read
-        # slots already written (prefill-seeded or appended by the
-        # very window doing the reading; the rest are masked), so
-        # zeroing it would just copy the whole pool per admission.
-        if speculative:
-            all_pages = jnp.asarray(pages, dtype=jnp.int32)
+        Windows are page-aligned. Pad tokens in the final window write
+        garbage K/V at positions >= L, which is safe for the same reason
+        the speculative window's rejected drafts are: those slots sit
+        beyond the cursor, are causally invisible until the cursor reaches
+        them, and every decode write lands before the read that could see
+        it. In speculative mode the draft pool replays the same windows so
+        both caches stay in lockstep. The windows carry their own table:
+        the global one keeps the row on the scratch page until activation."""
+        prompt, pos, pages = rec["prompt"], rec["pos"], rec["pages"]
+        ps = self.page_size
+        L = int(prompt.shape[0])
+        Lp = -(-L // ps) * ps  # the prompt padded to a whole number of pages
+        speculative = self.draft_params is not None
+        logprobs = rec["sampling"] is not None and rec["sampling"].logprobs
+        if speculative and pos == rec["start"]:
+            # zero the DRAFT pool's fresh pages: recycled pages hold a
+            # previous request's K/V, and only speculative drafting can
+            # read a not-yet-written slot inside its visible window (the
+            # full-accept gap, _step_speculative) — zeros make that read
+            # deterministic and pool-history-independent, matching the
+            # contiguous speculative_generate's zero-initialized cache.
+            # Only the FRESH pages: matched prefix pages hold valid draft
+            # K/V that other rows may be sharing right now. The target
+            # pool needs no zeroing: plain decode and the verify only read
+            # slots already written (seeded, or appended by the very
+            # window doing the reading; the rest are masked), so zeroing
+            # it would just copy the whole pool per admission.
+            fresh = jnp.asarray(pages[pos // ps:], dtype=jnp.int32)
             self.draft_cache = {
-                name: x.at[:, all_pages].set(0)
+                name: x.at[:, fresh].set(0)
                 for name, x in self.draft_cache.items()
             }
-        if prefill_chunk is not None:
-            # bounded-memory admission: the chunked prefill builds the
-            # cache in the pool's layout; copy its leaves verbatim
-            with self._phase("serve.admit.prefill"):
-                last_logits, contig = self._prefill_chunked(
-                    self.params, prompt[None, :],
-                    total_len=n_prompt_pages * self.page_size,
-                    chunk=prefill_chunk,
-                )
-            with self._phase("serve.admit.seed_pool"):
-                self.cache = seed_from_contiguous(
-                    self.cache, pages_arr,
-                    {name: x[:, 0] for name, x in contig.items()},
-                )
-            with self._phase("serve.admit.pull"):
-                last = self._pull_last_row(last_logits[0], logprobs)
-        else:
-            # one-shot prefill: exact O(L^2) forward, then the shared
-            # one-scatter-per-leaf page seeding (seed_prefill — the
-            # equality tests call the same function, so the tested
-            # path IS this path). The padded prompt bounds the compile
-            # count: pad tokens are causal-masked for every row < L,
-            # so logits[L-1] and K/V[:L] are exact, and distinct
-            # prompt lengths share a program per page count instead of
-            # one per length.
-            with self._phase("serve.admit.prefill"):
-                if self._seed_state is None:
-                    logits, (k_pre, v_pre) = self._prefill(
-                        self.params, padded[None, :]
-                    )
-                else:  # the state it hands back is that of the L real tokens
-                    logits, (k_pre, v_pre, ssm, conv) = self._prefill(
-                        self.params, padded[None, :], length=np.int32(L)
-                    )
-            with self._phase("serve.admit.seed_pool"):
-                self.cache = seed_prefill(
-                    self.cache, pages_arr,
-                    k_pre[:, 0, :, :L, :], v_pre[:, 0, :, :L, :],
-                )
-            if self._seed_state is not None:
-                with self._phase(
-                    "serve.admit.seed_state", rows=1,
-                    bytes=ssm.nbytes + conv.nbytes,
-                ):
-                    self.cache = self._seed_state(
-                        self.cache, np.int32(row), ssm, conv
-                    )
-            # waits for the device (prefill and seeding), then copies
-            with self._phase("serve.admit.pull"):
-                last = self._pull_last_row(logits[0, L - 1, :], logprobs)
-        if speculative:
-            # draft prefill into ITS pool at the same pages (the draft
-            # is small — the padded one-shot prefill is fine even when
-            # the target admission was chunked)
-            _, (dk, dv) = self._draft_prefill(
-                self.draft_params, padded[None, :]
+        end = Lp if rec["width"] is None else min(pos + rec["width"], Lp)
+        # one copy of the padding: a divergent pad between the target's
+        # and the draft's tokens would desync their caches
+        tokens = np.zeros((1, end - pos), dtype=np.int32)
+        tokens[0, : L - pos] = prompt[pos:end]
+        if rec["width"] is None:
+            rec["last_row"] = self._one_shot(
+                row, tokens, L, pages, speculative, logprobs
             )
+        else:
+            table = np.full(
+                (1, self.block_table.shape[1]), _SCRATCH_PAGE, dtype=np.int32
+            )
+            table[0, : len(pages)] = pages
+            tokens, table = jnp.asarray(tokens), jnp.asarray(table)
+            at = jnp.asarray([pos], dtype=jnp.int32)
+            logits, self.cache = self._window(
+                self.params, tokens, at, self.cache, table,
+                **self._lora_kwargs(np.array([rec["adapter_internal"]])),
+            )
+            if speculative:
+                _, self.draft_cache = self._draft_window(
+                    self.draft_params, tokens, at, self.draft_cache, table
+                )
+            if end >= L:  # the window that holds the last REAL token
+                rec["last_row"] = self._pull_last_row(
+                    logits[0, L - 1 - pos], logprobs
+                )
+        rec["pos"] = end
+        self._prefill_tokens += min(end, L) - pos
+        return end >= L
+
+    def _one_shot(self, row, padded, L, pages, speculative, logprobs):
+        """The one-shot admission program over the ``padded`` prompt
+        [1, Lp]: the exact O(L^2) forward, then the shared
+        one-scatter-per-leaf page seeding (seed_prefill — the equality
+        tests call the same function, so the tested path IS this path)
+        and, over mamba layers, the row's state. The padded prompt bounds
+        the compile count: pad tokens are causal-masked for every row < L,
+        so logits[L-1] and K/V[:L] are exact, and distinct prompt lengths
+        share a program per page count instead of one per length."""
+        pages_arr = jnp.asarray(
+            pages[: padded.shape[1] // self.page_size], dtype=jnp.int32
+        )
+        with self._phase("serve.admit.prefill"):
+            if self._seed_state is None:
+                logits, (k_pre, v_pre) = self._prefill(self.params, padded)
+            else:  # the state it hands back is that of the L real tokens
+                logits, (k_pre, v_pre, ssm, conv) = self._prefill(
+                    self.params, padded, length=np.int32(L)
+                )
+        with self._phase("serve.admit.seed_pool"):
+            self.cache = seed_prefill(
+                self.cache, pages_arr,
+                k_pre[:, 0, :, :L, :], v_pre[:, 0, :, :L, :],
+            )
+        if self._seed_state is not None:
+            with self._phase(
+                "serve.admit.seed_state", rows=1,
+                bytes=ssm.nbytes + conv.nbytes,
+            ):
+                self.cache = self._seed_state(
+                    self.cache, np.int32(row), ssm, conv
+                )
+        # waits for the device (prefill and seeding), then copies
+        with self._phase("serve.admit.pull"):
+            last = self._pull_last_row(logits[0, L - 1, :], logprobs)
+        if speculative:
+            # the draft's prefill into ITS pool at the same pages
+            _, (dk, dv) = self._draft_prefill(self.draft_params, padded)
             self.draft_cache = seed_prefill(
                 self.draft_cache, pages_arr,
                 dk[:, 0, :, :L, :], dv[:, 0, :, :L, :],
             )
-        return last
-
-    def _suffix_admit(self, row, prompt, matched, speculative, prefill_chunk,
-                      adapter_internal=0, logprobs=False):
-        """Window-prefill admission — prefix-cache hits (``matched`` > 0:
-        only the suffix runs through the model) AND every adapter
-        admission (``matched`` == 0: the whole prompt is the suffix) — as
-        consecutive ``decode_window_paged`` windows that append suffix K/V
-        into the row's fresh pages while attending to the shared prefix
-        through the block table — the paged analogue of chunked prefill
-        (``prefill_chunk`` bounds the window width the same way).
-
-        Windows are page-aligned (every width a multiple of page_size), so
-        the compile count stays bounded by max_pages_per_seq — the same
-        bound as the padded one-shot path. Pad tokens in the final window
-        write garbage K/V at positions >= L, which is safe for the same
-        reason the speculative window's rejected drafts are: those slots
-        sit beyond the cursor, are causally invisible until the cursor
-        reaches them, and every decode write lands before the read that
-        could see it. In speculative mode the draft pool replays the same
-        windows so both caches stay in lockstep.
-
-        Returns the last prompt token's logits row as ``_pull_last_row``
-        gives it."""
-        ps = self.page_size
-        L = int(prompt.shape[0])
-        start = matched * ps
-        if prefill_chunk is not None and prefill_chunk < 1:
-            raise ValueError(f"chunk must be >= 1, got {prefill_chunk}")
-        chunk_pages = (
-            max(1, prefill_chunk // ps) if prefill_chunk is not None
-            else self.block_table.shape[1]
-        )
-        suffix = np.zeros((-(-(L - start) // ps)) * ps, dtype=np.int32)
-        suffix[: L - start] = prompt[start:]
-        bt_row = jnp.asarray(self.block_table[row:row + 1])
-        last = None
-        pos = start
-        for off in range(0, len(suffix), chunk_pages * ps):
-            win = suffix[off: off + chunk_pages * ps]
-            win_arr = jnp.asarray(win[None, :])
-            pos_arr = jnp.asarray([pos], dtype=jnp.int32)
-            logits, self.cache = self._window(
-                self.params, win_arr, pos_arr, self.cache, bt_row,
-                **self._lora_kwargs(np.array([adapter_internal])),
-            )
-            if speculative:
-                _, self.draft_cache = self._draft_window(
-                    self.draft_params, win_arr, pos_arr,
-                    self.draft_cache, bt_row,
-                )
-            idx = L - 1 - pos  # last REAL token's index within this window
-            if 0 <= idx < win.shape[0]:
-                last = self._pull_last_row(logits[0, idx], logprobs)
-            pos += int(win.shape[0])
         return last
 
     # ------------------------------------------------------------ multi-LoRA
@@ -2484,17 +2369,8 @@ class ContinuousBatcher:
                 return
         for row, rec in list(self.prefill_state.items()):
             if rec["req"] == request_id:
-                # admission still interleaving: free the pages (shared
-                # ones drop their ref), keep the empty result readable
-                del self.prefill_state[row]
-                for page in reversed(rec["pages"]):
-                    self._release_page(page)
-                self.done[request_id] = True
-                self.finish[request_id] = "cancelled"
-                if rec["sampling"] is not None and rec["sampling"].logprobs:
-                    self.results_logprobs[request_id] = []
-                if self._monitor is not None:
-                    self._monitor.on_done(request_id, "cancelled", tokens=0)
+                # admission still interleaving
+                self._finish_unadmitted(row, rec, "cancelled")
                 return
         if request_id not in self.done:
             raise KeyError(f"unknown request {request_id}")
@@ -2511,9 +2387,7 @@ class ContinuousBatcher:
         request want :meth:`cancel`, which keeps its partial output."""
         for row, rec in list(self.prefill_state.items()):
             if rec["req"] == request_id:
-                del self.prefill_state[row]
-                for page in reversed(rec["pages"]):
-                    self._release_page(page)
+                self._abandon(row, rec)
                 self.results.pop(request_id, None)
                 self.done.pop(request_id, None)
                 if self._monitor is not None:

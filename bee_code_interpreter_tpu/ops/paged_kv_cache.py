@@ -291,28 +291,3 @@ def seed_state(cache: dict, row: jax.Array, ssm: jax.Array, conv: jax.Array) -> 
         return lax.dynamic_update_slice_in_dim(leaf, new.astype(leaf.dtype), row, 1)
 
     return {**cache, "ssm": put(cache["ssm"], ssm), "conv": put(cache["conv"], conv)}
-
-
-def seed_from_contiguous(
-    cache: dict,  # paged pool: leaves [n_layers, n_pages, kvh, ps, last]
-    pages: jax.Array,  # [P] int32 — pages covering the contiguous cache
-    contig_row: dict,  # ONE row's contiguous cache: [n_layers, kvh, P·ps, last]
-) -> dict:
-    """Copy a contiguous-cache row (already in the pool's layout — bf16
-    values or int8 values+scales) into pages VERBATIM. This is how chunked
-    prefill admits into the pool: ``prefill_chunked`` builds the layout
-    (quantizing per row for int8), and re-quantizing its dequantized
-    values would double the rounding — a straight leaf copy keeps paged
-    admission bit-identical to the contiguous cache it came from."""
-    P = int(pages.shape[0])
-    ps = cache["k"].shape[3]
-    out = dict(cache)
-    for name, x in contig_row.items():
-        nl, kvh, total, last = x.shape
-        if total != P * ps:
-            raise ValueError(
-                f"contiguous length {total} != {P} pages of {ps}"
-            )
-        vals = x.reshape(nl, kvh, P, ps, last).transpose(0, 2, 1, 3, 4)
-        out[name] = out[name].at[:, pages].set(vals.astype(out[name].dtype))
-    return out

@@ -129,7 +129,6 @@ SLOW_TEST_MODULES = {
     "test_baseline_configs", "test_beam", "test_bench", "test_bench_mfu",
     "test_checkpoint", "test_chunked_prefill", "test_engine",
     "test_example_payloads", "test_flash_attention", "test_hf_loader",
-    "test_interleaved_admission",
     "test_kv_cache", "test_local_code_executor", "test_lora", "test_models",
     "test_moe", "test_multihost_distributed", "test_multilora_serving",
     "test_paged_attention", "test_paged_kv_cache", "test_parallel",

@@ -42,6 +42,10 @@ class FakeExecutorPods:
         self._runners: dict[str, web.AppRunner] = {}
         self.cores: dict[str, ExecutorCore] = {}
         self.execute_counts: dict[str, int] = {}
+        # data-plane requests by kind, over all pods: "execute", "upload"
+        # (a file restored into a workspace), "download" (a changed file
+        # snapshotted out of one)
+        self.op_counts = {"execute": 0, "upload": 0, "download": 0}
         self._next_ip = 1
 
     async def start_pod(self, manifest: dict | None = None) -> str:
@@ -62,13 +66,14 @@ class FakeExecutorPods:
 
         @web.middleware
         async def inject_faults(request, handler):
-            if self.faults is not None:
-                op = None
-                if request.path.startswith("/execute"):
-                    op = "execute"
-                elif request.path.startswith("/workspace"):
-                    op = "upload" if request.method == "PUT" else "download"
-                if op is not None:
+            op = None
+            if request.path.startswith("/execute"):
+                op = "execute"
+            elif request.path.startswith("/workspace"):
+                op = "upload" if request.method == "PUT" else "download"
+            if op is not None:
+                self.op_counts[op] += 1
+                if self.faults is not None:
                     response = await self.faults.apply_http(
                         # kill lets DieMidExecute take this whole pod down,
                         # not just the one connection.

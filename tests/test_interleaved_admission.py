@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from bee_code_interpreter_tpu.models import transformer as T
 from bee_code_interpreter_tpu.models.engine import Engine
+from bee_code_interpreter_tpu.models.lora import init_lora, merge_lora
 from bee_code_interpreter_tpu.models.serving import (
     ContinuousBatcher,
     SamplingParams,
@@ -214,3 +215,176 @@ def test_bad_seed_releases_pages_even_at_activation():
     assert b.finish_reason(r) == "error"
     assert "ValueError" in b.request_error(r)
     assert int(b.stats["held_pages"]) == 0
+
+
+# ------------------------------------------------- one admission, two drives
+SCALE = 2.0
+_lora = init_lora(CFG, jax.random.PRNGKey(1), rank=4, targets=("wq", "wv"))
+# init_lora zeroes B (an adapter equal to the base): give it a delta that
+# visibly changes the logits
+ADAPTER = {
+    t: {
+        "A": ab["A"],
+        "B": jax.random.normal(
+            jax.random.PRNGKey(101), ab["B"].shape, jnp.float32
+        ) * 0.25,
+    }
+    for t, ab in _lora.items()
+}
+DRIVES = {
+    "one_shot": {},
+    "prefill_chunk": {"prefill_chunk": 4},
+    "interleave_admission": {"interleave_admission": 8},
+}
+
+
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("row", ["base", "adapter", "prefix_hit"])
+@pytest.mark.parametrize("drive", DRIVES)
+def test_every_drive_admits_what_the_one_shot_program_admits(drive, row, pool):
+    """Whatever the drive (blocking at the default width, blocking at
+    ``prefill_chunk``, a window a step) and whatever sends a row through
+    windows (an adapter, a prefix hit, the width itself), the request
+    decodes what the one-shot program admits for the same weights."""
+    cfg = dataclasses.replace(CFG, kv_cache_dtype=pool)
+    geometry = dict(max_batch=2, n_pages=32, page_size=4, max_pages_per_seq=8)
+    merged = merge_lora(PARAMS, ADAPTER, SCALE) if row == "adapter" else PARAMS
+    one_shot = ContinuousBatcher(merged, cfg, **geometry)
+    want_id = one_shot.submit(LONG, 5)
+    one_shot.run_to_completion()
+
+    b = ContinuousBatcher(
+        PARAMS, cfg, **geometry,
+        adapters=[ADAPTER] if row == "adapter" else None, lora_scale=SCALE,
+        prefix_cache=row == "prefix_hit",
+    )
+    if row == "prefix_hit":  # a first tenant leaves LONG's pages indexed
+        b.submit(LONG, 2)
+        b.run_to_completion()
+    r = b.submit(
+        LONG, 5, adapter=0 if row == "adapter" else None, **DRIVES[drive]
+    )
+    b.run_to_completion()
+    assert b.result(r) == one_shot.result(want_id)
+    if row == "prefix_hit":
+        assert b.prefix_stats["hits"] == 1
+    assert int(b.stats["held_pages"]) == 0
+
+
+def books(b):
+    """What an admission borrows from the batcher, as comparable values."""
+    kv = b.kv_telemetry()
+    return {
+        "free_pages": sorted(b.free_pages),
+        "page_ref": b.page_ref.tolist(),
+        "evictable": sorted(b.evictable),
+        "rows": (b.active.tolist(), sorted(b.prefill_state), b.has_free_row()),
+        "block_table": b.block_table.tolist(),
+        "held": kv["pages_allocated_total"] - kv["pages_released_total"],
+    }
+
+
+def drive_to_failure(b, drive, **kw):
+    """Submit LONG on ``drive`` into a batcher rigged to fail: the blocking
+    drive raises out of ``submit``; the interleaved one returns an id whose
+    ticket reads the error once the windows have run, and the step loop
+    lives."""
+    if drive == "blocking":
+        with pytest.raises(RuntimeError, match="device lost"):
+            b.submit(LONG, 4, **kw)
+        return
+    r = b.submit(LONG, 4, interleave_admission=4, **kw)
+    while not b.is_done(r):
+        b.step()
+    assert b.finish_reason(r) == "error"
+    assert "device lost" in b.request_error(r)
+    assert b.result(r) == []
+
+
+@pytest.mark.parametrize("prefix_hit", [False, True])
+@pytest.mark.parametrize("windows_before", [0, 2])
+@pytest.mark.parametrize("drive", ["blocking", "interleaved"])
+def test_a_failing_window_gives_everything_back(
+    drive, windows_before, prefix_hit
+):
+    """A device error out of the window program, at the first window or a
+    later one: fresh pages return to the free list, shared ones drop the
+    ref the admission took, the row frees, and a row decoding beside it
+    never notices."""
+    b = make(prefix_cache=prefix_hit)
+    if prefix_hit:  # a first tenant leaves LONG's first two pages indexed
+        first = b.submit(LONG[:9], 2)
+        b.run_to_completion()
+        b.release(first)
+    r_short = b.submit(SHORT, 8)  # decodes beside the admission
+    before = books(b)
+    real, calls = b._window, []
+
+    def window(*args, **kwargs):
+        calls.append(1)
+        if len(calls) > windows_before:
+            raise RuntimeError("device lost")
+        return real(*args, **kwargs)
+
+    b._window = window
+    # a width sends the blocking drive through windows too
+    drive_to_failure(
+        b, drive, **({"prefill_chunk": 4} if drive == "blocking" else {})
+    )
+    assert len(calls) == windows_before + 1
+    if prefix_hit:
+        assert b.prefix_stats["pages_reused"] == 2
+    b._window = real
+    assert books(b) == before
+    # and the pool admits the same prompt again
+    r = b.submit(LONG, 4)
+    b.run_to_completion()
+    assert b.result(r) == solo(LONG, 4)
+    assert b.result(r_short) == solo(SHORT, 8)
+
+
+@pytest.mark.parametrize("drive", ["blocking", "interleaved"])
+def test_a_failure_while_draft_pages_are_zeroed_gives_everything_back(drive):
+    draft_cfg = dataclasses.replace(CFG, n_layers=1)
+    draft_params = T.init_params(draft_cfg, jax.random.PRNGKey(1))
+    b = make(draft_params=draft_params, draft_config=draft_cfg, gamma=3)
+    before = books(b)
+
+    class Lost(dict):
+        def items(self):
+            raise RuntimeError("device lost")
+
+    pool = b.draft_cache
+    b.draft_cache = Lost(pool)
+    drive_to_failure(b, drive)
+    b.draft_cache = pool
+    assert books(b) == before
+    r = b.submit(LONG, 4)
+    b.run_to_completion()
+    assert b.result(r) == solo(LONG, 4)
+
+
+@pytest.mark.parametrize("surface", ["validate_request", "submit", "engine"])
+@pytest.mark.parametrize(
+    "kwargs, message",
+    [
+        ({"prefill_chunk": 4, "interleave_admission": 4}, "pass one of them"),
+        ({"prefill_chunk": 0}, "chunk must be >= 1"),
+    ],
+    ids=["both_widths", "chunk_0"],
+)
+def test_window_width_is_validated_once_for_every_surface(
+    surface, kwargs, message
+):
+    """Both parameters name the window's width, so the pair is refused
+    (one used to be dropped without a word), and a width of 0 is refused by
+    ``validate_request`` itself: at the engine's intake, not minutes later."""
+    b = make()
+    call = {
+        "validate_request": b.validate_request,
+        "submit": b.submit,
+        "engine": Engine(b).submit,
+    }[surface]
+    with pytest.raises(ValueError, match=message):
+        call(LONG, 4, **kwargs)
+    assert books(b) == books(make())

@@ -97,12 +97,16 @@ def test_snapshot_restore_does_not_replay_metrics():
     b1.submit(np.asarray([1, 2, 3]), 6)
     b1.step()
     b1.step()
+    # ...and one admission caught mid-prefill, whose TTFT anchor is a
+    # reading of the snapshotting process's clock
+    b1.submit(np.arange(1, 10), 2, interleave_admission=4)
     snap = b1.state_dict()
 
     reg2 = Registry()
     b2 = make_batcher(reg2)
     b2.load_state_dict(snap)
-    assert b2._t_submit is None
+    assert b2.prefill_state
+    assert not any("t_submit" in rec for rec in b2.prefill_state.values())
     import re
 
     assert not re.search(
@@ -112,6 +116,7 @@ def test_snapshot_restore_does_not_replay_metrics():
     generated_before = snap["host"]["n_tokens_generated"]
     expected = b2.n_tokens_generated - generated_before
     assert f"bci_serving_tokens_total {expected}" in reg2.expose()
+    assert not re.search(r"^bci_serving_ttft_seconds_count [1-9]", reg2.expose(), re.M)
 
 
 def test_tokens_per_second_decays_to_zero_when_idle():

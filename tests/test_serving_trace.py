@@ -568,7 +568,7 @@ def test_serve_spans_nest_and_carry_their_stats(monkeypatch, mix):
 
 TRACKED = {
     "_decode": "decode_step_paged", "_prefill": "prefill_forward",
-    "_prefill_chunked": "prefill_chunked", "_window": "decode_window_paged",
+    "_window": "decode_window_paged",
     "_draft_decode": "draft_decode_step_paged",
     "_draft_prefill": "draft_prefill_forward",
     "_draft_window": "draft_decode_window_paged",
