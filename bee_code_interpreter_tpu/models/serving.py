@@ -710,12 +710,11 @@ class ContinuousBatcher:
             "decode_step_paged",
             donate_argnums=(3,),
         )
-        # which way that program attends (kv_telemetry): the predicate it is
-        # traced under, over the same pool, window of one token and mesh
-        self._decode_attention = (
-            "pages_in_place"
-            if reads_pages_in_place(self.cache, 1, config.sliding_window, mesh)
-            else "gathered"
+        # which way that program reads and writes the pool (kv_telemetry):
+        # the predicate it is traced under, over the same pool, window of
+        # one token and mesh
+        self._decode_in_place = reads_pages_in_place(
+            self.cache, 1, config.sliding_window, mesh
         )
         # what the mamba layers keep for a row, replaced whole at admission
         self._seed_state = None
@@ -1045,10 +1044,14 @@ class ContinuousBatcher:
         out["state_bytes_per_row"] = per_row
         out["state_rows_live"] = int(self.active.sum()) if per_row else 0
         out["state_bytes"] = per_row * self.max_batch
-        # how the plain decode step's attention reads the pool: a row's live
-        # pages where they lie (the Pallas kernel) or the table's width
-        # gathered (ops.paged_attention.reads_pages_in_place)
-        out["decode_attention"] = self._decode_attention
+        # how the plain decode step addresses the pool
+        # (ops.paged_attention.reads_pages_in_place): the Pallas kernel
+        # reads a row's live pages and writes the new token's page where
+        # they lie in the stacked leaf, or a layer's slice is cut, scattered
+        # into and the table's width gathered out of it
+        in_place = self._decode_in_place
+        out["decode_attention"] = "pages_in_place" if in_place else "gathered"
+        out["decode_append"] = "in_place" if in_place else "scattered"
         return out
 
     # ----------------------------------------------------- snapshot/resume

@@ -171,9 +171,12 @@ class TransformerConfig:
 
     @property
     def layer_period(self) -> int:
-        """The shortest period of ``layer_types`` (n_layers where it has
-        none): the layers one iteration of the scan unrolls."""
+        """The shortest period of ``layer_types`` (1 where it has none: a
+        pattern of one attention layer): the layers one iteration of the
+        scan unrolls."""
         types_ = self.layer_types
+        if types_ is None:
+            return 1
         n = len(types_)
         for p in range(1, n + 1):
             if n % p == 0 and all(types_[i] == types_[i % p] for i in range(n)):
@@ -666,27 +669,35 @@ def _head(params: Params, h, config: TransformerConfig):
 
 
 def _pattern_layer(layers: Params, config: TransformerConfig, period_index, j):
-    """Layer ``j`` of period ``period_index`` (traced) of a declared
-    pattern: its kind, its index among the layers of that kind, and its
-    leaves taken out of the stacks (the norms and MLP are stacked over all
-    layers, a kind's own leaves over the layers of that kind)."""
+    """Layer ``j`` of period ``period_index`` (traced) of the layer pattern
+    (without ``layer_types``: a period of one attention layer): its kind,
+    its index among the layers of that kind, and its leaves taken out of
+    the stacks (the norms and MLP are stacked over all layers, a kind's own
+    leaves over the layers of that kind)."""
     from bee_code_interpreter_tpu.models.mamba import MIXER_LEAVES
 
     c = config
-    period = c.layer_types[:c.layer_period]
+    period = (
+        ("attention",) if c.layer_types is None
+        else c.layer_types[:c.layer_period]
+    )
     kind = period[j]
     of_kind = period_index * period.count(kind) + period[:j].count(kind)
     own = MIXER_LEAVES if kind == "mamba" else ATTENTION_LEAVES
 
-    def take(name, index):
-        return lax.dynamic_index_in_dim(layers[name], index, 0, keepdims=False)
-
-    layer = {name: take(name, of_kind) for name in own}
+    layer = {name: _take_layer(layers[name], of_kind) for name in own}
     layer.update({
-        name: take(name, period_index * len(period) + j)
+        name: _take_layer(layers[name], period_index * len(period) + j)
         for name in layers if name not in MIXER_LEAVES + ATTENTION_LEAVES
     })
     return kind, of_kind, layer
+
+
+def _take_layer(stacked, index):
+    """One layer's leaves out of a tree stacked over layers."""
+    return jax.tree.map(
+        lambda x: lax.dynamic_index_in_dim(x, index, 0, keepdims=False), stacked
+    )
 
 
 def _n_periods(config: TransformerConfig) -> int:
@@ -1076,11 +1087,16 @@ def decode_window_paged(
     the contiguous strategy. Rows whose slots would exceed the table's
     page budget are a scheduler bug (the scatter clamps).
 
-    A window of ONE token attends through the Pallas kernel that reads each
-    row's live pages where they lie, wherever
-    ``ops.paged_attention.reads_pages_in_place`` says it can (``mesh`` is
-    read for that alone: the kernel runs in ``shard_map`` over the KV
-    heads); everything else gathers the table's width (``_attend_paged``).
+    A window of ONE token goes through the Pallas kernel that addresses the
+    pool where it lies, wherever ``ops.paged_attention.reads_pages_in_place``
+    says it can (``mesh`` is read for that alone: the kernel runs in
+    ``shard_map`` over the KV heads): the stacked leaf and the layer's index
+    go to the kernel, which puts the new token into its row's boundary page
+    and reads the row's live pages; no slice of the pool is cut. Everything
+    else cuts the layer's slice, scatters into it and gathers the table's
+    width (``paged_append``, ``_attend_paged``). Either way the pool is the
+    CARRY of the one layer scan (``_decode_layers``), so the donated input
+    is updated in place and never copied through the scan.
 
     ``lora_bank`` enables MULTI-LoRA serving (S-LoRA style): a stacked
     bank of adapters for the attention projections, with ``adapter_idx``
@@ -1092,6 +1108,7 @@ def decode_window_paged(
     ``lora_bank is None`` is a static (trace-time) branch: the base path
     is untouched. Pinned by tests/test_multilora_serving.py.
     """
+    from bee_code_interpreter_tpu.ops import paged_attention
     from bee_code_interpreter_tpu.ops.paged_kv_cache import paged_append
 
     c = config
@@ -1105,19 +1122,32 @@ def decode_window_paged(
                 f"lora_bank targets {sorted(unknown)} unsupported in the "
                 "decode path (attention projections only)"
             )
+    if c.layer_types is not None and (
+        lora_bank is not None or (W != 1 and c.n_mamba_layers)
+    ):
+        raise NotImplementedError(
+            "a layer pattern with mamba layers decodes one token a row "
+            "and takes no adapters: its state advances a token at a time"
+        )
     page_size = cache["k"].shape[3]
     positions = pos0[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]  # [B, W]
     page_idx = jnp.take_along_axis(
         block_table, positions // page_size, axis=1
     )  # [B, W]
     slot_idx = positions % page_size
+    kv_names = [name for name in cache if name not in ("ssm", "conv")]
+    in_place = paged_attention.reads_pages_in_place(
+        cache, W, c.sliding_window, mesh
+    )
 
     h = _embed(params, tokens, c)  # [B, W, D]
 
-    def attention_layer(h, layer, c_layer, lora_layer):
-        """``c_layer``: one layer's pool slices [n_pages, kvh, ps, dh]."""
+    def attention_layer(h, layer, cache, index):
+        """Attention layer ``index`` (traced) of the pool ``cache``, whose
+        K/V leaves are [attention layers, n_pages, kvh, ps, dh]."""
         x = rms_norm(h, layer["ln1"])
         dh, nh, kvh = c.head_dim, c.n_heads, c.kv_heads
+        lora_layer = {} if lora_bank is None else _take_layer(lora_bank, index)
 
         def lora_delta(x_in, name):
             if name not in lora_layer:
@@ -1138,63 +1168,48 @@ def decode_window_paged(
         q = _positioned(proj(layer["wq"], nh, "wq"), positions, c)
         k_new = _positioned(proj(layer["wk"], kvh, "wk"), positions, c)
         v_new = proj(layer["wv"], kvh, "wv")
-        c_layer = paged_append(
-            c_layer,
-            k_new.transpose(0, 2, 1, 3),  # [B, W, kvh, dh]
-            v_new.transpose(0, 2, 1, 3),
-            page_idx, slot_idx,
-        )
-        attn = _attend_paged(q, c_layer, block_table, positions, c, mesh)
+        if in_place:
+            # the new token into its page and the row's live pages read,
+            # both where they lie in the stacked leaf: nothing cut, nothing
+            # scattered, nothing gathered
+            attn, k, v = paged_attention.paged_decode_attention(
+                q[:, :, 0], cache["k"], cache["v"], block_table,
+                positions[:, 0] + 1, sm_scale=_score_scale(c), mesh=mesh,
+                layer=index, k_new=k_new[:, :, 0], v_new=v_new[:, :, 0],
+            )
+            attn = attn.reshape(B, 1, nh * dh).astype(c.dtype)
+            cache = {**cache, "k": k, "v": v}
+        else:
+            c_layer = paged_append(
+                _take_layer({n: cache[n] for n in kv_names}, index),
+                k_new.transpose(0, 2, 1, 3),  # [B, W, kvh, dh]
+                v_new.transpose(0, 2, 1, 3),
+                page_idx, slot_idx,
+            )
+            attn = _attend_paged(q, c_layer, block_table, positions, c)
+            cache = _put_layer(cache, c_layer, index)
         o = qeinsum("blk,kd->bld", attn, layer["wo"], c.dtype)
         delta_o = lora_delta(attn, "wo")
         if delta_o is not None:
             o = o + delta_o
         h, _ = _mlp_residual(_residual(h, o, c), layer, c)
-        return h, c_layer
+        return h, cache
 
-    if c.layer_types is not None:
-        if lora_bank is not None or (W != 1 and c.n_mamba_layers):
-            raise NotImplementedError(
-                "a layer pattern with mamba layers decodes one token a row "
-                "and takes no adapters: its state advances a token at a time"
-            )
-        h, cache = _decode_pattern(params, h, cache, c, attention_layer)
-        return _head(params, h, c), cache
-
-    def layer_step(h, scanned):
-        if lora_bank is None:
-            layer, c_layer = scanned
-            lora_layer = {}
-        else:
-            layer, c_layer, lora_layer = scanned
-        return attention_layer(h, layer, c_layer, lora_layer)
-
-    scanned = (
-        (params["layers"], cache) if lora_bank is None
-        else (params["layers"], cache, lora_bank)
-    )
-    h, cache = lax.scan(layer_step, h, scanned)
+    h, cache = _decode_layers(params, h, cache, c, attention_layer)
     return _head(params, h, c), cache
 
 
-def _attend_paged(
-    q, c_layer, block_table, positions, config: TransformerConfig,
-    mesh: Mesh | None = None,
-):
+def _attend_paged(q, c_layer, block_table, positions, config: TransformerConfig):
     """Attention of ``q`` [B, nh, W, dh] (at ``positions`` [B, W]) over one
-    layer's pages as each row's block table maps them: [B, W, nh * dh]."""
-    from bee_code_interpreter_tpu.ops import paged_attention
+    layer's pages, the width of each row's block table gathered
+    (``paged_read``) for the grouped einsums: [B, W, nh * dh]. What a plain
+    decode step on a TPU does in its place is ``paged_decode_attention``,
+    whose oracle this is."""
     from bee_code_interpreter_tpu.ops.paged_kv_cache import paged_read
 
     c = config
     B, nh, W, dh = q.shape
     kvh = c.kv_heads
-    if paged_attention.reads_pages_in_place(c_layer, W, c.sliding_window, mesh):
-        # the row's live pages, where they lie: nothing gathered
-        return paged_attention.paged_decode_attention(
-            q[:, :, 0, :], c_layer["k"], c_layer["v"], block_table,
-            positions[:, 0] + 1, sm_scale=_score_scale(c), mesh=mesh,
-        ).reshape(B, 1, nh * dh).astype(c.dtype)
     kf, vf = paged_read(c_layer, block_table, c.dtype)  # [B,kvh,S,dh]
     S = kf.shape[2]
 
@@ -1217,21 +1232,29 @@ def _attend_paged(
     return attn.transpose(0, 3, 1, 2, 4).reshape(B, W, nh * dh)
 
 
-def _decode_pattern(params, h, cache, config: TransformerConfig, attention_layer):
-    """The layers of a declared pattern for one decode step, scanned a
-    period at a time with the whole pool as the carry: each layer reads its
-    own slice of the pool (K/V pages of its attention layer, or the rows'
-    state of its mamba layer) and writes it back in place, once."""
+def _put_layer(stacked: dict, new: dict, index) -> dict:
+    """``stacked`` with the leaves of ``new`` written at ``index`` of their
+    leading axis, in place where ``stacked`` is a loop's carry."""
+    return {**stacked, **{
+        name: lax.dynamic_update_index_in_dim(
+            stacked[name], x.astype(stacked[name].dtype), index, 0
+        )
+        for name, x in new.items()
+    }}
+
+
+def _decode_layers(params, h, cache, config: TransformerConfig, attention_layer):
+    """THE layer scan of a decode step: a period of the layer pattern an
+    iteration (one attention layer where none is declared), the whole pool
+    the carry beside ``h``. Each layer takes its weights out of the stacks
+    at its index and updates its own part of the pool in place
+    (``attention_layer(h, layer, cache, index)``: the K/V pages of its
+    attention layer; here, the rows' state of its mamba layer), so the pool
+    is never a scan input or output and the donated buffer is the one the
+    loop works on."""
     from bee_code_interpreter_tpu.models.mamba import mixer_step
 
     c = config
-    kv_names = [name for name in cache if name not in ("ssm", "conv")]
-
-    def take(x, index):
-        return lax.dynamic_index_in_dim(x, index, 0, keepdims=False)
-
-    def put(x, new, index):
-        return lax.dynamic_update_index_in_dim(x, new.astype(x.dtype), index, 0)
 
     def period_step(carry, period_index):
         h, cache = carry
@@ -1242,17 +1265,13 @@ def _decode_pattern(params, h, cache, config: TransformerConfig, attention_layer
             if kind == "mamba":
                 mix, ssm, conv = mixer_step(
                     rms_norm(h, layer["ln1"]), layer, c,
-                    take(cache["ssm"], of_kind), take(cache["conv"], of_kind),
+                    _take_layer(cache["ssm"], of_kind),
+                    _take_layer(cache["conv"], of_kind),
                 )
                 h, _ = _mlp_residual(_residual(h, mix, c), layer, c)
-                new = {"ssm": ssm, "conv": conv}
+                cache = _put_layer(cache, {"ssm": ssm, "conv": conv}, of_kind)
             else:
-                h, new = attention_layer(
-                    h, layer, {n: take(cache[n], of_kind) for n in kv_names}, {}
-                )
-            cache = {**cache, **{
-                n: put(cache[n], x, of_kind) for n, x in new.items()
-            }}
+                h, cache = attention_layer(h, layer, cache, of_kind)
         return (h, cache), None
 
     (h, cache), _ = lax.scan(

@@ -1,27 +1,46 @@
-"""Pallas paged-attention decode kernel: a row's LIVE K/V pages read where
-they lie.
+"""Pallas paged-attention decode kernel: the pool addressed where it lies.
 
-The plain decode step (``models/transformer._attend_paged``, a window of
-one token) attends through this kernel wherever ``reads_pages_in_place``
-says it can; everything else keeps ``ops/paged_kv_cache.paged_read`` and
-the grouped einsums, which are also this kernel's oracle in the tests.
-The gather costs what the block TABLE is wide (every slot of every row,
-live or not, copied and transposed in HBM each step); the kernel costs
-what is LIVE.
+The plain decode step (``models/transformer.decode_window_paged``, a window
+of one token) writes the new token's K/V and attends through this kernel
+wherever ``reads_pages_in_place`` says it can; everything else keeps
+``ops/paged_kv_cache``'s ``paged_append`` and ``paged_read`` on a layer's
+slice and the grouped einsums, which are also this kernel's oracle in the
+tests. The gather costs what the block TABLE is wide (every slot of every
+row, live or not, copied and transposed in HBM each step); a scatter over a
+layer's slice, a slice handed to a kernel, a pool scanned as ``xs``/``ys``
+cost what the POOL is large (each copies it, 34 ms of a 50 ms step; PERF.md,
+PR 30); the kernel costs what is LIVE.
 
 Structure — the flash kernel's online softmax, for one query token a row:
 
-- grid ``(B,)``, one row a step. The pool leaf ``[n_pages, kvh, ps, dh]``
-  stays in HBM (``memory_space`` any) in the layout everything else reads;
-  a page with all its KV heads is one contiguous block there, and the
-  kernel copies ``PAGE_BLOCK_TOKENS // ps`` such pages a block into VMEM
-  with its own async copies, double-buffered: block i+1 is in flight
-  while block i is computed;
+- grid ``(B,)``, one row a step. The STACKED pool leaf ``[layers, n_pages,
+  kvh, ps, dh]`` stays in HBM (``memory_space`` any) in the layout
+  everything else reads, and the layer is a scalar-prefetched index beside
+  the lengths and the block table: no operand is a slice. A page with all
+  its KV heads is one contiguous block there, and the kernel copies
+  ``PAGE_BLOCK_TOKENS // ps`` such pages a block into VMEM with its own
+  async copies, double-buffered: block i+1 is in flight while block i is
+  computed;
 - the loop runs to ``ceil(length / ps)`` pages, from the scalar-prefetched
   lengths and block table: a table entry past the live count is never
   read (so a sentinel there is harmless), slots past the length in the
   boundary page are masked, and a row of length 0 copies nothing and
   gives zeros;
+- THE WRITE (``k_new`` / ``v_new``): the row's newest token, which the
+  length already counts, belongs in slot ``length - 1``, in the boundary
+  page the last block has just brought into VMEM. The kernel sets that
+  slot there before the block is computed and copies the ONE page (all KV
+  heads: whole tiles, 32 KB at the benchmark's shapes) back to where it
+  lies, through pool results that alias the pool operands
+  (``input_output_aliases``): bytes touched in proportion to the rows,
+  never to the pool. The copy back has landed before the row's grid step
+  ends, so a later row that names the same page (dead rows all name the
+  scratch page; rows run one after another, last writer wins) fetches what
+  was written, and the buffer it leaves from is free. Prefix-shared pages
+  are never a boundary page (the cursor starts past them); a row of length
+  0 writes nothing. Reads and writes both go through the RESULT's
+  reference, which on the chip is the operand's buffer and in the
+  interpreter starts as its copy;
 - per KV head, the ``rep = nh / kvh`` query heads that share it are the
   matmul's rows (padded to the sublane tile). Operands enter the MXU in
   the pool's dtype and accumulate in float32; scores, running max,
@@ -29,7 +48,8 @@ Structure — the flash kernel's online softmax, for one query token a row:
   to the pool's dtype for the PV product, as the einsum path rounds them;
 - a row's result depends on its own pages and length alone, block by
   block in logical order at a fixed block size: the same bits alone and
-  in any batch.
+  in any batch, and the same bits whether the token was written by the
+  kernel or by ``paged_append`` before it.
 
 bf16/f32 pools only: the int8 pool's scale planes stay on the einsum path.
 CPU tests run the kernel in Pallas interpreter mode
@@ -68,11 +88,13 @@ def on_tpu() -> bool:
 def reads_pages_in_place(
     c_layer: dict, window: int, sliding_window: int | None, mesh=None
 ) -> bool:
-    """THE predicate of the decode step's attention path, over what the
-    traced program can see: the kernel where the backend is a TPU, the
-    window is one token, the pool has no scale planes, nothing slides, the
-    head fills the lane tile and, under a mesh, the KV heads divide over
-    tp; ``paged_read`` and the einsums otherwise."""
+    """THE predicate of how the decode step addresses the pool (``c_layer``:
+    the pool, or one layer's slice of it), over what the traced program can
+    see: the kernel, writing and reading in place, where the backend is a
+    TPU, the window is one token, the pool has no scale planes, nothing
+    slides, the head fills the lane tile and, under a mesh, the KV heads
+    divide over tp; ``paged_append``, ``paged_read`` and the einsums on the
+    layer's slice otherwise."""
     kvh, _, dh = c_layer["k"].shape[-3:]
     tp = 1 if mesh is None else dict(mesh.shape).get("tp", 1)
     return (
@@ -88,16 +110,26 @@ def reads_pages_in_place(
 def _kernel(
     bt_ref,        # scalar prefetch: [B, P] block table (int32)
     len_ref,       # scalar prefetch: [B] visible lengths (int32)
+    layer_ref,     # scalar prefetch: [1] the layer of the stacked leaf
     q_ref,         # VMEM [1, kvh, rep_p, dh]
-    k_hbm,         # HBM [n_pages, kvh, ps, dh]: the pool leaf as it lies
-    v_hbm,
-    o_ref,         # VMEM [1, kvh, rep_p, dh]
-    k_buf,         # VMEM [2, ppb, kvh, ps, dh]: two blocks of pages
-    v_buf,
-    sems,          # DMA semaphores [2 (k, v), 2 (buffer)]
-    *, sm_scale: float,
+    *refs,         # see ``paged_decode_attention``: the form with a write
+                   # has the new token's K/V before the pool and the pool
+                   # again, aliased, among the outputs
+    sm_scale: float, writes: bool,
 ):
+    if writes:
+        (k_new_ref, v_new_ref,    # VMEM [1, kvh, 1, dh]: the row's new token
+         _, _,                    # the pool as an input: donated to k_hbm/v_hbm
+         o_ref, k_hbm, v_hbm,     # the pool as an output: read AND written
+         k_buf, v_buf, sems, write_sems) = refs
+    else:
+        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
+    # k_hbm / v_hbm: HBM [layers, n_pages, kvh, ps, dh], the leaf as it lies
+    # o_ref: VMEM [1, kvh, rep_p, dh]
+    # k_buf / v_buf: VMEM [2, ppb, kvh, ps, dh], two blocks of pages
+    # sems: DMA semaphores [2 (k, v), 2 (buffer)]; write_sems [2 (k, v)]
     b = pl.program_id(0)
+    layer = layer_ref[0]
     _, ppb, kvh, ps, dh = k_buf.shape
     rep_p = q_ref.shape[2]
     block_tokens = ppb * ps
@@ -107,8 +139,22 @@ def _kernel(
 
     def page_copies(page, buf, j):
         return (
-            pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, j], sems.at[0, buf]),
-            pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, j], sems.at[1, buf]),
+            pltpu.make_async_copy(
+                k_hbm.at[layer, page], k_buf.at[buf, j], sems.at[0, buf]
+            ),
+            pltpu.make_async_copy(
+                v_hbm.at[layer, page], v_buf.at[buf, j], sems.at[1, buf]
+            ),
+        )
+
+    def write_backs(page, buf, j):
+        return (
+            pltpu.make_async_copy(
+                k_buf.at[buf, j], k_hbm.at[layer, page], write_sems.at[0]
+            ),
+            pltpu.make_async_copy(
+                v_buf.at[buf, j], v_hbm.at[layer, page], write_sems.at[1]
+            ),
         )
 
     def live_in(block):  # pages of this block that hold a visible slot
@@ -138,6 +184,19 @@ def _kernel(
 
         lax.fori_loop(0, live_in(block), one, 0)
 
+    def append(buf):
+        """The new token into its slot of the row's boundary page, which
+        the last block has just brought into VMEM, and that ONE page on its
+        way back to where it lies: the block is then computed over what the
+        pool will hold."""
+        at = length - 1
+        j = at // ps % ppb
+        here = lax.broadcasted_iota(jnp.int32, (kvh, ps, dh), 1) == at % ps
+        k_buf[buf, j] = jnp.where(here, k_new_ref[0], k_buf[buf, j])
+        v_buf[buf, j] = jnp.where(here, v_new_ref[0], v_buf[buf, j])
+        for copy in write_backs(bt_ref[b, at // ps], buf, j):
+            copy.start()
+
     @pl.when(n_blocks > 0)
     def _first():
         fetch(0, 0)
@@ -150,6 +209,8 @@ def _kernel(
             fetch(i + 1, 1 - buf)
 
         wait(i, buf)
+        if writes:
+            pl.when(i + 1 == n_blocks)(functools.partial(append, buf))
         slot = i * block_tokens + lax.broadcasted_iota(
             jnp.int32, (rep_p, block_tokens), 1
         )
@@ -191,39 +252,73 @@ def _kernel(
     for g, (_, l, acc) in enumerate(lax.fori_loop(0, n_blocks, block_step, start)):
         o_ref[0, g] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
+    if writes:
+        # the page has landed before the next row may fetch it (dead rows
+        # all write the scratch page) or reuse the buffer it leaves from
+        @pl.when(n_blocks > 0)
+        def _landed():
+            for copy in write_backs(0, 0, 0):  # the page is not read
+                copy.wait()
+
 
 def paged_decode_attention(
     q: jax.Array,            # [B, nh, dh] — ONE query token per row
-    k_pages: jax.Array,      # [n_pages, kvh, ps, dh] — one layer's pool
-    v_pages: jax.Array,
+    k_pages: jax.Array,      # [layers, n_pages, kvh, ps, dh] — the stacked
+    v_pages: jax.Array,      # pool leaf ([n_pages, kvh, ps, dh]: of one layer)
     block_table: jax.Array,  # [B, P] int32 logical block -> physical page
     lengths: jax.Array,      # [B] int32 visible length per row (pos + 1)
     sm_scale: float | None = None,
     interpret: bool | None = None,
     mesh=None,
-) -> jax.Array:              # [B, nh, dh]
-    """Single-token paged attention over each row's live pages (module
-    docstring). GQA-native: ``nh % kvh == 0``; bf16/f32 pools.
+    layer=0,                 # int32 scalar, traced or not: the leaf's layer
+    k_new: jax.Array | None = None,  # [B, kvh, dh] — the token at slot
+    v_new: jax.Array | None = None,  # ``lengths - 1``, to be written first
+):
+    """Single-token paged attention over each row's live pages of ``layer``
+    (module docstring): [B, nh, dh]. GQA-native: ``nh % kvh == 0``; bf16/f32
+    pools.
+
+    With ``k_new`` / ``v_new`` the row's newest token, the one the lengths
+    already count, is put into the pool in place on the way, and the result
+    is ``(attention, k_pages, v_pages)``: the leaves the call was given,
+    which it must be allowed to overwrite (donated, or a loop's carry), with
+    B slots changed. A row of length 0 writes nothing.
 
     Under ``mesh`` each device runs the kernel over its own KV heads in
-    ``shard_map`` (GSPMD cannot partition a ``pallas_call``): axis 1 of the
-    pool leaf over tp, as ``ContinuousBatcher._pool_sharding`` lays it, and
-    q's heads the same way, being group-major. Heads are independent, so
-    there is no collective; every other axis sees replicas."""
+    ``shard_map`` (GSPMD cannot partition a ``pallas_call``): the kvh axis
+    of the pool leaf over tp, as ``ContinuousBatcher._pool_sharding`` lays
+    it, and the heads of q and of the new token the same way, q's being
+    group-major. Heads are independent, so there is no collective; every
+    other axis sees replicas."""
+    writes = k_new is not None
+    if k_pages.ndim == 4:  # one layer's slice is a stack of one layer
+        out = paged_decode_attention(
+            q, k_pages[None], v_pages[None], block_table, lengths, sm_scale,
+            interpret, mesh, 0, k_new, v_new,
+        )
+        return (out[0], out[1][0], out[2][0]) if writes else out
     if mesh is not None:
         tp = "tp" if "tp" in mesh.axis_names else None
-        heads, pool = P(None, tp, None), P(None, tp, None, None)
+        heads, pool = P(None, tp, None), P(None, None, tp, None, None)
+
+        def per_device(q, k_pages, v_pages, block_table, lengths, layer, *new):
+            return paged_decode_attention(
+                q, k_pages, v_pages, block_table, lengths, sm_scale,
+                interpret, None, layer, *new,
+            )
+
         return jax.shard_map(
-            functools.partial(
-                paged_decode_attention, sm_scale=sm_scale, interpret=interpret
-            ),
+            per_device,
             mesh=mesh,
-            in_specs=(heads, pool, pool, P(), P()),
-            out_specs=heads,
+            in_specs=(heads, pool, pool, P(), P(), P()) + (heads,) * 2 * writes,
+            out_specs=(heads, pool, pool) if writes else heads,
             check_vma=False,  # vma checking cannot lower a pallas_call yet
-        )(q, k_pages, v_pages, block_table, lengths)
+        )(
+            q, k_pages, v_pages, block_table, lengths,
+            jnp.asarray(layer, jnp.int32), *((k_new, v_new) if writes else ()),
+        )
     B, nh, dh = q.shape
-    n_pages, kvh, ps, _ = k_pages.shape
+    _, _, kvh, ps, _ = k_pages.shape
     if nh % kvh:
         raise ValueError(f"n_heads {nh} not a multiple of kv_heads {kvh}")
     rep = nh // kvh
@@ -241,25 +336,39 @@ def paged_decode_attention(
     if rep_p != rep:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rep_p - rep), (0, 0)))
 
-    q_spec = pl.BlockSpec((1, kvh, rep_p, dh), lambda b, bt, lens: (b, 0, 0, 0))
+    def by_row(*block):
+        return pl.BlockSpec((1,) + block, lambda b, *_: (b,) + (0,) * len(block))
+
+    q_spec, new_spec = by_row(kvh, rep_p, dh), by_row(kvh, 1, dh)
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
+    o_shape = jax.ShapeDtypeStruct((B, kvh, rep_p, dh), q.dtype)
+    new = tuple(
+        x.astype(k_pages.dtype).reshape(B, kvh, 1, dh) for x in (k_new, v_new)
+    ) if writes else ()
+    n_prefetch = 3  # block table, lengths, layer
     out = pl.pallas_call(
-        functools.partial(_kernel, sm_scale=float(sm_scale)),
+        functools.partial(_kernel, sm_scale=float(sm_scale), writes=writes),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=n_prefetch,
             grid=(B,),
-            in_specs=[
-                q_spec,
-                pl.BlockSpec(memory_space=pl.ANY),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=q_spec,
+            in_specs=[q_spec] + [new_spec] * len(new) + [in_place] * 2,
+            out_specs=(q_spec, in_place, in_place) if writes else q_spec,
             scratch_shapes=[
                 pltpu.VMEM((2, ppb, kvh, ps, dh), k_pages.dtype),
                 pltpu.VMEM((2, ppb, kvh, ps, dh), v_pages.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
-            ],
+            ] + [pltpu.SemaphoreType.DMA((2,))] * writes,
         ),
-        out_shape=jax.ShapeDtypeStruct((B, kvh, rep_p, dh), q.dtype),
+        out_shape=(
+            o_shape,
+            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
+            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
+        ) if writes else o_shape,
+        # the pool operands ARE the pool results (operands count from the
+        # scalar-prefetched three)
+        input_output_aliases=(
+            {n_prefetch + 3: 1, n_prefetch + 4: 2} if writes else {}
+        ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ) if not interpret else None,
@@ -267,6 +376,8 @@ def paged_decode_attention(
         name="paged_decode_attention",  # the trace's ``XLA Ops`` line shows it
     )(
         block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-        qg, k_pages, v_pages,
+        jnp.asarray(layer, jnp.int32).reshape(1), qg, *new, k_pages, v_pages,
     )
-    return out[:, :, :rep].reshape(B, nh, dh)
+    if not writes:
+        return out[:, :, :rep].reshape(B, nh, dh)
+    return out[0][:, :, :rep].reshape(B, nh, dh), out[1], out[2]

@@ -15,22 +15,27 @@ TPU-first constraints shape the layout:
 - **Static shapes everywhere.** The pool, the block table and every
   program over them are fixed-size; "allocation" is host-side integer
   bookkeeping between steps, never a traced shape change.
-- **The served decode step does not gather.** A window of one token
-  attends through ``ops/paged_attention.py``, a Pallas kernel that copies
-  each row's LIVE pages from the pool leaf where they lie, wherever
+- **The served decode step moves nothing of the pool's size.** The pool
+  is the carry of the decode program's one layer scan, and a window of one
+  token goes through ``ops/paged_attention.py``, a Pallas kernel that takes
+  the STACKED leaf and a layer index, writes the new token's page in place
+  and copies each row's LIVE pages from where they lie, wherever
   ``paged_attention.reads_pages_in_place`` holds (a TPU, no scale planes,
-  no sliding window, a head that fills the lane tile). ``paged_read``
-  below is the other path: speculative windows, int8 pools, sliding
-  windows and the CPU of the tests gather the whole block-table width
-  into the [B, kvh, S, dh] view the contiguous attention einsums consume
-  (one advanced-indexing gather), so paged-vs-contiguous equality is a
-  pure indexing property, pinned by tests/test_paged_kv_cache.py, and the
-  einsum path is the kernel's oracle (tests/test_paged_decode_kernel.py).
-  ``paged_append`` scatters the new tokens either way.
+  no sliding window, a head that fills the lane tile): nothing is cut out
+  of the pool, scattered into it or gathered from it. ``paged_append`` and
+  ``paged_read`` below are the other path, on one layer's slice inside the
+  same scan: speculative windows, int8 pools, sliding windows, a head of
+  64 and the CPU of the tests scatter the new tokens (an XLA scatter,
+  which copies the slice and picks its layout) and gather the whole
+  block-table width into the [B, kvh, S, dh] view the contiguous attention
+  einsums consume (one advanced-indexing gather), so paged-vs-contiguous
+  equality is a pure indexing property, pinned by
+  tests/test_paged_kv_cache.py, and this path is the kernel's oracle, for
+  what it reads and for what it writes (tests/test_paged_decode_kernel.py).
 - **A page with all its KV heads is one contiguous block.** Pages are
   [kvh, page_size, dh] slabs of the leaf [n_pages, kvh, page_size, dh]:
-  the kernel copies whole pages, ``seed_prefill`` scatters whole pages,
-  the mesh shards the kvh axis. dh is contiguous and page_size defaults to
+  the kernel copies whole pages in and out, ``seed_prefill`` scatters whole
+  pages, the mesh shards the kvh axis. dh is contiguous and page_size defaults to
   a multiple of 8 so slabs keep the (8, 128) tiling XLA wants.
 
 The reference has no serving stack at all (SURVEY §2); this module is part

@@ -446,7 +446,10 @@ def phase_kernels(
     from bee_code_interpreter_tpu.ops.paged_attention import (
         paged_decode_attention,
     )
-    from bee_code_interpreter_tpu.ops.paged_kv_cache import paged_read
+    from bee_code_interpreter_tpu.ops.paged_kv_cache import (
+        paged_append,
+        paged_read,
+    )
     from bee_code_interpreter_tpu.parallel.ring_attention import (
         reference_attention,
     )
@@ -511,13 +514,16 @@ def phase_kernels(
             "compile_s": round(seconds, 1),
         }
 
-    # ---- paged decode attention: a row's live pages read where they lie,
-    # at the benchmark's table width (288 pages of 16 slots) with all KV
-    # heads and with the two a tp=4 shard holds; ragged lengths: a single
-    # token, a partial boundary page, an exact page multiple, nearly the
-    # whole table
+    # ---- paged decode attention as the decode program calls it: the
+    # STACKED pool leaf and a layer index, the new token written into its
+    # page in place (pool results aliased to the donated pool operands) and
+    # the row's live pages read where they lie; at the benchmark's table
+    # width (288 pages of 16 slots) with all KV heads and with the two a
+    # tp=4 shard holds; ragged lengths: a single token, a partial boundary
+    # page, an exact page multiple, nearly the whole table. Against
+    # paged_append, paged_read and the einsums on the layer's slice.
     rep = nh // kvh
-    rows = 4
+    rows, layers, layer = 4, 2, 1
     n_pool = rows * pages_per_seq + 8
     table = jnp.asarray(
         np.random.default_rng(SEED).permutation(n_pool - 1)[
@@ -530,46 +536,83 @@ def phase_kernels(
         [1, 2 * page_size + 5, 4 * page_size, span - 6], dtype=jnp.int32
     )
 
-    def paged_kernel(qd, k_pages, v_pages, table, lengths):
+    def paged_kernel(qd, k_pool, v_pool, table, lengths, layer, k_new, v_new):
         return paged_decode_attention(
-            qd, k_pages, v_pages, table, lengths, None, interpret
+            qd, k_pool, v_pool, table, lengths, None, interpret,
+            layer=layer, k_new=k_new, v_new=v_new,
         )
 
-    def paged_einsum(qd, k_pages, v_pages, table, lengths):
-        heads = k_pages.shape[1]
-        kf, vf = paged_read({"k": k_pages, "v": v_pages}, table, dtype)
+    def paged_einsum(qd, k_pool, v_pool, table, lengths, layer, k_new, v_new):
+        heads = k_pool.shape[2]
+        at = lengths - 1
+        c_layer = paged_append(
+            {"k": k_pool[layer], "v": v_pool[layer]},
+            k_new[:, None], v_new[:, None],
+            jnp.take_along_axis(table, (at // page_size)[:, None], axis=1),
+            (at % page_size)[:, None],
+        )
+        kf, vf = paged_read(c_layer, table, dtype)
         qg = qd.reshape(rows, heads, rep, dh).astype(jnp.float32)
         scores = jnp.einsum("bgrd,bgsd->bgrs", qg, kf) / math.sqrt(dh)
         visible = jnp.arange(span)[None, :] < lengths[:, None]
         scores = jnp.where(visible[:, None, None, :], scores, -jnp.inf)
         weights = jax.nn.softmax(scores, axis=-1).astype(dtype)
         out = jnp.einsum("bgrs,bgsd->bgrd", weights, vf)
-        return out.reshape(rows, heads * rep, dh)
+        return (
+            out.reshape(rows, heads * rep, dh),
+            k_pool.at[layer].set(c_layer["k"]),
+            v_pool.at[layer].set(c_layer["v"]),
+        )
 
     for name, heads in (
         ("paged_decode", kvh), ("paged_decode_tp4_shard", max(1, kvh // 4)),
     ):
-        k_pages = jax.random.normal(keys[4], (n_pool, heads, page_size, dh), dtype)
-        v_pages = jax.random.normal(keys[5], (n_pool, heads, page_size, dh), dtype)
-        qd = jax.random.normal(keys[6], (rows, heads * rep, dh), dtype)
-        args = (qd, k_pages, v_pages, table, lengths)
-        compiled, seconds = _timed_compile(paged_kernel, *args)
+        pool_shape = (layers, n_pool, heads, page_size, dh)
+        args = (
+            jax.random.normal(keys[6], (rows, heads * rep, dh), dtype),
+            jax.random.normal(keys[4], pool_shape, dtype),
+            jax.random.normal(keys[5], pool_shape, dtype),
+            table, lengths, jnp.int32(layer),
+            jax.random.normal(keys[7], (rows, heads, dh), dtype),
+            jax.random.normal(keys[3], (rows, heads, dh), dtype),
+        )
+        want = jax.block_until_ready(jax.jit(paged_einsum)(*args))
+        t_compile = time.monotonic()
+        compiled = jax.jit(paged_kernel, donate_argnums=(1, 2)).lower(
+            *args
+        ).compile()
+        seconds = time.monotonic() - t_compile
         compile_s += seconds
+        aliased = compiled.memory_analysis().alias_size_in_bytes
         t_run = time.monotonic()
         got = jax.block_until_ready(compiled(*args))
         run_s += time.monotonic() - t_run
-        paged_err = err(got, jax.jit(paged_einsum)(*args))
+        paged_err = err(got[0], want[0])
         check(
             math.isfinite(paged_err) and paged_err <= tol,
             f"{name}: paged_decode_attention disagrees with the paged_read "
             f"einsum path: rel err {paged_err} > {tol}",
         )
+        check(
+            all(bool(jnp.array_equal(g, w)) for g, w in zip(got[1:], want[1:])),
+            f"{name}: the pool the kernel wrote in place is not the pool "
+            "paged_append writes",
+        )
+        check(
+            interpret
+            or aliased >= 2 * math.prod(pool_shape) * jnp.dtype(dtype).itemsize,
+            f"{name}: the pool results do not alias the donated pool "
+            f"operands ({aliased} bytes aliased)",
+        )
         results[name] = {
             "shape": {"rows": rows, "nh": heads * rep, "kvh": heads, "rep": rep,
                       "dh": dh, "page_size": page_size,
-                      "pages_per_seq": pages_per_seq},
+                      "pages_per_seq": pages_per_seq, "layers": layers,
+                      "layer": layer},
             "lengths": [int(x) for x in lengths],
             "rel_err": round(paged_err, 5),
+            "pool_bitwise_equal": True,
+            "aliased_bytes": int(aliased),
             "tolerance": tol,
             "compile_s": round(seconds, 1),
         }

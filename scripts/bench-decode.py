@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """KV-cached decode throughput on the real TPU chip.
 
-Two measurements:
+Three measurements:
 
 1. ``decode``: tokens/sec of the full incremental decode loop
    (models/transformer.decode_step — one lax.scan-compiled program updating
@@ -17,6 +17,13 @@ Timing: the decode loop is naturally self-chaining (each step consumes the
 previous cache/token), so one jit + one scalar readback measures N real
 steps — the same chained clock as scripts/bench-flash-attention.py
 (utils/benchclock.chain_diff).
+
+3. ``paged_decode_kernel_alone``: the paged decode kernel at the
+   benchmark's shapes, in the form the decode program runs (the stacked
+   pool leaf at a layer index, the new token written in place), without
+   the write, and the way it ran before (a slice cut, scattered into and
+   put back). ``python scripts/bench-decode.py kernel`` runs this alone.
+   Not a measurement of any cell.
 
 One process, the one that holds the chip: no out-of-process probe.
 Usage:  python scripts/bench-decode.py   (needs a TPU; exits 2 if none)
@@ -392,10 +399,119 @@ def run_measurements(emit) -> None:
     })
 
 
+def kernel_alone(
+    emit, layers=16, n_pages=2560, kvh=8, ps=16, dh=128, B=32, P=288, nh=32,
+    N=64,
+) -> None:
+    """``paged_decode_attention`` alone, at ``mistral7b_chat``'s shapes: the
+    stacked pool leaf of 16 layers x 2,560 pages (2.68 GB, K and V), 32
+    rows of 128-960 live tokens under a table of 288 pages. One layer's
+    call, chained with the pool as the loop's carry (donated), three ways:
+    the form the decode program runs (the leaf at a layer index, the new
+    token written in place), the same without the write, and what the
+    program did before (the layer's slice cut out of the carry,
+    ``paged_append``'s scatter, the kernel on the slice, the slice put
+    back). Not a measurement of any cell: nothing else of the step runs."""
+    import numpy as np
+
+    from bee_code_interpreter_tpu.ops.paged_attention import (
+        paged_decode_attention,
+    )
+    from bee_code_interpreter_tpu.ops.paged_kv_cache import paged_append
+    from bee_code_interpreter_tpu.utils.benchclock import chain_diff
+
+    dtype = jnp.bfloat16
+    rng = np.random.default_rng(0)
+    lengths = jnp.asarray(rng.integers(P * ps // 36, P * ps // 5 + 1, B), jnp.int32)
+    table = np.zeros((B, P), np.int32)
+    pages = iter(rng.permutation(n_pages - 1) + 1)
+    for b in range(B):
+        for j in range(-(-int(lengths[b]) // ps)):
+            table[b, j] = next(pages)
+    table = jnp.asarray(table)
+    at = lengths - 1
+    page_idx = jnp.take_along_axis(table, (at // ps)[:, None], axis=1)
+    slot_idx = (at % ps)[:, None]
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    q0 = jax.random.normal(keys[0], (B, nh, dh), dtype)
+    new = jax.random.normal(keys[1], (2, B, kvh, dh), dtype)
+
+    def in_place(q, k, v, layer):
+        return paged_decode_attention(
+            q, k, v, table, lengths, layer=layer, k_new=new[0], v_new=new[1]
+        )
+
+    def read_only(q, k, v, layer):
+        return paged_decode_attention(q, k, v, table, lengths, layer=layer), k, v
+
+    def slice_cut_and_put_back(q, k, v, layer):
+        c_layer = paged_append(
+            {"k": lax.dynamic_index_in_dim(k, layer, 0, keepdims=False),
+             "v": lax.dynamic_index_in_dim(v, layer, 0, keepdims=False)},
+            new[0][:, None], new[1][:, None], page_idx, slot_idx,
+        )
+        out = paged_decode_attention(q, c_layer["k"], c_layer["v"], table, lengths)
+        return (
+            out,
+            lax.dynamic_update_index_in_dim(k, c_layer["k"], layer, 0),
+            lax.dynamic_update_index_in_dim(v, c_layer["v"], layer, 0),
+        )
+
+    def chain(call, n):
+        def f(q, k, v):
+            def body(i, carry):
+                out, k, v = call(*carry, i % layers)
+                return out.astype(dtype), k, v
+
+            q, k, v = lax.fori_loop(0, n, body, (q, k, v))
+            return q.astype(jnp.float32).sum(), k, v
+
+        return jax.jit(f, donate_argnums=(1, 2))
+
+    def best_of(f, reps=3):
+        pool = [
+            jax.random.normal(keys[2], (layers, n_pages, kvh, ps, dh), dtype)
+            for _ in range(2)
+        ]
+        best = float("inf")
+        for i in range(reps + 1):  # the first compiles and warms
+            t0 = time.perf_counter()
+            total, *pool = f(q0, *pool)
+            float(total)
+            if i:
+                best = min(best, time.perf_counter() - t0)
+        return best
+
+    per_call = {
+        name: chain_diff(best_of(chain(call, N)), best_of(chain(call, 1)), N)
+        for name, call in (
+            ("in_place", in_place), ("read_only", read_only),
+            ("slice_cut_and_put_back", slice_cut_and_put_back),
+        )
+    }
+    live_bytes = 2 * int(lengths.sum()) * kvh * dh * 2  # K and V, bf16
+    emit("paged_decode_kernel_alone", {
+        "shape": {"layers": layers, "n_pages": n_pages, "rows": B,
+                  "heads": f"{nh}/{kvh}", "head_dim": dh, "page_size": ps,
+                  "pages_per_seq": P, "live_tokens": int(lengths.sum())},
+        **{f"{name}_us": round(t * 1e6, 1) for name, t in per_call.items()},
+        "write_us": round((per_call["in_place"] - per_call["read_only"]) * 1e6, 1),
+        "in_place_live_gbps": round(live_bytes / per_call["in_place"] / 1e9, 1),
+        "note": "one layer's call alone, chained: not a measurement of a cell",
+    })
+
+
 def main() -> None:
+    """``python scripts/bench-decode.py [kernel]``: everything, or the paged
+    decode kernel alone."""
     from bee_code_interpreter_tpu.parallel.mesh import require_tpu
 
-    run_measurements(require_tpu("scripts/bench-decode.py"))
+    emit = require_tpu("scripts/bench-decode.py")
+    if sys.argv[1:] == ["kernel"]:
+        kernel_alone(emit)
+        return
+    run_measurements(emit)
+    kernel_alone(emit)
 
 
 if __name__ == "__main__":

@@ -116,6 +116,12 @@ def test_phase_kernels_tiny_cpu():
     }
     assert out["kernels"]["paged_decode"]["lengths"] == [1, 21, 32, 58]
     assert out["kernels"]["paged_decode_tp4_shard"]["shape"]["kvh"] == 1
+    # the form the decode program calls: the stacked leaf at a layer index,
+    # the new token written in place, bit for bit paged_append's pool
+    for name in ("paged_decode", "paged_decode_tp4_shard"):
+        assert out["kernels"][name]["shape"]["layers"] == 2
+        assert out["kernels"][name]["shape"]["layer"] == 1
+        assert out["kernels"][name]["pool_bitwise_equal"] is True
 
 
 def test_phase_serving_tiny_cpu(tmp_path):

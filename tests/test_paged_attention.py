@@ -130,6 +130,9 @@ def test_batcher_kernel_path_matches_einsum_path(monkeypatch):
         assert b.kv_telemetry()["decode_attention"] == (
             "pages_in_place" if engaged else "gathered"
         )
+        assert b.kv_telemetry()["decode_append"] == (
+            "in_place" if engaged else "scattered"
+        )
         reqs = [b.submit(p, 6) for p in prompts]
         b.run_to_completion()
         return [b.result(r) for r in reqs]
@@ -165,6 +168,7 @@ def test_int8_pool_and_windows_keep_the_einsum_path(monkeypatch):
             b = ContinuousBatcher(params, cfg, max_batch=1, n_pages=16,
                                   page_size=4, max_pages_per_seq=8)
             assert b.kv_telemetry()["decode_attention"] == "gathered"
+            assert b.kv_telemetry()["decode_append"] == "scattered"
             r = b.submit([5, 3, 7, 2], 4)
             b.run_to_completion()
             return b.result(r)
