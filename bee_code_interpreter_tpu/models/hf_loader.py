@@ -25,10 +25,10 @@ tests/test_hf_loader.py — the strongest correctness statement the
 transformer family has, and the reason this module lives next to the
 model code rather than in an example.
 
-Scope honestly stated: rms_norm eps is fixed at 1e-5 in our kernel-shared
-``rms_norm`` (Llama-2/3 checkpoints use 1e-5); checkpoints with a
-different eps are refused rather than silently mis-normed. Attention
-biases and non-default rope scaling configs are refused the same way.
+Scope honestly stated: attention biases and rope scaling configs other
+than linear interpolation (and ``deepseek_yarn`` for ``sarvam_mla``) are
+refused rather than silently mis-loaded. ``rms_norm_eps`` is a field of
+``TransformerConfig`` and is taken as published.
 """
 
 from __future__ import annotations
@@ -55,11 +55,6 @@ def config_from_hf(hf_config, dtype=jnp.bfloat16) -> TransformerConfig:
 
         hf_config = types.SimpleNamespace(**hf_config)
     eps = getattr(hf_config, "rms_norm_eps", 1e-5)
-    if abs(eps - 1e-5) > 1e-12:
-        raise ValueError(
-            f"rms_norm_eps {eps} unsupported (our rms_norm fixes 1e-5, "
-            "the Llama-2/3 value); refusing a silently mis-normed load"
-        )
     if getattr(hf_config, "attention_bias", False):
         raise ValueError("attention_bias checkpoints are not supported")
     if getattr(hf_config, "mlp_bias", False):
@@ -71,7 +66,9 @@ def config_from_hf(hf_config, dtype=jnp.bfloat16) -> TransformerConfig:
             "refusing a silently wrong load"
         )
     if getattr(hf_config, "model_type", None) == "granitemoehybrid":
-        return _granite_hybrid_config(hf_config, dtype)
+        return _granite_hybrid_config(hf_config, dtype, eps)
+    if getattr(hf_config, "model_type", None) == "sarvam_mla":
+        return _sarvam_mla_config(hf_config, dtype, eps)
     scaling = getattr(hf_config, "rope_scaling", None)
     rope_scaling = 1.0
     if scaling is not None:
@@ -105,10 +102,65 @@ def config_from_hf(hf_config, dtype=jnp.bfloat16) -> TransformerConfig:
         rope_theta=float(getattr(hf_config, "rope_theta", 10000.0)),
         rope_scaling=rope_scaling,
         dtype=dtype,
+        rms_norm_eps=eps,
     )
 
 
-def _granite_hybrid_config(hf_config, dtype) -> TransformerConfig:
+def _sarvam_mla_config(hf_config, dtype, eps) -> TransformerConfig:
+    """``sarvam_mla``: latent attention without a query latent, leading
+    dense layers, then routed experts (every one of them held: a chip's
+    share is the configuration's to cut) beside shared ones, under the
+    DeepSeek-V3 lineage's router. What is not computed is refused by
+    name."""
+    if getattr(hf_config, "q_lora_rank", None):
+        raise ValueError(
+            f"q_lora_rank {hf_config.q_lora_rank} unsupported: the query is "
+            "projected whole (no query latent)"
+        )
+    if getattr(hf_config, "n_group", 1) not in (None, 1):
+        raise ValueError(
+            f"n_group {hf_config.n_group} unsupported: the router has one "
+            "routing group"
+        )
+    scaling = getattr(hf_config, "rope_scaling", None)
+    if scaling is not None:
+        kind = scaling.get("rope_type", scaling.get("type"))
+        if kind != "deepseek_yarn":
+            raise ValueError(
+                f"rope_scaling type {kind!r} unsupported for sarvam_mla "
+                "(deepseek_yarn, or none)"
+            )
+    return TransformerConfig(
+        vocab_size=hf_config.vocab_size,
+        d_model=hf_config.hidden_size,
+        n_layers=hf_config.num_hidden_layers,
+        n_heads=hf_config.num_attention_heads,
+        d_ff=hf_config.intermediate_size,
+        max_seq_len=hf_config.max_position_embeddings,
+        rope_theta=getattr(hf_config, "rope_theta", 10000.0),
+        dtype=dtype,
+        rms_norm_eps=eps,
+        kv_lora_rank=hf_config.kv_lora_rank,
+        qk_nope_head_dim=hf_config.qk_nope_head_dim,
+        qk_rope_head_dim=hf_config.qk_rope_head_dim,
+        v_head_dim=hf_config.v_head_dim,
+        qk_norm=bool(getattr(hf_config, "use_qk_norm", False)),
+        rope_yarn=scaling,
+        n_dense_layers=getattr(hf_config, "first_k_dense_replace", 0),
+        n_experts=hf_config.num_experts,
+        moe_top_k=hf_config.num_experts_per_tok,
+        moe_scoring="sigmoid",
+        moe_held_experts=hf_config.num_experts,
+        moe_d_ff=hf_config.moe_intermediate_size,
+        moe_shared_experts=getattr(hf_config, "num_shared_experts", 0),
+        moe_routed_scaling=getattr(hf_config, "routed_scaling_factor", 1.0),
+        moe_router_bias=bool(
+            getattr(hf_config, "moe_router_enable_expert_bias", False)
+        ),
+    )
+
+
+def _granite_hybrid_config(hf_config, dtype, eps=1e-5) -> TransformerConfig:
     """``granitemoehybrid`` without routed experts: every published key the
     program has a field for, and a refusal by name for what it computes
     otherwise."""
@@ -153,6 +205,7 @@ def _granite_hybrid_config(hf_config, dtype) -> TransformerConfig:
         logits_scaling=hf_config.logits_scaling,
         position_embedding=hf_config.position_embedding_type,
         tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
+        rms_norm_eps=eps,
     )
 
 

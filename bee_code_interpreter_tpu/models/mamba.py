@@ -158,7 +158,7 @@ def _finish(y, x_, z, layer, config):
     y = y + _per_head(layer["D"], c)[..., None] * x_.astype(jnp.float32)
     y = y.reshape(*z.shape) * jax.nn.silu(z.astype(jnp.float32))
     return qeinsum(
-        "blk,kd->bld", rms_norm(y.astype(c.dtype), layer["ln_gate"]),
+        "blk,kd->bld", rms_norm(y.astype(c.dtype), layer["ln_gate"], c.rms_norm_eps),
         layer["out_proj"], c.dtype,
     )
 

@@ -19,6 +19,15 @@ NOT a ragged/sort-based CUDA-style implementation):
   mean-prob_e) keeps routing from collapsing; the transformer adds it to the
   training loss scaled by ``moe_aux_weight``.
 
+Beside it, the SORTED dispatch (``held_experts_mlp``; the DeepSeek-V3
+lineage's router): an expert layer that is told which experts it holds,
+routes over all of them, sorts the (token, expert) pairs routed to its own
+experts by expert and runs them through one grouped matmul
+(``jax.lax.ragged_dot``, on a TPU a Mosaic kernel over the rows the groups
+cover). Nothing is dropped, a pair's row is computed from its token alone,
+and what experts held elsewhere would add is left out: the chip's share of
+an expert-parallel layer, without the exchange.
+
 The reference (a code-execution service) has no MoE; this module exists for
 the framework's model-family/parallelism completeness: the full dp × ep × tp
 training step is exercised on virtual devices by tests/test_moe.py and the
@@ -180,3 +189,175 @@ def moe_mlp(
     )  # [n, g, D]
 
     return out.reshape(B, L, D), aux.mean()
+
+
+# ------------------------------------------------- the sorted (held) dispatch
+
+
+def init_held_params(key: jax.Array, config) -> Params:
+    """An expert layer that holds ``config.held_experts`` of the
+    ``config.n_experts`` its router scores: the router [d, E], or with a
+    per-expert selection bias [d + 1, E], the bias its LAST ROW (zeros
+    until trained; one leaf, so that a fill by fan-in gives the bias the
+    magnitude of a router weight and not that of a score), the held
+    experts' SwiGLU weights [H, ...] and the shared experts' as one SwiGLU
+    of their summed width (``ws_*``)."""
+    c = config
+    d, f = c.d_model, c.expert_ff_dim
+    k_router, k_gate, k_up, k_down, k_sg, k_su, k_sd = jax.random.split(key, 7)
+
+    def dense(key, fan_in, *shape):
+        return jax.random.normal(key, shape, dtype=jnp.float32) / math.sqrt(fan_in)
+
+    held = c.held_experts
+    router = dense(k_router, d, d, c.n_experts)
+    if c.moe_router_bias:
+        router = jnp.concatenate([router, jnp.zeros((1, c.n_experts))])
+    out = {
+        "router": router,
+        "we_gate": dense(k_gate, d, held, d, f),
+        "we_up": dense(k_up, d, held, d, f),
+        "we_down": dense(k_down, f, held, f, d),
+    }
+    if c.moe_shared_experts:
+        fs = c.moe_shared_experts * f
+        out.update({
+            "ws_gate": dense(k_sg, d, d, fs), "ws_up": dense(k_su, d, d, fs),
+            "ws_down": dense(k_sd, fs, fs, d),
+        })
+    return out
+
+
+EXPERT_STACKS = ("we_gate", "we_up", "we_down")
+
+
+def take_held_layer(stacked: Params, index) -> Params:
+    """Layer ``index`` (traced) of an expert layer's leaves stacked over
+    layers: the small leaves cut out, the experts' stacks WHOLE beside the
+    index (``layer``). A slice of a stack handed to a kernel is a copy of
+    the slice, 1.6 GB a layer at 32 experts of 4096 x 2048; the grouped
+    matmul takes layers x experts as its groups and reads in place."""
+    out = {
+        name: lax.dynamic_index_in_dim(x, index, 0, keepdims=False)
+        for name, x in stacked.items() if name not in EXPERT_STACKS
+    }
+    out.update({name: stacked[name] for name in EXPERT_STACKS})
+    out["layer"] = index
+    return out
+
+
+def route_sigmoid(x: jax.Array, moe: Params, config):
+    """The router's choice for tokens ``x`` [T, D], in float32: sigmoid
+    scores over all experts, the ``moe_top_k`` largest of score + bias
+    chosen, weighted by their scores (no bias) over the sum of the kept
+    scores times ``moe_routed_scaling``. Gives (expert ids [T, k] int32,
+    weights [T, k] float32)."""
+    c = config
+    router = moe["router"].astype(jnp.float32)
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "td,de->te", x.astype(jnp.float32), router[:x.shape[1]],
+        precision=lax.Precision.HIGHEST,
+    ))
+    biased = scores + router[-1] if c.moe_router_bias else scores
+    _, chosen = lax.top_k(biased, c.moe_top_k)
+    kept = jnp.take_along_axis(scores, chosen, axis=1)
+    weights = kept / kept.sum(axis=-1, keepdims=True) * c.moe_routed_scaling
+    return chosen.astype(jnp.int32), weights
+
+
+def sorted_rows(n_tokens: int, config) -> int:
+    """Rows of the sorted buffer the common case runs at: the pairs
+    ``n_tokens`` route to the held experts in expectation, and a quarter
+    more, and 32 (a decode batch's count spreads by a tenth of its mean),
+    rounded up to 8. More pairs than that take the buffer of every pair."""
+    c = config
+    mean = n_tokens * c.moe_top_k * c.held_experts / c.n_experts
+    rows = -(-int(math.ceil(1.25 * mean) + 32) // 8) * 8
+    return min(rows, n_tokens * c.moe_top_k)
+
+
+def sort_pairs(chosen: jax.Array, config):
+    """The (token, expert) pairs ``chosen`` [T, k] in the order the grouped
+    matmul wants them: pairs of held experts first, by expert, in token
+    order within one (a stable sort); every other pair after. Gives
+    (``order`` [T*k]: the flat pair at each sorted position, ``sizes`` [H]:
+    pairs of each held expert)."""
+    c = config
+    held = c.held_experts
+    local = chosen.reshape(-1) - c.moe_held_from
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    return order, sizes
+
+
+def grouped_experts(x, order, sizes, weights, moe: Params, config, rows: int):
+    """The held experts' weighted sum for every token of ``x`` [T, D], in
+    float32, over a sorted buffer of ``rows`` rows (which must hold every
+    pair ``sizes`` counts): the rows' tokens gathered, three grouped
+    matmuls whose groups are layers x held experts (all but this layer's
+    empty), and each token's pairs gathered back and summed in the order of
+    its top-k slots."""
+    c = config
+    T, k, held = x.shape[0], c.moe_top_k, c.held_experts
+    stacks = [moe[name] for name in EXPERT_STACKS]
+    if stacks[0].ndim == 3:  # one layer's experts: a stack of one layer
+        stacks, layer = [w[None] for w in stacks], 0
+    else:
+        layer = moe["layer"]
+    n_layers = stacks[0].shape[0]
+    groups = lax.dynamic_update_slice_in_dim(
+        jnp.zeros((n_layers * held,), jnp.int32), sizes, layer * held, 0
+    )
+    gate_w, up_w, down_w = (
+        w.astype(c.dtype).reshape(n_layers * held, *w.shape[2:]) for w in stacks
+    )
+    xs = x.astype(c.dtype)[order[:rows] // k]  # [rows, D]
+    with jax.named_scope("moe.experts"):
+        gate = lax.ragged_dot(xs, gate_w, groups)
+        up = lax.ragged_dot(xs, up_w, groups)
+        out = lax.ragged_dot(jax.nn.silu(gate) * up, down_w, groups)
+    # where each pair sits in the buffer (beyond the pairs counted: nowhere)
+    at = jnp.zeros((T * k,), jnp.int32).at[order].set(
+        jnp.arange(T * k, dtype=jnp.int32)
+    ).reshape(T, k)
+    mine = at < sizes.sum()
+    picked = out[jnp.minimum(at, rows - 1)].astype(jnp.float32)  # [T, k, D]
+    return jnp.einsum(
+        "tk,tkd->td", jnp.where(mine, weights, 0.0),
+        jnp.where(mine[..., None], picked, 0.0),
+    )
+
+
+def held_experts_mlp(moe: Params, y: jax.Array, config) -> jax.Array:
+    """The expert layer's output for ``y`` [B, L, D]: what the held experts
+    give the tokens routed to them, beside the shared experts that take
+    every token. ``moe`` is ``take_held_layer``'s (or one layer's leaves)."""
+    c = config
+    B, L, D = y.shape
+    T = B * L
+    x = y.reshape(T, D)
+    with jax.named_scope("moe.route"):
+        chosen, weights = route_sigmoid(x, moe, c)
+    with jax.named_scope("moe.sort"):
+        order, sizes = sort_pairs(chosen, c)
+    small, every = sorted_rows(T, c), T * c.moe_top_k
+
+    def at(rows):
+        return lambda: grouped_experts(x, order, sizes, weights, moe, c, rows)
+
+    # dropless: the rare batch with more pairs than the common buffer
+    # holds runs at the buffer that holds every pair
+    if small < every:
+        routed = lax.cond(sizes.sum() <= small, at(small), at(every))
+    else:
+        routed = at(every)()
+    if c.moe_shared_experts:
+        with jax.named_scope("moe.shared"):
+            dt = c.dtype
+            gate = jnp.einsum("td,df->tf", x, moe["ws_gate"].astype(dt))
+            up = jnp.einsum("td,df->tf", x, moe["ws_up"].astype(dt))
+            routed = routed + jnp.einsum(
+                "tf,fd->td", jax.nn.silu(gate) * up, moe["ws_down"].astype(dt)
+            ).astype(jnp.float32)
+    return routed.astype(y.dtype).reshape(B, L, D)

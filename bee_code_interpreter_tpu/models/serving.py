@@ -82,6 +82,7 @@ from bee_code_interpreter_tpu.models.transformer import (
 from bee_code_interpreter_tpu.ops.paged_attention import reads_pages_in_place
 from bee_code_interpreter_tpu.ops.paged_kv_cache import (
     alloc_paged_cache,
+    pages_leaf,
     seed_prefill,
     seed_state,
 )
@@ -199,6 +200,35 @@ def log_normalizers(logits: jax.Array) -> jax.Array:
     return jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
 
 
+def row_answers(
+    logits: jax.Array,  # [B, 1, V] — the decode step's, as it leaves them
+    picked: jax.Array,  # [B] int32: ``pick_tokens``' ids
+    sampled: jax.Array,  # [B] bool: the rows whose token is ``picked``'s
+) -> jax.Array:
+    """All that the host reads of a plain step's logits when the device
+    chooses the tokens, as ONE array [3, B] int32: each row's token
+    (``picked``'s where it samples, else the argmax, first of the
+    largest), its logit at that token and its normaliser
+    (``log_normalizers``), the two float32s carried bit for bit. One
+    copy crosses to the host a step, not one per answer (each costs half
+    a millisecond on a v5e whatever its size) and not the ``[B, V]`` rows
+    for one logit each (16.8 MB and 3.7 ms a step at 64 rows of 65,536
+    logits): the part of a step that followed the host's load from run
+    to run (PERF.md, PR 33). The logit is a masked sum over the
+    vocabulary, which has one term and is exact; it splits over a
+    vocabulary sharded under a mesh as a gather by index would not."""
+    last = logits[:, -1, :].astype(jnp.float32)
+    token = jnp.where(sampled, picked, jnp.argmax(last, axis=-1))
+    token = token.astype(jnp.int32)
+    ids = lax.broadcasted_iota(jnp.int32, last.shape, 1)
+    logit = jnp.sum(jnp.where(ids == token[:, None], last, 0.0), axis=-1)
+    return jnp.stack([
+        token,
+        lax.bitcast_convert_type(logit, jnp.int32),
+        lax.bitcast_convert_type(log_normalizers(last), jnp.int32),
+    ])
+
+
 def filtered_probs_host(
     logits: np.ndarray, params: SamplingParams
 ) -> np.ndarray:
@@ -213,15 +243,46 @@ def filtered_probs_host(
         kth = np.partition(lg, -params.top_k)[-params.top_k]
         lg = np.where(lg < kth, -np.inf, lg)
     if params.top_p is not None:
-        order = np.argsort(-lg, kind="stable")
-        probs = np.exp(lg[order] - lg[order[0]])
-        probs /= probs.sum()
-        keep = np.cumsum(probs) - probs < params.top_p  # smallest set > p
-        keep[0] = True  # at least the top token (device-filter parity:
-        # top_p <= 0 would otherwise mask the whole vocab into NaNs)
-        lg[order[~keep]] = -np.inf
+        kept = _nucleus(lg, params.top_p)
+        masked = np.full(lg.shape, -np.inf)
+        masked[kept] = lg[kept]
+        lg = masked
     probs = np.exp(lg - lg.max())
     return probs / probs.sum()
+
+
+def _nucleus(lg: np.ndarray, top_p: float) -> np.ndarray:
+    """The ids the nucleus keeps of one row of scaled logits: the smallest
+    prefix of the stable descending order (logit descending, id ascending)
+    whose mass BEFORE each member is under ``top_p``, the top token always.
+    No sort of the row: the logits are binned by value (equal logits share
+    a bin), the bins' masses summed from the top find the one bin the
+    prefix ends in, every bin above it is kept whole, and only that bin's
+    few members are ordered. A stable argsort of the whole row cost 9 ms at
+    a vocabulary of 65,536, for the first token of every sampling request
+    (PERF.md, PR 33)."""
+    top = lg.max()
+    weights = np.exp(lg - top)
+    total = weights.sum()
+    live = weights > 0  # top-k's -inf, and what underflows, weigh nothing
+    low = lg[live].min()
+    n_bins = 4096
+    # bin 0 holds the largest logits
+    scale = (n_bins - 1) / (top - low) if top > low else 0.0
+    bins = np.where(live, (top - np.where(live, lg, top)) * scale, n_bins).astype(np.int64)
+    mass = np.bincount(bins, weights=weights, minlength=n_bins + 1)[:n_bins] / total
+    ends = np.cumsum(mass)
+    reached = np.flatnonzero(ends >= top_p)
+    last = int(reached[0]) if reached.size else int(np.flatnonzero(mass > 0)[-1])
+    members = np.flatnonzero(bins == last)
+    members = members[np.lexsort((members, -lg[members]))]
+    probs = weights[members] / total
+    before = ends[last] - mass[last] + np.cumsum(probs) - probs
+    keep = before < top_p
+    if last == 0:
+        keep[0] = True  # at least the top token (device-filter parity:
+        # top_p <= 0 would otherwise mask the whole vocab into NaNs)
+    return np.concatenate([np.flatnonzero(bins < last), members[keep]])
 
 
 def sample_host(
@@ -767,6 +828,8 @@ class ContinuousBatcher:
             "pick_tokens",
         )
         self._log_normalizers = self._track(log_normalizers, "log_normalizers")
+        self._row_answers = self._track(row_answers, "row_answers")
+        self._no_picks = jnp.zeros(max_batch, jnp.int32)
         if draft_config is not None:
             # the draft's own paged pool, addressed by the SAME block
             # tables/pages (one allocation covers both models' K/V)
@@ -877,29 +940,50 @@ class ContinuousBatcher:
 
     @staticmethod
     def _refuse_over_state(config, *, prefix_cache, draft_params, adapters, mesh):
-        """What a config with mamba layers cannot be served with, each
-        refused by name: the features below assume that everything a row
-        keeps is K/V by position, which a page can share, a window can
-        overwrite and a mesh can split by head. Recurrent state is one
-        value a row, advanced in place."""
-        if not config.n_mamba_layers:
-            return
-        for asked, name, why in (
-            (prefix_cache, "prefix_cache",
-             "a shared page carries K/V of its tokens but not the recurrent "
-             "state after them"),
-            (draft_params is not None, "draft_params (speculative mode)",
-             "a rejected draft would have to roll the recurrent state back"),
-            (bool(adapters), "adapters",
-             "adapter admissions prefill through windows, which do not "
-             "carry the recurrent state"),
-            (mesh is not None, "mesh (tp > 1)",
-             "the recurrent state and the mixer are kept whole on one chip"),
-        ):
-            if asked:
-                raise NotImplementedError(
-                    f"{name} is not supported over mamba layers: {why}"
-                )
+        """What a config with a latent cache or with mamba layers cannot be
+        served with, each refused by name. Over mamba layers the features
+        below assume that everything a row keeps is K/V by position, which
+        a page can share, a window can overwrite and a mesh can split by
+        head; recurrent state is one value a row, advanced in place. A
+        latent cache is one KV head a layer, kept whole on one chip, and
+        the window paths were not carried over to it (PERF.md 7)."""
+        speculative = draft_params is not None
+        refusals = []
+        if config.kv_lora_rank:
+            refusals.append(("a latent cache", (
+                (prefix_cache, "prefix_cache",
+                 "a hit admits its suffix through windows, which the latent "
+                 "pool does not run yet"),
+                (speculative, "draft_params (speculative mode)",
+                 "the verify window is not run over a latent pool yet"),
+                (bool(adapters), "adapters",
+                 "they target wq/wk/wv/wo, and latent attention has no wk "
+                 "and wv"),
+                (mesh is not None, "mesh (tp > 1)",
+                 "a latent cache has one KV head, which does not split by "
+                 "head"),
+            )))
+        if config.n_mamba_layers:
+            refusals.append(("mamba layers", (
+                (prefix_cache, "prefix_cache",
+                 "a shared page carries K/V of its tokens but not the "
+                 "recurrent state after them"),
+                (speculative, "draft_params (speculative mode)",
+                 "a rejected draft would have to roll the recurrent state "
+                 "back"),
+                (bool(adapters), "adapters",
+                 "adapter admissions prefill through windows, which do not "
+                 "carry the recurrent state"),
+                (mesh is not None, "mesh (tp > 1)",
+                 "the recurrent state and the mixer are kept whole on one "
+                 "chip"),
+            )))
+        for over, table in refusals:
+            for asked, name, why in table:
+                if asked:
+                    raise NotImplementedError(
+                        f"{name} is not supported over {over}: {why}"
+                    )
 
     # throughput gauge window: samples older than this are dropped at read
     # time, and a gauge whose newest sample is older reads 0 — an idle
@@ -1000,7 +1084,7 @@ class ContinuousBatcher:
         from bee_code_interpreter_tpu.parallel.mesh import device_memory_rows
 
         return device_memory_rows(
-            sorted(self.cache["k"].devices(), key=lambda d: d.id)
+            sorted(pages_leaf(self.cache).devices(), key=lambda d: d.id)
         )
 
     def profiler_trace(self, trace_dir: str):
@@ -1050,6 +1134,15 @@ class ContinuousBatcher:
         # they lie in the stacked leaf, or a layer's slice is cut, scattered
         # into and the table's width gathered out of it
         in_place = self._decode_in_place
+        # what a token keeps in the pages: K and V per head, or one latent
+        # for all heads (its bytes as the pool lays them, pad included)
+        c = self.config
+        out["cache_kind"] = "latent" if c.kv_lora_rank else "kv_heads"
+        out["cache_bytes_per_token"] = sum(
+            x.dtype.itemsize * x.shape[0] * int(np.prod(x.shape[2:]))
+            // self.page_size
+            for name, x in self.cache.items() if name not in ("ssm", "conv")
+        )
         out["decode_attention"] = "pages_in_place" if in_place else "gathered"
         out["decode_append"] = "in_place" if in_place else "scattered"
         return out
@@ -1232,16 +1325,25 @@ class ContinuousBatcher:
         L = int(prompt.shape[0])
         if L < 1:
             raise ValueError("prompt must be non-empty")
+        windowless = None  # what the admission window cannot run over, and why
         if self.config.n_mamba_layers:
+            windowless = ("mamba layers", (
+                "the chunks and windows of such an admission do not "
+                "carry the recurrent state from one to the next"
+            ))
+        elif self.config.kv_lora_rank:
+            windowless = ("a latent cache", (
+                "the admission window is not run over a latent pool yet"
+            ))
+        if windowless is not None:
+            over, why = windowless
             for asked, name in (
                 (prefill_chunk, "prefill_chunk"),
                 (interleave_admission, "interleave_admission"),
             ):
                 if asked is not None:
                     raise NotImplementedError(
-                        f"{name} is not supported over mamba layers: the "
-                        "chunks and windows of such an admission do not "
-                        "carry the recurrent state from one to the next"
+                        f"{name} is not supported over {over}: {why}"
                     )
         if interleave_admission is not None and (
             interleave_admission < self.page_size
@@ -1457,11 +1559,29 @@ class ContinuousBatcher:
         with self._request_context(req), self._phase(
             "serve.admit", req=req, prompt_tokens=int(rec["prompt"].shape[0]),
             pages=len(rec["pages"]),
+            **self._held_pairs_stat(int(rec["prompt"].shape[0])),
         ), self._admission(row, rec, propagate=True):
             while not self._admit_next(row, rec):
                 pass
             with self._phase("serve.admit.activate"):
                 self._activate_row(row, rec)
+
+    def _held_pairs_stat(self, tokens: int) -> dict:
+        """For a configuration whose expert layers hold a share of the
+        experts (the sorted dispatch): the (token, expert) pairs ``tokens``
+        route to the held experts over all expert layers, as
+        ``held_expert_pairs``. The EXPECTATION under even routing (tokens x
+        top-k x held / routed, a layer): the programs hand back logits and
+        the pool alone, and a count of their own would be one more pull a
+        step. Empty for every other configuration."""
+        c = self.config
+        if not c.n_experts or c.moe_scoring != "sigmoid":
+            return {}
+        per_token = (
+            (c.n_layers - c.n_dense_layers) * c.moe_top_k
+            * c.held_experts / c.n_experts
+        )
+        return {"held_expert_pairs": int(round(tokens * per_token))}
 
     def _request_context(self, req: int):
         """The request's serving trace as the current context, while a
@@ -1709,16 +1829,18 @@ class ContinuousBatcher:
             pages[: padded.shape[1] // self.page_size], dtype=jnp.int32
         )
         with self._phase("serve.admit.prefill"):
+            # K and V [layers, 1, kvh, Lp, dh], or a latent [layers, 1, Lp,
+            # width] alone (no V: ``v_pre`` is empty)
             if self._seed_state is None:
-                logits, (k_pre, v_pre) = self._prefill(self.params, padded)
+                logits, (k_pre, *v_pre) = self._prefill(self.params, padded)
             else:  # the state it hands back is that of the L real tokens
-                logits, (k_pre, v_pre, ssm, conv) = self._prefill(
+                logits, (k_pre, *v_pre, ssm, conv) = self._prefill(
                     self.params, padded, length=np.int32(L)
                 )
         with self._phase("serve.admit.seed_pool"):
             self.cache = seed_prefill(
                 self.cache, pages_arr,
-                k_pre[:, 0, :, :L, :], v_pre[:, 0, :, :L, :],
+                k_pre[:, 0, ..., :L, :], *[v[:, 0, :, :L, :] for v in v_pre],
             )
         if self._seed_state is not None:
             with self._phase(
@@ -1912,6 +2034,9 @@ class ContinuousBatcher:
                     "max_batch": int(self.active.shape[0]),
                     "decode_tokens": produced,
                     "prefill_tokens": self._prefill_tokens - prefill_before,
+                    **self._held_pairs_stat(
+                        produced + self._prefill_tokens - prefill_before
+                    ),
                     "spec_accepted": self._spec_accepted - spec_acc_before,
                     "spec_rejected": self._spec_rejected - spec_rej_before,
                     # rows of the plain step whose token pick_tokens drew /
@@ -1964,24 +2089,11 @@ class ContinuousBatcher:
             ]
             self._host_picked += len(host_rows)
             self._device_picked += len(device_rows)
-            # the common case moves [B] int32s; the full [max_batch, V]
-            # logits cross to host only when some active row is steered or
-            # records logprobs
-            logprob_rows = any(
-                self.row_sampling[row].logprobs for row in active_rows
-            )
-            need_rows = bool(host_rows) or logprob_rows
-            # ...and the device argmax + its [B] pull only runs when some
-            # active row actually decodes greedily: an all-sampled batch
-            # was paying an argmax kernel and a host sync per token for an
-            # array nobody read — found by the jaxlint host-sync audit
-            # (docs/analysis.md "Accelerator lint")
-            need_greedy = len(host_rows) + len(device_rows) < len(active_rows)
-            # the small programs that cut the answer down to what the host
-            # reads are queued behind the decode step before anything
-            # waits: the device runs them back to back, and their dispatch
-            # (a dozen tiny programs for one negative index) hides under it
-            picked = None
+            # what the device answers is cut down to what the host reads
+            # by small programs queued behind the decode step before
+            # anything waits: the device runs them back to back and their
+            # dispatch hides under it
+            picked = self._no_picks
             if device_rows:
                 # one uniform a row from the request's own generator: its
                 # tokens depend on its seed and on how many it has drawn,
@@ -1996,25 +2108,22 @@ class ContinuousBatcher:
                     ),
                     draw,
                 )
-            last = logits[:, -1, :] if need_greedy or need_rows else None
-            log_z = self._log_normalizers(last) if logprob_rows else None
-            greedy = jnp.argmax(last, axis=-1) if need_greedy else None
-            lg = last if need_rows else None
+            is_picked = np.zeros(self.active.shape[0], dtype=bool)
+            is_picked[device_rows] = True
+            answers = self._row_answers(logits, picked, is_picked)
+            # the full [max_batch, V] logits cross to the host only when
+            # some active row is steered: its token is picked there
+            lg = logits[:, -1, :] if host_rows else None
         with self._phase("serve.step.wait"):
-            jax.block_until_ready((greedy, picked, lg, log_z))
+            jax.block_until_ready((answers, lg))
         with self._phase(
             "serve.step.pull",
-            bytes=sum(
-                x.nbytes for x in (greedy, picked, lg, log_z) if x is not None
-            ),
+            bytes=answers.nbytes + (lg.nbytes if host_rows else 0),
         ):
-            if logprob_rows:
-                log_z = np.asarray(log_z, dtype=np.float64)
-            if need_greedy:
-                greedy = np.asarray(greedy, dtype=np.int32)
-            if device_rows:
-                picked = np.asarray(picked, dtype=np.int32)
-            if need_rows:
+            token, logit, log_z = np.asarray(answers, dtype=np.int32)
+            logit = logit.view(np.float32)
+            log_z = log_z.view(np.float32).astype(np.float64)
+            if host_rows:
                 lg = np.asarray(lg, dtype=np.float32)
         with self._phase(
             "serve.step.sample",
@@ -2045,17 +2154,21 @@ class ContinuousBatcher:
                         self.errors[req_row] = repr(e)
                         self._retire(int(row), "error")
                         continue
-                elif sp.temperature > 0.0:
-                    nxt = int(picked[row])
                 else:
-                    nxt = int(greedy[row])
+                    nxt = int(token[row])
                 self.pos[row] += 1
                 self.current[row, 0] = nxt
                 self.results[req_row].append(nxt)
                 self.n_tokens_generated += 1
                 if sp.logprobs:
+                    # the row's logits and the token, or the one logit the
+                    # device looked up for it
+                    at = (
+                        (lg[row], nxt) if sp.steered
+                        else (logit[row:row + 1], 0)
+                    )
                     self.results_logprobs[req_row].append(
-                        logprob(lg[row], nxt, log_z[row])
+                        logprob(*at, log_z[row])
                     )
                 self._retire_if_done(int(row))
 

@@ -43,8 +43,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from bee_code_interpreter_tpu.parallel.ring_attention import ring_attention
 
 Params = dict[str, Any]
-# an attention layer's own leaves (the norms and the MLP are every layer's)
+# an attention layer's own leaves (the norms and the MLP are every layer's):
+# K and V per head, or a latent for all heads (``kv_lora_rank``)
 ATTENTION_LEAVES = ("wq", "wk", "wv", "wo")
+LATENT_LEAVES = ("wq", "ln_q", "w_kva", "ln_kv", "w_kvb", "wo")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -126,12 +128,116 @@ class TransformerConfig:
     position_embedding: str = "rope"
     # the output head is the embedding transposed: no ``lm_head`` leaf
     tie_embeddings: bool = False
+    # every RMSNorm's epsilon (the published ``rms_norm_eps``)
+    rms_norm_eps: float = 1e-5
+    # Latent attention (MLA; DeepSeek-V2, arXiv:2405.04434, without a query
+    # latent): ``kv_lora_rank`` > 0 replaces K and V per head by ONE latent
+    # of ``kv_lora_rank`` values a token, normed, beside one rotary key of
+    # ``qk_rope_head_dim`` shared by all heads; that pair is what a token
+    # keeps in the cache (``latent_width``). A head's query is ``qk_nope_
+    # head_dim`` values scored against the latent's up-projection beside
+    # ``qk_rope_head_dim`` rotary ones; its value is ``v_head_dim`` wide.
+    # The prefill makes K and V per head from the latent (the published
+    # form, through the flash kernel); a decode step scores the query's
+    # up-projected form against the cached latent itself (the absorbed
+    # form: one KV head whose values are the first ``kv_lora_rank`` of its
+    # keys). ``qk_norm`` (published ``use_qk_norm``) norms each head's
+    # whole query, with a scale of its own, before the rotary split.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    qk_norm: bool = False
+    # The published ``rope_scaling`` group of type ``deepseek_yarn`` (YaRN,
+    # arXiv:2309.00071: ``factor``, ``original_max_position_embeddings``,
+    # ``beta_fast``, ``beta_slow``, ``mscale``, ``mscale_all_dim``), frozen
+    # to a sorted tuple of its items. None = plain rotary frequencies.
+    rope_yarn: Any = None
+    # The first ``n_dense_layers`` layers (published ``first_k_dense_
+    # replace``) keep the dense SwiGLU of ``d_ff`` in an expert model; their
+    # leaves are stacked under ``params["dense_layers"]`` and they run
+    # before the scan over the expert layers.
+    n_dense_layers: int = 0
+    # "softmax": Mixtral's router (softmax over all experts, top-k,
+    # renormalised) through the GShard dispatch of ``moe.moe_mlp``.
+    # "sigmoid": the DeepSeek-V3 lineage's (sigmoid scores in float32, the
+    # top-k of score + a per-expert bias, weighted by the kept scores over
+    # their sum times ``moe_routed_scaling``) through the token-sorted
+    # dropless dispatch of ``moe.held_experts_mlp``, which is told which
+    # experts it holds: ``moe_held_experts`` of them from ``moe_held_from``
+    # on (None = all ``n_experts``, the router's width). What the experts
+    # held elsewhere would add is left out and the partial sum goes on.
+    # ``moe_d_ff`` is an expert's own width (None = ``d_ff``),
+    # ``moe_shared_experts`` experts of that width take every token, and
+    # with ``moe_router_bias`` the router leaf's last row is the bias.
+    moe_scoring: str = "softmax"
+    moe_held_experts: int | None = None
+    moe_held_from: int = 0
+    moe_d_ff: int | None = None
+    moe_shared_experts: int = 0
+    moe_routed_scaling: float = 1.0
+    moe_router_bias: bool = False
 
     def __post_init__(self) -> None:
         if self.position_embedding not in ("rope", "nope"):
             raise ValueError(
                 f"position_embedding must be 'rope' or 'nope', got "
                 f"{self.position_embedding!r}"
+            )
+        if self.rope_yarn is not None:
+            yarn = dict(self.rope_yarn)
+            kind = yarn.get("type", yarn.get("rope_type"))
+            if kind != "deepseek_yarn":
+                raise ValueError(
+                    f"rope_yarn is a rope_scaling group of type "
+                    f"'deepseek_yarn', got {kind!r}"
+                )
+            object.__setattr__(self, "rope_yarn", tuple(sorted(yarn.items())))
+        if self.moe_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_scoring must be 'softmax' or 'sigmoid', got "
+                f"{self.moe_scoring!r}"
+            )
+        if self.kv_lora_rank and not (
+            self.qk_nope_head_dim and self.qk_rope_head_dim and self.v_head_dim
+        ):
+            raise ValueError(
+                "a latent cache (kv_lora_rank) needs qk_nope_head_dim, "
+                "qk_rope_head_dim and v_head_dim"
+            )
+        if self.kv_lora_rank and self.kv_cache_dtype != "bf16":
+            raise NotImplementedError(
+                "an int8 latent cache is not supported: the latent is kept "
+                "in the compute dtype"
+            )
+        sorted_only = {
+            "moe_held_experts": self.moe_held_experts is not None,
+            "moe_shared_experts": bool(self.moe_shared_experts),
+            "moe_router_bias": self.moe_router_bias,
+            "moe_routed_scaling": self.moe_routed_scaling != 1.0,
+            "n_dense_layers": bool(self.n_dense_layers),
+        }
+        if self.moe_scoring != "sigmoid" and any(sorted_only.values()):
+            raise NotImplementedError(
+                f"{[k for k, v in sorted_only.items() if v]} belong to the "
+                "sorted expert layer (moe_scoring='sigmoid'); the GShard "
+                "dispatch holds every expert and has none of them"
+            )
+        if self.moe_scoring == "sigmoid" and not (
+            0 <= self.moe_held_from
+            and self.moe_held_from + self.held_experts <= self.n_experts
+            and self.moe_top_k <= self.n_experts
+        ):
+            raise ValueError(
+                f"experts {self.moe_held_from}..{self.moe_held_from + self.held_experts}"
+                f" held of a router of {self.n_experts}, top {self.moe_top_k}"
+            )
+        if self.n_dense_layers and (
+            self.layer_types is not None or self.n_dense_layers >= self.n_layers
+        ):
+            raise ValueError(
+                "leading dense layers precede a scan of expert layers, with "
+                "no declared layer pattern"
             )
         if self.layer_types is None:
             return
@@ -197,6 +303,38 @@ class TransformerConfig:
         return self.n_kv_heads or self.n_heads
 
     @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this program holds."""
+        if self.moe_held_experts is None:
+            return self.n_experts
+        return self.moe_held_experts
+
+    @property
+    def expert_ff_dim(self) -> int:
+        return self.ff_dim if self.moe_d_ff is None else self.moe_d_ff
+
+    @property
+    def qk_head_dim(self) -> int:
+        """A latent-attention head's query: the part scored against the
+        latent's up-projection and the rotary part."""
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def latent_width(self) -> int:
+        """What a token keeps a layer in a latent cache: the normed latent
+        and the shared rotary key, padded with zeros to whole lane tiles.
+        The decode kernel copies whole pages, and Mosaic slices no page
+        whose rows are not whole tiles out of the leaf ("Slice shape along
+        dimension 4 must be aligned to tiling (128), but is 576", compiled
+        for a described v5e; PERF.md, PR 33); in the (8, 128) tiles the
+        kernel takes its operand in, a 576-wide row occupies 640 anyway."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def yarn(self) -> dict | None:
+        return None if self.rope_yarn is None else dict(self.rope_yarn)
+
+    @property
     def head_dim(self) -> int:
         return self.d_model // self.n_heads
 
@@ -214,12 +352,14 @@ class TransformerConfig:
         composition — dense configs always; MoE configs under dropless
         per-token routing (moe_dropless + moe_group_size=1: no capacity
         eviction, and the expert einsums see the pool only as a batch
-        dim). The exactness-claiming features (serving solo-equality,
+        dim), and always under the sorted dispatch (``moe_scoring``
+        "sigmoid"), which drops nothing and computes a (token, expert)
+        pair's row from that token alone. The exactness-claiming features (serving solo-equality,
         prefix cache, speculative verify, beam rescoring) key on this;
         dropless with larger groups is deterministic and ulp-stable but
         reduction tiling varies with pool shape, so near-exact logit ties
         could flip a token."""
-        return self.n_experts == 0 or (
+        return self.n_experts == 0 or self.moe_scoring == "sigmoid" or (
             self.moe_dropless and self.moe_group_size == 1
         )
 
@@ -258,22 +398,63 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-5) -> jax.Array:
     return (norm * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 mscale ln(factor) + 1."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(d: int, theta: float, yarn: dict) -> jax.Array:
+    """The [d/2] rotary frequencies of a ``deepseek_yarn`` group (YaRN,
+    "NTK-by-parts"): a dimension that turns more than ``beta_fast`` times
+    within the original context keeps its frequency, one that turns fewer
+    than ``beta_slow`` times has it divided by ``factor``, and a linear ramp
+    over the dimensions between blends the two."""
+    plain = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    original = yarn["original_max_position_embeddings"]
+
+    def turns_at(rotations):  # the dimension that turns so often
+        return d * math.log(original / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta)
+        )
+
+    low = max(math.floor(turns_at(yarn["beta_fast"])), 0)
+    high = min(math.ceil(turns_at(yarn["beta_slow"])), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip(
+        (jnp.arange(d // 2, dtype=jnp.float32) - low) / (high - low), 0.0, 1.0
+    )
+    return plain / yarn["factor"] * ramp + plain * (1.0 - ramp)
+
+
 def rope(
-    x: jax.Array, positions: jax.Array, theta: float, scaling: float = 1.0
+    x: jax.Array, positions: jax.Array, theta: float, scaling: float = 1.0,
+    yarn: dict | None = None,
 ) -> jax.Array:
     """Rotary embeddings over [B, H, L, D_head] with positions [B, L].
 
     ``scaling`` > 1 is linear position interpolation (Chen et al. — effective
     position = position / scaling), the simple context-extension recipe: a
     model trained at L runs at scaling·L with positions compressed back into
-    the trained range."""
+    the trained range. ``yarn`` (a ``deepseek_yarn`` group) takes
+    ``yarn_frequencies`` in place of the plain ones and scales cos and sin
+    by mscale(factor, mscale) / mscale(factor, mscale_all_dim)."""
     if scaling <= 0:
         raise ValueError(f"rope scaling must be > 0, got {scaling}")
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # [d/2]
+    amplitude = 1.0
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)  # [d/2]
+    else:
+        freqs = yarn_frequencies(d, theta, yarn)
+        amplitude = yarn_mscale(yarn["factor"], yarn.get("mscale", 1)) / (
+            yarn_mscale(yarn["factor"], yarn.get("mscale_all_dim", 0))
+        )
     scaled = positions.astype(jnp.float32) / scaling
     angles = scaled[:, None, :, None] * freqs  # [B,1,L,d/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     rotated = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return rotated.astype(x.dtype)
@@ -301,6 +482,10 @@ def qeinsum(spec: str, x: jax.Array, leaf, dtype) -> jax.Array:
 def init_params(config: TransformerConfig, key: jax.Array) -> Params:
     """f32 master params; stacked [n_layers, ...] leading axis for lax.scan.
 
+    Leading dense layers of an expert model (``config.n_dense_layers``) are
+    stacked under ``dense_layers`` and ``layers`` holds the expert layers
+    after them. With latent attention (``config.kv_lora_rank``) an attention
+    layer's leaves are ``LATENT_LEAVES``.
     With a layer pattern (``config.layer_types``) every layer-stacked leaf
     still sits under ``layers``, each stacked over the layers that have it:
     the norms and the MLP over all of them, ``wq wk wv wo`` over the
@@ -313,6 +498,25 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
         return jax.random.normal(key, shape, dtype=jnp.float32) / math.sqrt(fan_in)
 
     def attention(ks):
+        if c.kv_lora_rank:
+            # wq a head's [nope | rope] query; w_kva the latent beside the
+            # shared rotary key; w_kvb a head's [k_nope | v] from the latent
+            rank, per_head = c.kv_lora_rank, c.qk_nope_head_dim + c.v_head_dim
+            out = {
+                "wq": dense(ks[0], c.d_model, c.d_model, c.n_heads * c.qk_head_dim),
+                "w_kva": dense(
+                    ks[1], c.d_model, c.d_model, rank + c.qk_rope_head_dim
+                ),
+                "ln_kv": jnp.ones((rank,), jnp.float32),
+                "w_kvb": dense(ks[2], rank, rank, c.n_heads * per_head),
+                "wo": dense(
+                    ks[3], c.n_heads * c.v_head_dim,
+                    c.n_heads * c.v_head_dim, c.d_model,
+                ),
+            }
+            if c.qk_norm:
+                out["ln_q"] = jnp.ones((c.qk_head_dim,), jnp.float32)
+            return out
         dh, kvh = c.head_dim, c.kv_heads
         return {
             "wq": dense(ks[0], c.d_model, c.d_model, c.n_heads * dh),
@@ -321,12 +525,16 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
             "wo": dense(ks[3], c.n_heads * dh, c.n_heads * dh, c.d_model),
         }
 
-    def mlp(ks):
+    def mlp(ks, experts=bool(c.n_experts)):
         out = {
             "ln1": jnp.ones((c.d_model,), jnp.float32),
             "ln2": jnp.ones((c.d_model,), jnp.float32),
         }
-        if c.n_experts:
+        if experts and c.moe_scoring == "sigmoid":
+            from bee_code_interpreter_tpu.models.moe import init_held_params
+
+            out["moe"] = init_held_params(ks[0], c)
+        elif experts:
             from bee_code_interpreter_tpu.models.moe import init_moe_params
 
             out["moe"] = init_moe_params(ks[0], c.d_model, c.ff_dim, c.n_experts)
@@ -341,12 +549,18 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
             jax.random.split(key, n)
         )
 
+    dense_layers = None
     if c.layer_types is None:
-        def layer(key):
+        def layer(key, **kind):
             ks = jax.random.split(key, 7)
-            return {**attention(ks[:4]), **mlp(ks[4:])}
+            return {**attention(ks[:4]), **mlp(ks[4:], **kind)}
 
-        stacked = jax.vmap(layer)(jax.random.split(k_layers, c.n_layers))
+        keys = jax.random.split(k_layers, c.n_layers)
+        stacked = jax.vmap(layer)(keys[c.n_dense_layers:])
+        if c.n_dense_layers:
+            dense_layers = jax.vmap(functools.partial(layer, experts=False))(
+                keys[:c.n_dense_layers]
+            )
     else:
         k_attn, k_mamba, k_mlp = jax.random.split(k_layers, 3)
         stacked = {
@@ -364,6 +578,8 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
         "layers": stacked,
         "ln_f": jnp.ones((c.d_model,), jnp.float32),
     }
+    if dense_layers is not None:
+        params["dense_layers"] = dense_layers
     if not c.tie_embeddings:
         params["lm_head"] = dense(k_out, c.d_model, c.d_model, c.vocab_size)
     return params
@@ -383,7 +599,27 @@ def param_specs(config: TransformerConfig, mesh: Mesh) -> Params:
         "wq": _stack(col), "wk": _stack(col), "wv": _stack(col),
         "wo": _stack(row),
     }
-    if config.n_experts:
+    if config.kv_lora_rank:
+        # heads over tp; the latent projection and its norm are every chip's
+        for name in ("wk", "wv"):
+            del layer[name]
+        layer.update({
+            "w_kva": _stack(rep), "ln_kv": _stack(rep), "w_kvb": _stack(col),
+        })
+        if config.qk_norm:
+            layer["ln_q"] = _stack(rep)
+    dense_layer = dict(layer)
+    if config.n_experts and config.moe_scoring == "sigmoid":
+        layer["moe"] = {
+            "router": _stack(rep), "we_gate": _stack(P(ep, fsdp, tp)),
+            "we_up": _stack(P(ep, fsdp, tp)), "we_down": _stack(P(ep, tp, fsdp)),
+        }
+        if config.moe_shared_experts:
+            layer["moe"].update({
+                "ws_gate": _stack(col), "ws_up": _stack(col),
+                "ws_down": _stack(row),
+            })
+    elif config.n_experts:
         # expert axis over ep, expert-internal matmuls Megatron-style
         layer["moe"] = {
             "router": _stack(P(None, None)),  # small; replicated
@@ -406,6 +642,11 @@ def param_specs(config: TransformerConfig, mesh: Mesh) -> Params:
         "layers": layer,
         "ln_f": rep,
     }
+    if config.n_dense_layers:
+        specs["dense_layers"] = {
+            **dense_layer, "w_gate": _stack(col), "w_up": _stack(col),
+            "w_down": _stack(row),
+        }
     if not config.tie_embeddings:
         specs["lm_head"] = P(None, tp)   # column-parallel output projection
     return specs
@@ -544,7 +785,9 @@ def _positioned(x, positions, config: TransformerConfig):
     or nothing at all (the published "nope")."""
     if config.position_embedding == "nope":
         return x
-    return rope(x, positions, config.rope_theta, config.rope_scaling)
+    return rope(
+        x, positions, config.rope_theta, config.rope_scaling, config.yarn
+    )
 
 
 def _residual(h, branch, config: TransformerConfig):
@@ -571,23 +814,29 @@ def _layer_apply(
     (h, kv_out | None, aux-loss scalar)."""
     c = config
     B, L = h.shape[0], h.shape[1]
-    x = rms_norm(h, layer["ln1"])
-    dh, nh, kvh = c.head_dim, c.n_heads, c.kv_heads
+    x = rms_norm(h, layer["ln1"], c.rms_norm_eps)
+    if c.kv_lora_rank:
+        q_nope, q_rope, latent = _latent_projections(x, layer, c, positions)
+        # what a token keeps: the latent beside the shared rotary key
+        kv_out = (_pad_latent(latent, c),) if return_kv else None
+        attn = _latent_attention_published(q_nope, q_rope, latent, layer, c, mesh)
+    else:
+        dh, nh, kvh = c.head_dim, c.n_heads, c.kv_heads
 
-    def proj(w, heads):
-        out = qeinsum("bld,dk->blk", x, w, c.dtype)
-        return out.reshape(B, L, heads, dh).transpose(0, 2, 1, 3)
+        def proj(w, heads):
+            out = qeinsum("bld,dk->blk", x, w, c.dtype)
+            return out.reshape(B, L, heads, dh).transpose(0, 2, 1, 3)
 
-    q = _positioned(proj(layer["wq"], nh), positions, c)
-    k = _positioned(proj(layer["wk"], kvh), positions, c)
-    v = proj(layer["wv"], kvh)
-    kv_out = (k, v) if return_kv else None
-    # GQA-native: compact k/v go in as-is
-    attn = _attention(
-        q, k, v, mesh, c.sp_attention, window=c.sliding_window,
-        sm_scale=c.attention_multiplier,
-    )
-    attn = attn.transpose(0, 2, 1, 3).reshape(B, L, nh * dh)
+        q = _positioned(proj(layer["wq"], nh), positions, c)
+        k = _positioned(proj(layer["wk"], kvh), positions, c)
+        v = proj(layer["wv"], kvh)
+        kv_out = (k, v) if return_kv else None
+        # GQA-native: compact k/v go in as-is
+        attn = _attention(
+            q, k, v, mesh, c.sp_attention, window=c.sliding_window,
+            sm_scale=c.attention_multiplier,
+        )
+        attn = attn.transpose(0, 2, 1, 3).reshape(B, L, nh * dh)
     h = _residual(
         h, constrain(qeinsum("blk,kd->bld", attn, layer["wo"], c.dtype)), c
     )
@@ -595,9 +844,91 @@ def _layer_apply(
     return h, kv_out, aux
 
 
+def _latent_projections(x, layer, config: TransformerConfig, positions):
+    """Latent attention's projections of the normed ``x`` [B, L, D]: a head's
+    query as ``q_nope`` [B, nh, L, qk_nope] and the rotated ``q_rope`` [B,
+    nh, L, qk_rope] (the whole query normed first under ``qk_norm``), and
+    what a token keeps, [B, L, kv_lora_rank + qk_rope]: the normed latent
+    beside the one rotated key all heads share."""
+    c = config
+    B, L = x.shape[:2]
+    rank, eps = c.kv_lora_rank, c.rms_norm_eps
+    q = qeinsum("bld,dk->blk", x, layer["wq"], c.dtype).reshape(
+        B, L, c.n_heads, c.qk_head_dim
+    )
+    if c.qk_norm:
+        q = rms_norm(q, layer["ln_q"], eps)
+    q = q.transpose(0, 2, 1, 3)
+    q_nope, q_rope = q[..., :c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
+    kva = qeinsum("bld,dk->blk", x, layer["w_kva"], c.dtype)
+    latent = rms_norm(kva[..., :rank], layer["ln_kv"], eps)
+    k_rope = _positioned(kva[:, None, :, rank:], positions, c)[:, 0]
+    return (
+        q_nope, _positioned(q_rope, positions, c),
+        jnp.concatenate([latent, k_rope], axis=-1),
+    )
+
+
+def _pad_latent(latent, config: TransformerConfig):
+    """A token's latent and rotary key at the width the pool keeps them."""
+    pad = config.latent_width - latent.shape[-1]
+    return jnp.pad(latent, ((0, 0),) * (latent.ndim - 1) + ((0, pad),))
+
+
+def _kvb_by_head(layer, config: TransformerConfig):
+    """``w_kvb`` as [kv_lora_rank, heads, qk_nope + v]: a head's key and
+    value up-projections side by side."""
+    c = config
+    return layer["w_kvb"].astype(c.dtype).reshape(
+        c.kv_lora_rank, c.n_heads, c.qk_nope_head_dim + c.v_head_dim
+    )
+
+
+def _latent_attention_published(q_nope, q_rope, latent, layer, config, mesh):
+    """The published form, over whole sequences: K and V made per head from
+    the latent, the shared rotary key beside every head's, then ordinary
+    causal attention at a query of qk_nope + qk_rope and a value of v_head_dim
+    (the flash kernel on a TPU): [B, L, heads * v_head_dim]."""
+    c = config
+    B, nh, L, dn = q_nope.shape
+    rank = c.kv_lora_rank
+    kv = jnp.einsum(
+        "blc,chd->bhld", latent[..., :rank], _kvb_by_head(layer, c)
+    )
+    k_rope = jnp.broadcast_to(
+        latent[:, None, :, rank:], (B, nh, L, c.qk_rope_head_dim)
+    )
+    attn = _attention(
+        jnp.concatenate([q_nope, q_rope], axis=-1),
+        jnp.concatenate([kv[..., :dn], k_rope], axis=-1), kv[..., dn:],
+        mesh, c.sp_attention, sm_scale=_score_scale(c),
+    )
+    return attn.transpose(0, 2, 1, 3).reshape(B, L, nh * c.v_head_dim)
+
+
+def _absorbed_query(q_nope, q_rope, layer, config: TransformerConfig):
+    """The absorbed form's query, [B, heads, W, latent_width]: a head's
+    q_nope through its key up-projection (so that it scores against the
+    cached latent itself), its rotary part, and zeros over the pool's pad."""
+    c = config
+    w_uk = _kvb_by_head(layer, c)[..., :c.qk_nope_head_dim]
+    q_latent = jnp.einsum("bhwd,chd->bhwc", q_nope, w_uk)
+    return _pad_latent(jnp.concatenate([q_latent, q_rope], axis=-1), c)
+
+
+def _absorbed_output(o_latent, layer, config: TransformerConfig):
+    """The attention-weighted latents [B, heads, W, kv_lora_rank] through
+    each head's value up-projection: [B, W, heads * v_head_dim]."""
+    c = config
+    B, nh, W, _ = o_latent.shape
+    w_uv = _kvb_by_head(layer, c)[..., c.qk_nope_head_dim:]
+    out = jnp.einsum("bhwc,chd->bwhd", o_latent.astype(c.dtype), w_uv)
+    return out.reshape(B, W, nh * c.v_head_dim)
+
+
 def _mlp_residual(h, layer, config, constrain=lambda x: x):
     """The MLP half of every layer kind: (h + mlp(rmsnorm(h)), aux)."""
-    y = rms_norm(h, layer["ln2"])
+    y = rms_norm(h, layer["ln2"], config.rms_norm_eps)
     mlp, aux = _mlp_block(y, layer, config)
     return _residual(h, constrain(mlp), config), aux
 
@@ -608,7 +939,7 @@ def _mamba_layer_apply(h, layer, config, length, constrain=lambda x: x):
     from bee_code_interpreter_tpu.models.mamba import mixer_prefill
 
     mix, state, tail = mixer_prefill(
-        rms_norm(h, layer["ln1"]), layer, config, length
+        rms_norm(h, layer["ln1"], config.rms_norm_eps), layer, config, length
     )
     h, _ = _mlp_residual(_residual(h, constrain(mix), config), layer, config, constrain)
     return h, (state, tail)
@@ -622,7 +953,11 @@ def _mlp_block(
     decode_step_paged. Returns (mlp_out, aux) with aux = 0.0 for dense
     configs (decode paths drop it)."""
     c = config
-    if c.n_experts:
+    if "moe" in layer and c.moe_scoring == "sigmoid":
+        from bee_code_interpreter_tpu.models.moe import held_experts_mlp
+
+        return held_experts_mlp(layer["moe"], y, c), jnp.float32(0.0)
+    if "moe" in layer:
         from bee_code_interpreter_tpu.models.moe import moe_mlp
 
         return moe_mlp(
@@ -657,7 +992,7 @@ def _head(params: Params, h, config: TransformerConfig):
     head multiplies by the embedding transposed; ``logits_scaling`` divides
     the result."""
     c = config
-    h = rms_norm(h, params["ln_f"])
+    h = rms_norm(h, params["ln_f"], c.rms_norm_eps)
     if c.tie_embeddings:
         logits = qeinsum("bld,vd->blv", h, params["embed"], c.dtype)
     else:
@@ -683,13 +1018,24 @@ def _pattern_layer(layers: Params, config: TransformerConfig, period_index, j):
     )
     kind = period[j]
     of_kind = period_index * period.count(kind) + period[:j].count(kind)
-    own = MIXER_LEAVES if kind == "mamba" else ATTENTION_LEAVES
+    if kind == "mamba":
+        own = MIXER_LEAVES
+    else:
+        own = LATENT_LEAVES if c.kv_lora_rank else ATTENTION_LEAVES
+    every = period_index * len(period) + j
 
-    layer = {name: _take_layer(layers[name], of_kind) for name in own}
-    layer.update({
-        name: _take_layer(layers[name], period_index * len(period) + j)
-        for name in layers if name not in MIXER_LEAVES + ATTENTION_LEAVES
-    })
+    layer = {
+        name: _take_layer(layers[name], of_kind) for name in own if name in layers
+    }
+    for name in layers:
+        if name in MIXER_LEAVES + ATTENTION_LEAVES + LATENT_LEAVES:
+            continue
+        if name == "moe" and c.moe_scoring == "sigmoid":
+            from bee_code_interpreter_tpu.models.moe import take_held_layer
+
+            layer[name] = take_held_layer(layers[name], every)
+        else:
+            layer[name] = _take_layer(layers[name], every)
     return kind, of_kind, layer
 
 
@@ -701,14 +1047,30 @@ def _take_layer(stacked, index):
 
 
 def _n_periods(config: TransformerConfig) -> int:
-    return config.n_layers // config.layer_period
+    """Iterations of the layer scan: the periods of a declared pattern, or
+    the layers after the leading dense ones."""
+    return (config.n_layers - config.n_dense_layers) // config.layer_period
+
+
+def _scans_by_index(config: TransformerConfig) -> bool:
+    """Whether the layer scan takes each layer's leaves out of the stacks at
+    its index (a declared pattern, whose kinds are stacked apart; leading
+    dense layers, which run before it; held experts, whose stacks go to
+    the grouped matmul whole) where a plain decoder scans the stacks as
+    ``xs``."""
+    c = config
+    return (
+        c.layer_types is not None or bool(c.n_dense_layers)
+        or (bool(c.n_experts) and c.moe_scoring == "sigmoid")
+    )
 
 
 def _one_layer_kind(config: TransformerConfig, what: str) -> None:
-    if config.layer_types is not None:
+    if _scans_by_index(config) or config.kv_lora_rank:
         raise NotImplementedError(
-            f"{what} runs one layer kind under its scan; a declared layer "
-            "pattern runs through forward and decode_step_paged"
+            f"{what} runs one layer kind under its scan, K and V per head: "
+            "a declared layer pattern, leading dense layers, held experts "
+            "and a latent cache run through forward and decode_step_paged"
         )
 
 
@@ -721,9 +1083,17 @@ def _scaled_scores(scores, config: TransformerConfig):
 
 
 def _score_scale(config: TransformerConfig) -> float:
-    """``_scaled_scores`` as the one factor a kernel multiplies by."""
+    """``_scaled_scores`` as the one factor a kernel multiplies by. Latent
+    attention scales by the whole query's width, and under YaRN by
+    mscale(factor, mscale_all_dim) squared."""
     if config.attention_multiplier is not None:
         return config.attention_multiplier
+    if config.kv_lora_rank:
+        scale = 1.0 / math.sqrt(config.qk_head_dim)
+        yarn = config.yarn
+        if yarn is not None:
+            scale *= yarn_mscale(yarn["factor"], yarn.get("mscale_all_dim", 0)) ** 2
+        return scale
     return 1.0 / math.sqrt(config.head_dim)
 
 
@@ -785,7 +1155,8 @@ def forward(
         return h, (kv_out, aux)
 
     def period_step(h, period_index):
-        """One period of a declared pattern, its layers unrolled."""
+        """One period of a declared pattern (or one layer taken out of the
+        stacks at its index), its layers unrolled."""
         kv, state = [], []
         for j in range(c.layer_period):
             kind, _, layer = _pattern_layer(params["layers"], c, period_index, j)
@@ -801,13 +1172,22 @@ def forward(
         stack = lambda xs: tuple(jnp.stack(x) for x in zip(*xs))  # noqa: E731
         return h, (stack(kv), stack(state)) if return_kv else None
 
-    if c.layer_types is None:
+    if not _scans_by_index(c):
         h, (kv, aux_layers) = lax.scan(layer_step, h, params["layers"])
     else:
+        dense_kv = []
+        for i in range(c.n_dense_layers):
+            h, (kept, _) = layer_step(h, _take_layer(params["dense_layers"], i))
+            dense_kv.append(kept)
         h, kept = lax.scan(period_step, h, jnp.arange(_n_periods(c)))
         aux_layers = jnp.zeros((), jnp.float32)
         if return_kv:  # [periods, a period's layers of a kind, ...] -> [layers, ...]
             kv = tuple(x.reshape(-1, *x.shape[2:]) for part in kept for x in part)
+            if dense_kv:  # the leading layers' first
+                kv = tuple(
+                    jnp.concatenate([jnp.stack(first), rest])
+                    for first, rest in zip(zip(*dense_kv), kv)
+                )
     logits = _head(params, h, c)
     extras = []
     if return_kv:
@@ -993,7 +1373,7 @@ def decode_window(
 
     def layer_step(h, scanned):
         layer, c_layer = scanned
-        x = rms_norm(h, layer["ln1"])
+        x = rms_norm(h, layer["ln1"], c.rms_norm_eps)
         dh, nh, kvh = c.head_dim, c.n_heads, c.kv_heads
 
         def proj(w, heads):
@@ -1109,7 +1489,10 @@ def decode_window_paged(
     is untouched. Pinned by tests/test_multilora_serving.py.
     """
     from bee_code_interpreter_tpu.ops import paged_attention
-    from bee_code_interpreter_tpu.ops.paged_kv_cache import paged_append
+    from bee_code_interpreter_tpu.ops.paged_kv_cache import (
+        paged_append,
+        paged_page_size,
+    )
 
     c = config
     B, W = tokens.shape
@@ -1129,7 +1512,11 @@ def decode_window_paged(
             "a layer pattern with mamba layers decodes one token a row "
             "and takes no adapters: its state advances a token at a time"
         )
-    page_size = cache["k"].shape[3]
+    if c.kv_lora_rank and lora_bank is not None:
+        raise NotImplementedError(
+            "adapters target wq/wk/wv/wo: latent attention has no wk and wv"
+        )
+    page_size = paged_page_size(cache)
     positions = pos0[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]  # [B, W]
     page_idx = jnp.take_along_axis(
         block_table, positions // page_size, axis=1
@@ -1142,10 +1529,44 @@ def decode_window_paged(
 
     h = _embed(params, tokens, c)  # [B, W, D]
 
+    def latent_layer(h, layer, cache, index):
+        """Latent-attention layer ``index`` (traced) of the pool ``cache``,
+        whose one leaf ``ckv`` is [layers, n_pages, ps, latent_width]: the
+        absorbed form, one KV head whose values are the first
+        ``kv_lora_rank`` of its keys."""
+        x = rms_norm(h, layer["ln1"], c.rms_norm_eps)
+        q_nope, q_rope, latent = _latent_projections(x, layer, c, positions)
+        latent = _pad_latent(latent, c)  # [B, W, latent_width]
+        with jax.named_scope("mla.absorb"):
+            q = _absorbed_query(q_nope, q_rope, layer, c)
+        with jax.named_scope("mla.attend"):
+            if in_place:
+                o_latent, ckv = paged_attention.paged_decode_attention(
+                    q[:, :, 0], cache["ckv"], None, block_table,
+                    positions[:, 0] + 1, sm_scale=_score_scale(c), mesh=mesh,
+                    layer=index, k_new=latent, v_width=c.kv_lora_rank,
+                )
+                o_latent = o_latent[:, :, None]  # [B, nh, 1, rank]
+                cache = {**cache, "ckv": ckv}
+            else:
+                c_layer = paged_append(
+                    _take_layer({"ckv": cache["ckv"]}, index), latent, None,
+                    page_idx, slot_idx,
+                )
+                o_latent = _attend_paged(
+                    q, c_layer, block_table, positions, c
+                ).reshape(B, W, c.n_heads, c.kv_lora_rank).transpose(0, 2, 1, 3)
+                cache = _put_layer(cache, c_layer, index)
+        with jax.named_scope("mla.absorb"):
+            attn = _absorbed_output(o_latent, layer, c)
+        o = qeinsum("blk,kd->bld", attn, layer["wo"], c.dtype)
+        h, _ = _mlp_residual(_residual(h, o, c), layer, c)
+        return h, cache
+
     def attention_layer(h, layer, cache, index):
         """Attention layer ``index`` (traced) of the pool ``cache``, whose
         K/V leaves are [attention layers, n_pages, kvh, ps, dh]."""
-        x = rms_norm(h, layer["ln1"])
+        x = rms_norm(h, layer["ln1"], c.rms_norm_eps)
         dh, nh, kvh = c.head_dim, c.n_heads, c.kv_heads
         lora_layer = {} if lora_bank is None else _take_layer(lora_bank, index)
 
@@ -1195,27 +1616,33 @@ def decode_window_paged(
         h, _ = _mlp_residual(_residual(h, o, c), layer, c)
         return h, cache
 
-    h, cache = _decode_layers(params, h, cache, c, attention_layer)
+    h, cache = _decode_layers(
+        params, h, cache, c, latent_layer if c.kv_lora_rank else attention_layer
+    )
     return _head(params, h, c), cache
 
 
 def _attend_paged(q, c_layer, block_table, positions, config: TransformerConfig):
     """Attention of ``q`` [B, nh, W, dh] (at ``positions`` [B, W]) over one
     layer's pages, the width of each row's block table gathered
-    (``paged_read``) for the grouped einsums: [B, W, nh * dh]. What a plain
-    decode step on a TPU does in its place is ``paged_decode_attention``,
-    whose oracle this is."""
+    (``paged_read``) for the grouped einsums: [B, kvh, rep, W, dv] (a latent
+    layer: one KV head, ``dv`` the latent's rank, the absorbed query's heads
+    all on it). What a plain decode step on a TPU does in its place is
+    ``paged_decode_attention``, whose oracle this is."""
     from bee_code_interpreter_tpu.ops.paged_kv_cache import paged_read
 
     c = config
     B, nh, W, dh = q.shape
-    kvh = c.kv_heads
-    kf, vf = paged_read(c_layer, block_table, c.dtype)  # [B,kvh,S,dh]
-    S = kf.shape[2]
+    kf, vf = paged_read(c_layer, block_table, c.dtype, c.kv_lora_rank)
+    kvh, S = kf.shape[1:3]  # [B,kvh,S,dh]
 
     rep = nh // kvh
     qg = q.reshape(B, kvh, rep, W, dh).astype(jnp.float32)
-    scores = _scaled_scores(jnp.einsum("bgrwd,bgsd->bgrws", qg, kf), c)
+    scores = jnp.einsum("bgrwd,bgsd->bgrws", qg, kf)
+    if c.kv_lora_rank:
+        scores = scores * _score_scale(c)
+    else:
+        scores = _scaled_scores(scores, c)
     # row (b, w) sees cache positions s <= pos0_b + w (and within
     # the sliding window when configured)
     visible = (
@@ -1229,7 +1656,7 @@ def _attend_paged(q, c_layer, block_table, positions, config: TransformerConfig)
     scores = jnp.where(visible[:, None, None, :, :], scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
     attn = jnp.einsum("bgrws,bgsd->bgrwd", weights, vf)
-    return attn.transpose(0, 3, 1, 2, 4).reshape(B, W, nh * dh)
+    return attn.transpose(0, 3, 1, 2, 4).reshape(B, W, nh * vf.shape[-1])
 
 
 def _put_layer(stacked: dict, new: dict, index) -> dict:
@@ -1251,7 +1678,8 @@ def _decode_layers(params, h, cache, config: TransformerConfig, attention_layer)
     (``attention_layer(h, layer, cache, index)``: the K/V pages of its
     attention layer; here, the rows' state of its mamba layer), so the pool
     is never a scan input or output and the donated buffer is the one the
-    loop works on."""
+    loop works on. Leading dense layers run before the scan, on the first
+    layers of the pool."""
     from bee_code_interpreter_tpu.models.mamba import mixer_step
 
     c = config
@@ -1264,16 +1692,22 @@ def _decode_layers(params, h, cache, config: TransformerConfig, attention_layer)
             )
             if kind == "mamba":
                 mix, ssm, conv = mixer_step(
-                    rms_norm(h, layer["ln1"]), layer, c,
+                    rms_norm(h, layer["ln1"], c.rms_norm_eps), layer, c,
                     _take_layer(cache["ssm"], of_kind),
                     _take_layer(cache["conv"], of_kind),
                 )
                 h, _ = _mlp_residual(_residual(h, mix, c), layer, c)
                 cache = _put_layer(cache, {"ssm": ssm, "conv": conv}, of_kind)
             else:
-                h, cache = attention_layer(h, layer, cache, of_kind)
+                h, cache = attention_layer(
+                    h, layer, cache, c.n_dense_layers + of_kind
+                )
         return (h, cache), None
 
+    for i in range(c.n_dense_layers):
+        h, cache = attention_layer(
+            h, _take_layer(params["dense_layers"], i), cache, i
+        )
     (h, cache), _ = lax.scan(
         period_step, (h, cache), jnp.arange(_n_periods(c))
     )

@@ -95,7 +95,10 @@ def reads_pages_in_place(
     slides, the head fills the lane tile and, under a mesh, the KV heads
     divide over tp; ``paged_append``, ``paged_read`` and the einsums on the
     layer's slice otherwise."""
-    kvh, _, dh = c_layer["k"].shape[-3:]
+    if "ckv" in c_layer:  # a latent: one KV head of the slot's width
+        kvh, dh = 1, c_layer["ckv"].shape[-1]
+    else:
+        kvh, _, dh = c_layer["k"].shape[-3:]
     tp = 1 if mesh is None else dict(mesh.shape).get("tp", 1)
     return (
         on_tpu()
@@ -115,46 +118,48 @@ def _kernel(
     *refs,         # see ``paged_decode_attention``: the form with a write
                    # has the new token's K/V before the pool and the pool
                    # again, aliased, among the outputs
-    sm_scale: float, writes: bool,
+    sm_scale: float, writes: bool, v_width: int,
 ):
+    # ``leaves`` K/V leaves: two, or ONE whose slots are keys whole and
+    # values in their first ``v_width`` (a latent)
+    leaves = 1 if v_width else 2
     if writes:
-        (k_new_ref, v_new_ref,    # VMEM [1, kvh, 1, dh]: the row's new token
-         _, _,                    # the pool as an input: donated to k_hbm/v_hbm
-         o_ref, k_hbm, v_hbm,     # the pool as an output: read AND written
-         k_buf, v_buf, sems, write_sems) = refs
+        new_refs = refs[:leaves]  # VMEM [1, kvh, 1, dh]: the row's new token
+        # (the pool as an input follows: donated to the outputs)
+        o_ref = refs[2 * leaves]
+        hbm = refs[2 * leaves + 1:3 * leaves + 1]  # as an output: read AND written
+        bufs = refs[3 * leaves + 1:4 * leaves + 1]
+        sems, write_sems = refs[4 * leaves + 1:]
     else:
-        k_hbm, v_hbm, o_ref, k_buf, v_buf, sems = refs
-    # k_hbm / v_hbm: HBM [layers, n_pages, kvh, ps, dh], the leaf as it lies
-    # o_ref: VMEM [1, kvh, rep_p, dh]
-    # k_buf / v_buf: VMEM [2, ppb, kvh, ps, dh], two blocks of pages
+        hbm, o_ref = refs[:leaves], refs[leaves]
+        bufs, (sems,) = refs[leaves + 1:2 * leaves + 1], refs[2 * leaves + 1:]
+    # hbm: HBM [layers, n_pages, kvh, ps, dh], each leaf as it lies
+    # o_ref: VMEM [1, kvh, rep_p, dv]
+    # bufs: VMEM [2, ppb, kvh, ps, dh] each, two blocks of pages
     # sems: DMA semaphores [2 (k, v), 2 (buffer)]; write_sems [2 (k, v)]
     b = pl.program_id(0)
     layer = layer_ref[0]
-    _, ppb, kvh, ps, dh = k_buf.shape
-    rep_p = q_ref.shape[2]
+    _, ppb, kvh, ps, dh = bufs[0].shape
+    rep_p, dv = q_ref.shape[2], o_ref.shape[3]
     block_tokens = ppb * ps
     length = len_ref[b]
     n_live = (length + ps - 1) // ps           # pages with a visible slot
     n_blocks = (n_live + ppb - 1) // ppb
 
     def page_copies(page, buf, j):
-        return (
+        return tuple(
             pltpu.make_async_copy(
-                k_hbm.at[layer, page], k_buf.at[buf, j], sems.at[0, buf]
-            ),
-            pltpu.make_async_copy(
-                v_hbm.at[layer, page], v_buf.at[buf, j], sems.at[1, buf]
-            ),
+                x_hbm.at[layer, page], x_buf.at[buf, j], sems.at[i, buf]
+            )
+            for i, (x_hbm, x_buf) in enumerate(zip(hbm, bufs))
         )
 
     def write_backs(page, buf, j):
-        return (
+        return tuple(
             pltpu.make_async_copy(
-                k_buf.at[buf, j], k_hbm.at[layer, page], write_sems.at[0]
-            ),
-            pltpu.make_async_copy(
-                v_buf.at[buf, j], v_hbm.at[layer, page], write_sems.at[1]
-            ),
+                x_buf.at[buf, j], x_hbm.at[layer, page], write_sems.at[i]
+            )
+            for i, (x_hbm, x_buf) in enumerate(zip(hbm, bufs))
         )
 
     def live_in(block):  # pages of this block that hold a visible slot
@@ -169,6 +174,7 @@ def _kernel(
         # a dead page of the boundary block is not copied: its scores are
         # masked, but 0 * whatever VMEM held must still be 0
         def clear(j, carry):
+            v_buf = bufs[-1]
             v_buf[buf, j] = jnp.zeros(v_buf.shape[2:], v_buf.dtype)
             return carry
 
@@ -192,8 +198,8 @@ def _kernel(
         at = length - 1
         j = at // ps % ppb
         here = lax.broadcasted_iota(jnp.int32, (kvh, ps, dh), 1) == at % ps
-        k_buf[buf, j] = jnp.where(here, k_new_ref[0], k_buf[buf, j])
-        v_buf[buf, j] = jnp.where(here, v_new_ref[0], v_buf[buf, j])
+        for new_ref, x_buf in zip(new_refs, bufs):
+            x_buf[buf, j] = jnp.where(here, new_ref[0], x_buf[buf, j])
         for copy in write_backs(bt_ref[b, at // ps], buf, j):
             copy.start()
 
@@ -221,8 +227,10 @@ def _kernel(
         # (rolled, or with the sums in VMEM, a layer took 219-254 us where
         # this takes 188 in mistral7b_chat; my chip runs, PR 30)
         for g, (m_prev, l_prev, acc) in enumerate(carry):
-            k = k_buf[buf, :, g].reshape(block_tokens, dh)
-            v = v_buf[buf, :, g].reshape(block_tokens, dh)
+            k = bufs[0][buf, :, g].reshape(block_tokens, dh)
+            v = k[:, :dv] if v_width else bufs[1][buf, :, g].reshape(
+                block_tokens, dh
+            )
             s = lax.dot_general(
                 q_ref[0, g], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -245,7 +253,7 @@ def _kernel(
         (
             jnp.full((rep_p, 1), NEG_INF, jnp.float32),
             jnp.zeros((rep_p, 1), jnp.float32),
-            jnp.zeros((rep_p, dh), jnp.float32),
+            jnp.zeros((rep_p, dv), jnp.float32),
         )
         for _ in range(kvh)
     )
@@ -264,7 +272,7 @@ def _kernel(
 def paged_decode_attention(
     q: jax.Array,            # [B, nh, dh] — ONE query token per row
     k_pages: jax.Array,      # [layers, n_pages, kvh, ps, dh] — the stacked
-    v_pages: jax.Array,      # pool leaf ([n_pages, kvh, ps, dh]: of one layer)
+    v_pages: jax.Array | None,  # pool leaf ([n_pages, kvh, ps, dh]: of one layer)
     block_table: jax.Array,  # [B, P] int32 logical block -> physical page
     lengths: jax.Array,      # [B] int32 visible length per row (pos + 1)
     sm_scale: float | None = None,
@@ -273,6 +281,7 @@ def paged_decode_attention(
     layer=0,                 # int32 scalar, traced or not: the leaf's layer
     k_new: jax.Array | None = None,  # [B, kvh, dh] — the token at slot
     v_new: jax.Array | None = None,  # ``lengths - 1``, to be written first
+    v_width: int = 0,        # a latent pool: the values' width (see below)
 ):
     """Single-token paged attention over each row's live pages of ``layer``
     (module docstring): [B, nh, dh]. GQA-native: ``nh % kvh == 0``; bf16/f32
@@ -284,6 +293,13 @@ def paged_decode_attention(
     which it must be allowed to overwrite (donated, or a loop's carry), with
     B slots changed. A row of length 0 writes nothing.
 
+    A LATENT pool (``v_pages`` None, ``v_width`` > 0; latent attention's
+    absorbed form) is ONE stacked leaf ``k_pages`` [layers, n_pages, ps, dh]
+    with one KV head: a slot is a key whole and a value in its first
+    ``v_width``, so a page is copied once and serves both products. The
+    result is [B, nh, v_width], with ``k_new`` [B, 1, dh]: ``(attention,
+    k_pages)``.
+
     Under ``mesh`` each device runs the kernel over its own KV heads in
     ``shard_map`` (GSPMD cannot partition a ``pallas_call``): the kvh axis
     of the pool leaf over tp, as ``ContinuousBatcher._pool_sharding`` lays
@@ -291,6 +307,17 @@ def paged_decode_attention(
     group-major. Heads are independent, so there is no collective; every
     other axis sees replicas."""
     writes = k_new is not None
+    if v_pages is None:
+        if not v_width or mesh is not None:
+            raise ValueError(
+                "one leaf for keys and values is a latent pool: it needs "
+                "v_width and takes no mesh (one KV head)"
+            )
+        out = _paged_decode(
+            q, (k_pages[:, :, None],), block_table, lengths, sm_scale,
+            interpret, layer, (k_new,) if writes else (), v_width,
+        )
+        return (out[0], out[1][:, :, 0]) if writes else out
     if k_pages.ndim == 4:  # one layer's slice is a stack of one layer
         out = paged_decode_attention(
             q, k_pages[None], v_pages[None], block_table, lengths, sm_scale,
@@ -317,13 +344,29 @@ def paged_decode_attention(
             q, k_pages, v_pages, block_table, lengths,
             jnp.asarray(layer, jnp.int32), *((k_new, v_new) if writes else ()),
         )
+    return _paged_decode(
+        q, (k_pages, v_pages), block_table, lengths, sm_scale, interpret,
+        layer, (k_new, v_new) if writes else (), 0,
+    )
+
+
+def _paged_decode(
+    q, pages, block_table, lengths, sm_scale, interpret, layer, new, v_width
+):
+    """The ``pallas_call`` on one device: ``pages`` the K and V leaves
+    [layers, n_pages, kvh, ps, dh], or the one leaf of a latent pool
+    (``v_width``); ``new`` the new token for each leaf, or nothing. Gives
+    the attention, then the leaves where there was a write."""
+    writes = bool(new)
     B, nh, dh = q.shape
-    _, _, kvh, ps, _ = k_pages.shape
+    _, _, kvh, ps, _ = pages[0].shape
+    dv = v_width or dh
+    dtype = pages[0].dtype
     if nh % kvh:
         raise ValueError(f"n_heads {nh} not a multiple of kv_heads {kvh}")
     rep = nh // kvh
     # query rows padded to the sublane tile of the pool's dtype
-    sublanes = 32 // k_pages.dtype.itemsize
+    sublanes = 32 // dtype.itemsize
     rep_p = -(-rep // sublanes) * sublanes
     if sm_scale is None:
         sm_scale = dh ** -0.5
@@ -332,7 +375,7 @@ def paged_decode_attention(
     ppb = max(1, min(PAGE_BLOCK_TOKENS // ps, block_table.shape[1]))
 
     # group-major view [B, kvh, rep, dh], zero-padded to rep_p rows
-    qg = q.reshape(B, kvh, rep, dh).astype(k_pages.dtype)
+    qg = q.reshape(B, kvh, rep, dh).astype(dtype)
     if rep_p != rep:
         qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rep_p - rep), (0, 0)))
 
@@ -340,35 +383,34 @@ def paged_decode_attention(
         return pl.BlockSpec((1,) + block, lambda b, *_: (b,) + (0,) * len(block))
 
     q_spec, new_spec = by_row(kvh, rep_p, dh), by_row(kvh, 1, dh)
+    o_spec = by_row(kvh, rep_p, dv)
     in_place = pl.BlockSpec(memory_space=pl.ANY)
-    o_shape = jax.ShapeDtypeStruct((B, kvh, rep_p, dh), q.dtype)
-    new = tuple(
-        x.astype(k_pages.dtype).reshape(B, kvh, 1, dh) for x in (k_new, v_new)
-    ) if writes else ()
+    o_shape = jax.ShapeDtypeStruct((B, kvh, rep_p, dv), q.dtype)
+    new = tuple(x.astype(dtype).reshape(B, kvh, 1, dh) for x in new)
     n_prefetch = 3  # block table, lengths, layer
+    n = len(pages)
     out = pl.pallas_call(
-        functools.partial(_kernel, sm_scale=float(sm_scale), writes=writes),
+        functools.partial(
+            _kernel, sm_scale=float(sm_scale), writes=writes, v_width=v_width
+        ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=n_prefetch,
             grid=(B,),
-            in_specs=[q_spec] + [new_spec] * len(new) + [in_place] * 2,
-            out_specs=(q_spec, in_place, in_place) if writes else q_spec,
+            in_specs=[q_spec] + [new_spec] * len(new) + [in_place] * n,
+            out_specs=(o_spec,) + (in_place,) * n if writes else o_spec,
             scratch_shapes=[
-                pltpu.VMEM((2, ppb, kvh, ps, dh), k_pages.dtype),
-                pltpu.VMEM((2, ppb, kvh, ps, dh), v_pages.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),
-            ] + [pltpu.SemaphoreType.DMA((2,))] * writes,
+                pltpu.VMEM((2, ppb, kvh, ps, dh), dtype) for _ in pages
+            ] + [pltpu.SemaphoreType.DMA((2, 2))]
+            + [pltpu.SemaphoreType.DMA((2,))] * writes,
         ),
-        out_shape=(
-            o_shape,
-            jax.ShapeDtypeStruct(k_pages.shape, k_pages.dtype),
-            jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype),
+        out_shape=(o_shape,) + tuple(
+            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in pages
         ) if writes else o_shape,
         # the pool operands ARE the pool results (operands count from the
-        # scalar-prefetched three)
-        input_output_aliases=(
-            {n_prefetch + 3: 1, n_prefetch + 4: 2} if writes else {}
-        ),
+        # scalar-prefetched three, then q and the new token's leaves)
+        input_output_aliases={
+            n_prefetch + 1 + n + i: 1 + i for i in range(n)
+        } if writes else {},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ) if not interpret else None,
@@ -376,8 +418,8 @@ def paged_decode_attention(
         name="paged_decode_attention",  # the trace's ``XLA Ops`` line shows it
     )(
         block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1), qg, *new, k_pages, v_pages,
+        jnp.asarray(layer, jnp.int32).reshape(1), qg, *new, *pages,
     )
     if not writes:
-        return out[:, :, :rep].reshape(B, nh, dh)
-    return out[0][:, :, :rep].reshape(B, nh, dh), out[1], out[2]
+        return out[:, :, :rep].reshape(B, nh, dv)
+    return (out[0][:, :, :rep].reshape(B, nh, dv),) + tuple(out[1:])

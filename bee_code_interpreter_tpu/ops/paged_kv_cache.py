@@ -103,12 +103,28 @@ def pool_telemetry(
     }
 
 
+def paged_page_size(cache: dict) -> int:
+    """Slots a page of the pool holds."""
+    if "ckv" in cache:
+        return cache["ckv"].shape[2]
+    return cache["k"].shape[3]
+
+
+def pages_leaf(cache: dict):
+    """A leaf of the pool that holds pages (for where the pool lives)."""
+    return cache["ckv"] if "ckv" in cache else cache["k"]
+
+
 def alloc_paged_cache(
     config, n_pages: int, page_size: int, sharding=None,
     max_batch: int | None = None,
 ) -> dict:
-    """Zeroed page pool: k/v [attention layers, n_pages, kvh, page_size, dh]
-    and, for a configuration with mamba layers, what those keep by ROW of
+    """Zeroed page pool: k/v [attention layers, n_pages, kvh, page_size, dh],
+    or for latent attention (``config.kv_lora_rank``) ONE leaf ``ckv``
+    [layers, n_pages, page_size, latent_width], a token's normed latent and
+    shared rotary key side by side (keys and values both: the values are
+    the first ``kv_lora_rank`` of a slot), and, for a configuration with
+    mamba layers, what those keep by ROW of
     the batch beside it (``models/mamba.alloc_state``: ``ssm`` and ``conv``
     over [mamba layers, max_batch, ...]). One tree holds everything a
     request keeps on the device between steps, and the decode program
@@ -144,6 +160,15 @@ def alloc_paged_cache(
             return jnp.zeros(shape, dtype)
         return jax.device_put(np.zeros(shape, dtype), sharding)
 
+    if c.kv_lora_rank:
+        if sharding is not None:
+            raise NotImplementedError(
+                "a latent cache has one KV head and is not sharded: no mesh "
+                "over latent attention"
+            )
+        return {"ckv": zeros(
+            (c.n_attention_layers, n_pages, page_size, c.latent_width), c.dtype
+        )}
     if c.kv_cache_dtype == "int8":
         pool = {
             "k": zeros(shape, jnp.int8),
@@ -187,7 +212,13 @@ def paged_append(
     decode equals contiguous int8 decode (and a window append is
     bit-identical to W single appends, which keeps paged speculative
     verify exact).
+
+    A latent layer's slice (``ckv`` [n_pages, ps, width]) takes ``k_new``
+    [B, W, width], the latent beside the rotary key, and no ``v_new``.
     """
+    if "ckv" in c_layer:
+        leaf = c_layer["ckv"]
+        return {"ckv": leaf.at[page_idx, slot_idx, :].set(k_new.astype(leaf.dtype))}
     if "k_s" in c_layer:
         kq, ks = quantize(k_new)  # [B, W, kvh, dh] -> values + [B, W, kvh, 1]
         vq, vs = quantize(v_new)
@@ -212,12 +243,19 @@ def paged_read(
     c_layer: dict,  # [n_pages, kvh, ps, dh]
     block_table: jax.Array,  # [B, P] int32 logical block -> physical page
     dtype,  # V compute dtype — required, matching cache_read's contract
+    v_width: int = 0,  # a latent layer: the values are so many of a slot's first
 ) -> tuple[jax.Array, jax.Array]:
     """Gather each row's pages into the contiguous [B, kvh, P·ps, dh] view
     the attention einsums consume. K comes back f32 (scores operand), V in
     ``dtype`` — the same contract as ops/kv_cache.cache_read; int8 pools
-    dequantize after the gather (scales gathered alongside)."""
+    dequantize after the gather (scales gathered alongside). A latent
+    layer's slice comes back as one KV head: keys the whole slot, values
+    its first ``v_width``."""
     B, P = block_table.shape
+    if "ckv" in c_layer:
+        ps, width = c_layer["ckv"].shape[1:]
+        g = c_layer["ckv"][block_table].reshape(B, 1, P * ps, width)
+        return g.astype(jnp.float32), g[..., :v_width].astype(dtype)
     n_pages, kvh, ps, dh = c_layer["k"].shape
 
     def view(x, out_dtype):
@@ -242,7 +280,7 @@ def seed_prefill(
     cache: dict,  # full pool: leaves [n_layers, n_pages, ...]
     pages: jax.Array,  # [P] int32 physical pages covering ceil(L/ps)
     k_pre: jax.Array,  # [n_layers, kvh, L, dh] — one sequence's prefill K
-    v_pre: jax.Array,
+    v_pre: jax.Array | None = None,  # (a latent pool: [n_layers, L, width] alone)
 ) -> dict:
     """Write one sequence's prefill K/V into its pages — ONE batched
     scatter per pool leaf; the single copy of the prefill-seeding logic
@@ -250,13 +288,18 @@ def seed_prefill(
     this, so the tested path IS the served path). int8 pools quantize per
     (token, head) row, identical to cache_append's semantics; the pad tail
     quantizes to scale-0 exact zeros and stays masked by ``s <= pos``."""
-    ps = cache["k"].shape[3]
+    ps = paged_page_size(cache)
     n_pages_used = int(pages.shape[0])
-    L = k_pre.shape[2]
+    L = k_pre.shape[-2]
     if L > n_pages_used * ps:
         raise ValueError(
             f"prefill length {L} exceeds {n_pages_used} pages of {ps}"
         )
+    if "ckv" in cache:
+        leaf = cache["ckv"]
+        vals = jnp.pad(k_pre, ((0, 0), (0, n_pages_used * ps - L), (0, 0)))
+        vals = vals.reshape(leaf.shape[0], n_pages_used, ps, leaf.shape[-1])
+        return {**cache, "ckv": leaf.at[:, pages].set(vals.astype(leaf.dtype))}
 
     def page_view(x):  # [n_layers, kvh, L, dh] -> [n_layers, P, kvh, ps, dh]
         x = jnp.pad(

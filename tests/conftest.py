@@ -157,8 +157,8 @@ def _bound_xla_jit_accumulation():
     jax.clear_caches()
 
 
-# A case of the benchmark's frozen tests that asks, in its own words, to be
-# retired: it asserts that ``TransformerConfig`` has NO ``tie_embeddings``
+# Cases of the benchmark's frozen tests that ask, in their own words, to be
+# retired. The first: it asserts that ``TransformerConfig`` has NO ``tie_embeddings``
 # field ("the program has the field now: retire this case"). PR 29 brought
 # the field, which a tied-head configuration's file needs
 # (``benchmarks/lib/harness.py``: a published ``tie_word_embeddings: true``
@@ -174,6 +174,14 @@ RETIRED_CASES = {
     "test_what_the_program_fixes_is_refused_until_it_has_the_field"
     "[tie_word_embeddings-tie_embeddings-True-False-untied embeddings only]":
         "TransformerConfig has tie_embeddings since PR 29; the case asks to "
+        "be retired and its file is frozen outside a benchmark PR",
+    # PR 33 brought ``rms_norm_eps`` (sarvam-105b publishes 1e-6); its second
+    # half is held in ``tests/benchmark/test_benchmark_sarvam.py``
+    # ``test_an_epsilon_is_accepted_where_the_group_states_it_and_refused_elsewhere``
+    "tests/benchmark/test_benchmark_counts.py::"
+    "test_what_the_program_fixes_is_refused_until_it_has_the_field"
+    "[rms_norm_eps-rms_norm_eps-1e-06-1e-05-rms_norm_eps 1e-06 is not the 1e-05]":
+        "TransformerConfig has rms_norm_eps since PR 33; the case asks to "
         "be retired and its file is frozen outside a benchmark PR",
 }
 

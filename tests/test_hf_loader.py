@@ -93,9 +93,10 @@ def test_config_mapping_and_refusals():
     assert (config.d_model, config.n_layers, config.n_heads,
             config.kv_heads, config.ff_dim) == (64, 2, 4, 2, 128)
     bad_eps = dataclasses.replace  # noqa: F841 (readability anchor)
-    cfg = transformers.LlamaConfig(rms_norm_eps=1e-6)
-    with pytest.raises(ValueError, match="rms_norm_eps"):
-        config_from_hf(cfg)
+    # every norm's epsilon is a field of the config since PR 33
+    assert config_from_hf(
+        transformers.LlamaConfig(rms_norm_eps=1e-6)
+    ).rms_norm_eps == 1e-6
     cfg = transformers.LlamaConfig(rms_norm_eps=1e-5, attention_bias=True)
     with pytest.raises(ValueError, match="attention_bias"):
         config_from_hf(cfg)

@@ -548,15 +548,12 @@ def test_serve_spans_nest_and_carry_their_stats(monkeypatch, mix):
     assert names[10:16] == ["serve.step"] + [
         f"serve.step.{phase}" for phase in TOP_PHASES
     ]
-    # all greedy and no logprobs: the [B] argmax ids cross, no logits row
-    ids, rows = 4 * 2, 4 * 2 * CFG.vocab_size
-    # mixed: the argmax ids, the picked ids and (logprobs) the logits rows
-    # with their [B] float32 normalisers; steered: the argmax ids and the
-    # rows the host picks from
+    # one [3, B] int32 array a step, whatever the rows (row_answers: the
+    # token, its logit and the row's normaliser); steered: the rows the
+    # host picks from beside it
+    answers, rows = 3 * 4 * 2, 4 * 2 * CFG.vocab_size
     pulls = {s["bytes"] for name, s, _ in spans if name == "serve.step.pull"}
-    assert pulls == {
-        {"greedy": ids, "mixed": 3 * ids + rows, "steered": ids + rows}[mix]
-    }
+    assert pulls == {answers + (rows if mix == "steered" else 0)}
     picked = {
         (s["device_picked_rows"], s["host_picked_rows"])
         for name, s, _ in spans if name == "serve.step.sample"
@@ -566,6 +563,49 @@ def test_serve_spans_nest_and_carry_their_stats(monkeypatch, mix):
     }
 
 
+@pytest.mark.parametrize("rows", ["greedy", "sampled", "both"])
+def test_rows_the_device_chose_for_cross_as_one_small_array(rows):
+    # row_answers: each row's token (the pick's where it samples, else the
+    # argmax), its logit there, the same float32 the host would have read
+    # from the row, and its normaliser, in ONE program and one [3, B] array
+    # whichever rows the batch holds
+    from bee_code_interpreter_tpu.models.serving import (
+        log_normalizers,
+        row_answers,
+    )
+
+    logits = jax.random.normal(jax.random.PRNGKey(3), (4, 1, 97), jnp.float32)
+    picked = jnp.asarray([5, 96, 0, 41], jnp.int32)
+    sampled = np.array([False, True, True, False])
+    token, logit, log_z = np.asarray(row_answers(logits, picked, sampled))
+    last = np.asarray(logits)[:, 0]
+    assert token.tolist() == np.where(sampled, picked, last.argmax(-1)).tolist()
+    assert logit.view(np.float32).tolist() == last[np.arange(4), token].tolist()
+    assert log_z.view(np.float32).tolist() == np.asarray(
+        log_normalizers(logits[:, 0])
+    ).tolist()
+
+    kinds = {
+        "greedy": [SamplingParams(logprobs=True)] * 2,
+        "sampled": [SAMPLED, dataclasses.replace(SAMPLED, seed=4)],
+        "both": [SamplingParams(logprobs=True), SAMPLED],
+    }[rows]
+    batcher = ContinuousBatcher(
+        PARAMS, CFG, max_batch=2, n_pages=32, page_size=4, max_pages_per_seq=8,
+    )
+    # the function's jit cache is one for every batcher of the process
+    before = batcher._row_answers._cache_size()
+    reqs = [batcher.submit(SHORT, 5, sampling=sp) for sp in kinds]
+    batcher.run_to_completion()
+    assert batcher._row_answers._cache_size() - before <= 1
+    for r in reqs:
+        out = batcher.result(r)
+        full = T.forward(PARAMS, jnp.asarray(SHORT + out)[None, :], CFG)[0]
+        logp = np.asarray(jax.nn.log_softmax(full.astype(jnp.float32), axis=-1))
+        want = [logp[len(SHORT) - 1 + j, t] for j, t in enumerate(out)]
+        np.testing.assert_allclose(batcher.result_logprobs(r), want, atol=2e-4)
+
+
 TRACKED = {
     "_decode": "decode_step_paged", "_prefill": "prefill_forward",
     "_window": "decode_window_paged",
@@ -573,6 +613,7 @@ TRACKED = {
     "_draft_prefill": "draft_prefill_forward",
     "_draft_window": "draft_decode_window_paged",
     "_pick": "pick_tokens",
+    "_row_answers": "row_answers",
 }
 
 
