@@ -288,12 +288,25 @@ class Engine:
 
     # --------------------------------------------------------------- step
     def step(self) -> None:
-        """Admit whatever fits, then advance the batch one round."""
+        """Admit whatever fits, then advance the batch one round.
+
+        The batcher's plain step runs one step ahead of the host
+        (``ContinuousBatcher.step``): this call returns with the step it
+        dispatched still in flight on the device, having landed the one
+        before, so the tokens that step picks are read (``new_tokens``,
+        ``partial_result``, ``is_done``) after the NEXT ``step``. An
+        admission lands the step in flight before it takes its row, so a
+        request's first token is readable when the ``step`` that admitted
+        it returns, as before. Rows and pages a step frees are seen by the
+        admission after this call's, or by the next call's."""
         self._admit_ready()
         self.batcher.step()
         self._admit_ready()  # rows/pages freed by retirements this step
 
     def run_to_completion(self, max_steps: int = 100_000) -> None:
+        """``step`` until nothing is queued and the batcher is idle, which
+        it is not while a step is in flight: every token is landed at the
+        end."""
         for _ in range(max_steps):
             if not self._queued and not self.batcher.busy:
                 return
@@ -380,8 +393,13 @@ class Engine:
     def new_tokens(self, ticket: int) -> list[int]:
         """STREAMING read: tokens appended for this ticket since the last
         ``new_tokens`` call (empty while queued). Poll between steps to
-        stream a response; the final chunk lands no later than the step
-        that finishes the request. While the request is live, the last
+        stream a response: a poll reads what has LANDED, which after a
+        ``step`` is everything but the token of the step that call left in
+        flight (one token a poll in the steady state, each a step after the
+        device picked it); the final chunk lands with the ``step`` that
+        finishes the request (``is_done`` turns true in the same call), or
+        with whatever lands the step in flight sooner (``cancel``,
+        ``state_dict``, an admission). While the request is live, the last
         (max stop length - 1) tokens are held back so a stop sequence
         completing later can never trim a token the stream already
         emitted — the stream's concatenation always equals ``result``."""
@@ -431,7 +449,8 @@ class Engine:
 
     def cancel(self, ticket: int) -> None:
         """Cancel queued (never touches the device) or admitted (pages
-        freed mid-decode) work; racing completion is a no-op."""
+        freed mid-decode) work; racing completion is a no-op, and the step
+        in flight, landed first, may be what completes it."""
         rid = self._rid(ticket)
         if rid == "queued":
             self._queued.discard(ticket)  # heap entry skipped lazily

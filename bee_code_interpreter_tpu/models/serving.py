@@ -18,6 +18,12 @@ TPU-first split of responsibilities:
 - **Host**: integer bookkeeping only — the free-page stack, block tables,
   row admission/retirement. Mutating a block table or recycling pages is
   numpy work between steps, never a re-trace.
+- **One step apart**: the plain step's feedback edge (the picked token is
+  the next step's input, the position one on) stays on the device, so the
+  host dispatches step k before it has pulled step k - 1's answers and
+  does its bookkeeping for one step while the device runs the next
+  (``ContinuousBatcher.step``). The block table, the pick settings and the
+  row masks are the host's and go up only when it changed them.
 
 Greedy decoding matches ``Transformer.generate_cached`` token-for-token
 per request (pinned by tests/test_serving.py) — batching other requests
@@ -204,7 +210,11 @@ def row_answers(
     logits: jax.Array,  # [B, 1, V] — the decode step's, as it leaves them
     picked: jax.Array,  # [B] int32: ``pick_tokens``' ids
     sampled: jax.Array,  # [B] bool: the rows whose token is ``picked``'s
-) -> jax.Array:
+    current: jax.Array,  # [B, 1] int32: the tokens the step was given
+    pos: jax.Array,  # [B] int32: the slots it wrote them at
+    stepping: jax.Array,  # [B] bool: the rows the step ran for
+    last_slot: int,  # the block table's last slot (static)
+) -> tuple[jax.Array, jax.Array, jax.Array]:
     """All that the host reads of a plain step's logits when the device
     chooses the tokens, as ONE array [3, B] int32: each row's token
     (``picked``'s where it samples, else the argmax, first of the
@@ -216,17 +226,28 @@ def row_answers(
     logits): the part of a step that followed the host's load from run
     to run (PERF.md, PR 33). The logit is a masked sum over the
     vocabulary, which has one term and is exact; it splits over a
-    vocabulary sharded under a mesh as a gather by index would not."""
+    vocabulary sharded under a mesh as a gather by index would not.
+
+    Beside it, what the NEXT step is given, left on the device: each
+    ``stepping`` row's token as its ``current`` and its ``pos`` one slot
+    on (never past the table's last), every other row's as they were. The
+    loop's feedback edge does not cross to the host, so the next step can
+    be called before this one's answers are pulled (PERF.md, PR 34)."""
     last = logits[:, -1, :].astype(jnp.float32)
     token = jnp.where(sampled, picked, jnp.argmax(last, axis=-1))
     token = token.astype(jnp.int32)
     ids = lax.broadcasted_iota(jnp.int32, last.shape, 1)
     logit = jnp.sum(jnp.where(ids == token[:, None], last, 0.0), axis=-1)
-    return jnp.stack([
+    answers = jnp.stack([
         token,
         lax.bitcast_convert_type(logit, jnp.int32),
         lax.bitcast_convert_type(log_normalizers(last), jnp.int32),
     ])
+    return (
+        answers,
+        jnp.where(stepping[:, None], token[:, None], current),
+        jnp.where(stepping, jnp.minimum(pos + 1, last_slot), pos),
+    )
 
 
 def filtered_probs_host(
@@ -828,8 +849,24 @@ class ContinuousBatcher:
             "pick_tokens",
         )
         self._log_normalizers = self._track(log_normalizers, "log_normalizers")
-        self._row_answers = self._track(row_answers, "row_answers")
+        # Under a mesh the plain step's small operands live whole on every
+        # device, under a sharding named once (``_upload``), and
+        # ``row_answers`` leaves the next step's ``current`` and ``pos``
+        # under the same.
+        self._replicated = replicated
+        self._row_answers = self._track(
+            functools.partial(row_answers, last_slot=self.max_len - 1),
+            "row_answers",
+            **({} if mesh is None else {"out_shardings": (replicated,) * 3}),
+        )
         self._no_picks = jnp.zeros(max_batch, jnp.int32)
+        # The plain step that has been dispatched and not landed (its
+        # answers not pulled): ``_dispatch``'s record, or None. At most one,
+        # and only the plain step leaves one (``step``).
+        self._in_flight: dict | None = None
+        # name -> (the host's copy, the device's) of the plain step's
+        # host-owned operands as last uploaded (``_operand``)
+        self._resident: dict[str, tuple[np.ndarray, jax.Array]] = {}
         if draft_config is not None:
             # the draft's own paged pool, addressed by the SAME block
             # tables/pages (one allocation covers both models' K/V)
@@ -886,6 +923,11 @@ class ContinuousBatcher:
         self._device_picked = 0
         self._host_picked = 0
         self._n_steps = 0
+        # plain steps dispatched before / after the step before them was
+        # landed, and the tokens of rows that ran one step past their end
+        self._steps_ahead = 0
+        self._steps_synchronous = 0
+        self._discarded_tokens = 0
         # the step being recorded's phase -> ms (see _phase); a dict only
         # inside a step with a lifecycle monitor attached, None otherwise
         self._phase_ms: dict[str, float] | None = None
@@ -1193,6 +1235,7 @@ class ContinuousBatcher:
         """
         import copy
 
+        self._land()  # the pool and the lists at one point of the sequence
         # copy=True: the decode jits DONATE the pool buffer, so a zero-copy
         # view (np.asarray can return one on CPU) would alias memory the
         # very next step() invalidates — the periodic-checkpoint pattern
@@ -1215,6 +1258,7 @@ class ContinuousBatcher:
         logprobs, same page accounting."""
         import copy
 
+        self._land()  # what was in flight is this batcher's, not the snapshot's
         meta = state["meta"]
         mine = self._geometry()
         if set(meta) != set(mine):
@@ -1300,9 +1344,13 @@ class ContinuousBatcher:
 
     @property
     def busy(self) -> bool:
-        """Rows decoding OR admissions still interleaving — the loop-until
-        condition for ``run_to_completion`` at every layer."""
-        return bool(self.active.any()) or bool(self.prefill_state)
+        """Rows decoding, admissions still interleaving OR a step in
+        flight whose tokens are not landed yet — the loop-until condition
+        for ``run_to_completion`` at every layer."""
+        return (
+            bool(self.active.any()) or bool(self.prefill_state)
+            or self._in_flight is not None
+        )
 
     def validate_request(
         self,
@@ -1438,7 +1486,9 @@ class ContinuousBatcher:
 
         On either drive the row's block-table entry stays on the scratch
         page until activation (decode steps cannot touch the half-written
-        pages).
+        pages). A plain step in flight (``step``) is landed before the row
+        is activated: its tokens are readable when ``submit`` returns, and
+        what it frees counts toward this request's row and pages.
 
         ``adapter`` serves this request under the i-th LoRA adapter the
         batcher was constructed with (None = the base model)."""
@@ -1449,6 +1499,19 @@ class ContinuousBatcher:
             interleave_admission=interleave_admission,
             prefill_chunk=prefill_chunk,
         )
+        # An admission changes rows, so the step in flight lands before
+        # this one is activated: but AFTER its prefill has been queued
+        # behind that step (``_one_shot``), where the row and the pages are
+        # free already by what has landed. A step in flight can only free
+        # more, so it is landed here only where they are not. What it frees
+        # may go to this admission at once: the admission's programs run
+        # after it on the device, so the write of a row that ran one step
+        # past its end lands before they seed the pages it gave back.
+        if not (
+            self.has_free_row()
+            and n_need <= len(self.free_pages) + len(self.evictable)
+        ):
+            self._land()
         L = int(prompt.shape[0])
         # internal index: 0 is the all-zeros base adapter in the bank
         adapter_internal = 0 if adapter is None else adapter + 1
@@ -1561,6 +1624,8 @@ class ContinuousBatcher:
             pages=len(rec["pages"]),
             **self._held_pairs_stat(int(rec["prompt"].shape[0])),
         ), self._admission(row, rec, propagate=True):
+            if rec["width"] is not None:
+                self._land()  # the one-shot program lands it further on
             while not self._admit_next(row, rec):
                 pass
             with self._phase("serve.admit.activate"):
@@ -1850,6 +1915,12 @@ class ContinuousBatcher:
                 self.cache = self._seed_state(
                     self.cache, np.int32(row), ssm, conv
                 )
+        # The step in flight lands HERE, with the prefill and the seeding
+        # queued behind it: the device goes from that step into the prefill
+        # while the host is woken, pulls the step's answers and retires its
+        # rows, where it would idle through all of that and the prefill's
+        # dispatch (4-6 ms an admission on a v5e; PERF.md, PR 34).
+        self._land()
         # waits for the device (prefill and seeding), then copies
         with self._phase("serve.admit.pull"):
             last = self._pull_last_row(logits[0, L - 1, :], logprobs)
@@ -1863,14 +1934,20 @@ class ContinuousBatcher:
         return last
 
     # ------------------------------------------------------------ multi-LoRA
-    def _lora_kwargs(self, adapter_rows: np.ndarray) -> dict:
+    def _lora_kwargs(self, adapter_rows: np.ndarray, resident=False) -> dict:
         """Extra kwargs for the paged decode/window programs when a lora
-        bank is configured; empty (the untouched base path) otherwise."""
+        bank is configured; empty (the untouched base path) otherwise.
+        ``resident``: the rows are the batch's, kept on the device between
+        the plain steps (``_operand``)."""
         if self.lora_bank is None:
             return {}
+        rows = np.asarray(adapter_rows, dtype=np.int32)
         return {
             "lora_bank": self.lora_bank,
-            "adapter_idx": jnp.asarray(adapter_rows, dtype=jnp.int32),
+            "adapter_idx": (
+                self._operand("adapter_idx", rows) if resident
+                else jnp.asarray(rows)
+            ),
         }
 
     # -------------------------------------------------- prefix-cache pages
@@ -1956,34 +2033,61 @@ class ContinuousBatcher:
         mode). Interleaved admissions advance one window first, so their
         prefill and the batch's decode share the step cadence.
 
+        The plain step runs ONE STEP AHEAD of the host. A call dispatches
+        step *k* from what step *k - 1* left on the device (its tokens as
+        the next ``current``, its positions one on: ``row_answers``), THEN
+        lands step *k - 1* (waits for its answers, pulls them, appends
+        tokens and log-probabilities, retires rows), and returns with step
+        *k* in flight: the device works on it while the host lands, returns
+        to its caller and is called again. So the tokens a call's own step
+        picked become readable after the NEXT call, or after anything that
+        drains (``_land``: ``submit``, ``cancel``, ``preempt``, ``release``
+        of a live request, ``state_dict``, ``load_state_dict``); ``busy``
+        stays true while a step is in flight, so ``while busy: step()``
+        ends with every token landed. A row that ends by its budget is left
+        out of the step after its last (the host counts tokens, it need not
+        see them); one that ends by ``eos_id`` or a stop sequence is seen
+        at landing, when the next step has run it once more: that token is
+        thrown away (``discarded_tokens``), and its write went to the row's
+        own page or the scratch page. Results are those of the synchronous
+        step, request by request.
+
+        It engages by what the batcher holds, not by a switch: a step
+        finds a step in flight only if nothing drained since, and a row
+        whose token is chosen on the host (``SamplingParams.steered``) is
+        landed in the call that dispatched it, because the next step needs
+        its choice; speculative steps and interleaved windows land first
+        and leave nothing in flight.
+
         With a metrics registry configured, each step also observes its
         wall time, the per-row inter-token latency (step time scaled by how
         many tokens each row committed — one in plain mode, the accept
         length in speculative mode), and the throughput window the
         tokens-per-second gauge reads. With a lifecycle monitor attached,
         each step additionally lands one step record (occupancy, token
-        counts, speculative accepts, page churn, and ``phase_ms``, the
-        step's host time by phase — see docs/observability.md "Serving
-        observability").
+        counts, speculative accepts, page churn, ``ahead`` and
+        ``discarded_tokens``, and ``phase_ms``, the step's host time by
+        phase — see docs/observability.md "Serving observability").
 
         The step is one ``serve.step`` span with the ``serve.step.*``
         phases inside it (``_phase``): nothing while no profiler session
         is open, the host's side of the device trace while one is."""
         self._n_steps += 1
         rows = int(np.count_nonzero(self.active))
+        ahead = self._ahead()
         with jax.profiler.TraceAnnotation(
-            "serve.step", n=self._n_steps, rows=rows
+            "serve.step", n=self._n_steps, rows=rows, ahead=int(ahead)
         ):
             if (
                 self._metrics is None
                 and self._monitor is None
                 and self._device_monitor is None
             ):
-                self._step_inner()
+                self._step_inner(ahead)
             else:
-                self._step_observed(rows)
+                self._step_observed(rows, ahead)
 
-    def _step_observed(self, rows_before: int) -> None:
+    def _step_observed(self, rows_before: int, ahead: bool) -> None:
         """``_step_inner`` under the attached metrics and monitors."""
         prefilling_before = len(self.prefill_state)
         tokens_before = self.n_tokens_generated
@@ -1994,10 +2098,11 @@ class ContinuousBatcher:
         host_picked_before = self._host_picked
         alloc_before = self._pages_allocated
         released_before = self._pages_released
+        discarded_before = self._discarded_tokens
         phase_ms = self._phase_ms = {} if self._monitor is not None else None
         t0 = time.monotonic()
         try:
-            self._step_inner()
+            self._step_inner(ahead)
         finally:
             self._phase_ms = None
         t1 = time.monotonic()
@@ -2028,11 +2133,19 @@ class ContinuousBatcher:
                     # what they leave of duration_ms is unspanned
                     "phase_ms": phase_ms,
                     "mesh": self._mesh_key,
+                    # dispatched before the step before it was landed: its
+                    # decode_tokens are that step's, landed here
+                    "ahead": ahead,
                     "active_rows": rows_before,
                     "active_rows_after": int(np.count_nonzero(self.active)),
                     "prefilling_rows": prefilling_before,
                     "max_batch": int(self.active.shape[0]),
                     "decode_tokens": produced,
+                    # rows that ran one step past an eos or a stop
+                    # sequence: landed here, their token thrown away
+                    "discarded_tokens": (
+                        self._discarded_tokens - discarded_before
+                    ),
                     "prefill_tokens": self._prefill_tokens - prefill_before,
                     **self._held_pairs_stat(
                         produced + self._prefill_tokens - prefill_before
@@ -2056,7 +2169,24 @@ class ContinuousBatcher:
                 }
             )
 
-    def _step_inner(self) -> None:
+    def _ahead(self) -> bool:
+        """Whether the coming step will be dispatched BEFORE the step in
+        flight is landed: there is one (nothing drained since its dispatch,
+        so no active row is steered: such a row's step is landed in its own
+        call), some row goes on after it, and no interleaved admission has
+        a window to run first (a window may activate a row)."""
+        step = self._in_flight
+        return (
+            step is not None
+            and not self.prefill_state
+            and bool((self.active & step["outlives"]).any())
+        )
+
+    def _step_inner(self, ahead: bool) -> None:
+        landing, self._in_flight = self._in_flight, None
+        if landing is not None and not ahead:
+            self._land_step(landing)
+            landing = None
         if self.prefill_state:
             with self._phase("serve.step.prefills"):
                 self._advance_prefills()
@@ -2065,25 +2195,102 @@ class ContinuousBatcher:
         if self.draft_params is not None:
             self._step_speculative()
             return
+        try:
+            step = self._dispatch(landing)
+        except BaseException:
+            self._in_flight = landing  # not landed yet: the next drain's
+            raise
+        if landing is not None:
+            self._land_step(landing)
+        if step["host_rows"]:
+            # a steered row's token is chosen on the host from its logits,
+            # and the next step is given it: seen before anything else runs
+            self._land_step(step)
+        else:
+            self._in_flight = step
+
+    def _upload(self, host: np.ndarray) -> jax.Array:
+        """One small operand of the plain step to the device: the one
+        place the step uploads through. It arrives as ``row_answers``
+        leaves the arrays it feeds to the next step, so that the decode
+        program is ONE compiled program whichever it is given: replicated
+        over the mesh; without one, committed to the pool's device if the
+        pool is (a caller who placed the params: everything the programs
+        return is then), else wherever jax puts an array."""
+        sharding = self._replicated
+        if sharding is None:
+            pages = pages_leaf(self.cache)
+            sharding = pages.sharding if pages.committed else None
+        return jax.device_put(host, sharding)
+
+    def _operand(self, name: str, host: np.ndarray) -> jax.Array:
+        """The device's copy of a HOST-OWNED operand of the plain step (the
+        block table, the pick settings, the row masks, the adapter rows):
+        uploaded when its value is not the one last uploaded under
+        ``name``, which is when an admission or a retirement changed it,
+        and otherwise the array that is there. The comparison is of a few
+        hundred numbers; an upload is a call into the runtime (and under a
+        mesh one ``DevicePutWithSharding`` a device) that the device used
+        to wait out before every step (PERF.md, PR 34)."""
+        held = self._resident.get(name)
+        if held is None or not np.array_equal(held[0], host):
+            kept = host.copy()  # the host goes on writing into its own
+            held = self._resident[name] = (kept, self._upload(kept))
+        return held[1]
+
+    def _dispatch(self, before: dict | None) -> dict:
+        """Queue one plain decode step, and what is picked and read of its
+        logits, on the device; nothing waits. ``before`` is the step that
+        is in flight ahead of it (not landed), or None. Returns the step's
+        record, which ``_land_step`` lands.
+
+        With a step ``before``, ``current`` and ``pos`` are the arrays that
+        step left on the device and the rows are those that outlive it;
+        with none, the host's mirrors are exact and go up whole. Either
+        way a row not stepping is pointed at the scratch page and keeps
+        its ``current`` and ``pos``."""
         with self._phase("serve.step.upload"):
-            current = jnp.asarray(self.current)
-            pos = jnp.asarray(self.pos)
-            block_table = jnp.asarray(self.block_table)
-            lora = self._lora_kwargs(self.row_adapter)
+            if before is None:
+                stepping = self.active.copy()
+                # copies: the host goes on writing into its mirrors
+                current = self._upload(self.current.copy())
+                pos = self._upload(self.pos.copy())
+                self._steps_synchronous += 1
+            else:
+                # rows that ended at the last landing (eos, a stop
+                # sequence) are inactive by now; rows that ``before`` takes
+                # to their budget end when it lands
+                stepping = self.active & before["outlives"]
+                current, pos = before["next"]
+                self._steps_ahead += 1
+            rows = np.flatnonzero(stepping)
+            # a row ends by its budget at a token count the host knows
+            # without seeing the tokens: this step's is one more than the
+            # landed ones and the one in flight
+            outlives = stepping.copy()
+            for row in rows:
+                landed = len(self.results[int(self.row_request[row])])
+                outlives[row] = (
+                    landed + (before is not None) + 1 < self.budget[row]
+                )
+            block_table = self._operand("block_table", np.where(
+                stepping[:, None], self.block_table, _SCRATCH_PAGE
+            ))
+            stepping_dev = self._operand("stepping", stepping)
+            lora = self._lora_kwargs(self.row_adapter, resident=True)
         with self._phase("serve.step.dispatch"):
             logits, self.cache = self._decode(
                 self.params, current, pos, self.cache, block_table, **lora
             )
-            active_rows = np.flatnonzero(self.active)
             # where each row's token is picked, read off its request:
             # steered rows on the host from the row's logits (bias, or a
             # constraint that is Python), other sampling rows by the pick
             # program, the rest by the device's argmax
             host_rows = [
-                row for row in active_rows if self.row_sampling[row].steered
+                row for row in rows if self.row_sampling[row].steered
             ]
             device_rows = [
-                row for row in active_rows
+                row for row in rows
                 if self.row_sampling[row].temperature > 0.0
                 and not self.row_sampling[row].steered
             ]
@@ -2097,44 +2304,82 @@ class ContinuousBatcher:
             if device_rows:
                 # one uniform a row from the request's own generator: its
                 # tokens depend on its seed and on how many it has drawn,
-                # never on its row or its batch-mates
+                # never on its row or its batch-mates. Drawn a step ahead
+                # of the logits it picks from: it depends on no token
                 draw = np.ones(self.active.shape[0], dtype=np.float32)
                 for row in device_rows:
                     draw[row] = 1.0 - self.row_rng[row].random()  # (0, 1]
+                settings = pick_settings(
+                    self.row_sampling, device_rows, self.config.vocab_size
+                )
                 picked = self._pick(
                     logits,
-                    *pick_settings(
-                        self.row_sampling, device_rows, self.config.vocab_size
+                    *(
+                        self._operand(name, setting) for name, setting
+                        in zip(("temperature", "top_k", "top_p"), settings)
                     ),
                     draw,
                 )
             is_picked = np.zeros(self.active.shape[0], dtype=bool)
             is_picked[device_rows] = True
-            answers = self._row_answers(logits, picked, is_picked)
+            answers, *fed = self._row_answers(
+                logits, picked, self._operand("is_picked", is_picked),
+                current, pos, stepping_dev,
+            )
             # the full [max_batch, V] logits cross to the host only when
             # some active row is steered: its token is picked there
             lg = logits[:, -1, :] if host_rows else None
-        with self._phase("serve.step.wait"):
+        return {
+            "rows": rows, "requests": self.row_request[rows],
+            "outlives": outlives, "answers": answers, "logits": lg,
+            "next": tuple(fed), "device_rows": len(device_rows),
+            "host_rows": len(host_rows),
+        }
+
+    def _land(self) -> None:
+        """Land the step in flight, if there is one: the one drain, which
+        whatever changes rows or reads the pool calls before it does. After
+        it every token is in its list, every finished row retired, and the
+        host's ``current`` / ``pos`` mirrors are exact, so the next step
+        uploads them whole."""
+        step, self._in_flight = self._in_flight, None
+        if step is not None:
+            with self._phase("serve.land"):
+                self._land_step(step, under="serve.land")
+
+    def _land_step(self, step: dict, under: str = "serve.step") -> None:
+        """Wait for a dispatched step's answers, pull them, and do the
+        host's part for every row it ran: token, log-probability,
+        retirement. The three phases are spans ``under`` the one they run
+        in: a step's, or a drain's between steps."""
+        answers, lg = step["answers"], step["logits"]
+        with self._phase(f"{under}.wait"):
             jax.block_until_ready((answers, lg))
         with self._phase(
-            "serve.step.pull",
-            bytes=answers.nbytes + (lg.nbytes if host_rows else 0),
+            f"{under}.pull",
+            bytes=answers.nbytes + (0 if lg is None else lg.nbytes),
         ):
             token, logit, log_z = np.asarray(answers, dtype=np.int32)
             logit = logit.view(np.float32)
             log_z = log_z.view(np.float32).astype(np.float64)
-            if host_rows:
+            if lg is not None:
                 lg = np.asarray(lg, dtype=np.float32)
         with self._phase(
-            "serve.step.sample",
-            device_picked_rows=len(device_rows),
-            host_picked_rows=len(host_rows),
+            f"{under}.sample",
+            device_picked_rows=step["device_rows"],
+            host_picked_rows=step["host_rows"],
         ):
             choose = self._clocked(choose_host, "sample_choose")
             logprob = self._clocked(logprob_of, "sample_logprob")
-            for row in active_rows:
+            for row, req_row in zip(step["rows"], step["requests"]):
+                if self.row_request[row] != req_row:
+                    # the row ended at the landing before this one, by eos
+                    # or a stop sequence, after this step had been
+                    # dispatched with it: its extra token is no one's
+                    self._discarded_tokens += 1
+                    continue
+                req_row = int(req_row)
                 sp = self.row_sampling[row]
-                req_row = int(self.row_request[row])
                 if sp.steered:
                     try:
                         nxt = choose(
@@ -2419,6 +2664,11 @@ class ContinuousBatcher:
             "requests_submitted": self._next_request_id,
             "requests_finished": sum(1 for v in self.done.values() if v),
             "tokens_generated": self.n_tokens_generated,
+            # plain steps dispatched before the step before them was
+            # landed / with nothing in flight (after a drain, or beside a
+            # steered row)
+            "steps_ahead": self._steps_ahead,
+            "steps_synchronous": self._steps_synchronous,
             "prefix_cache": dict(self.prefix_stats),
         }
 
@@ -2478,7 +2728,9 @@ class ContinuousBatcher:
         ``finish_reason`` reports 'cancelled'. Cancelling a finished or
         released request is a no-op (the cancel raced completion — the
         caller shouldn't have to care who won); an id the batcher never
-        issued raises KeyError like every other request API."""
+        issued raises KeyError like every other request API. The step in
+        flight lands first: its token is one of those generated so far."""
+        self._land()
         for row in np.flatnonzero(self.active):
             if int(self.row_request[row]) == request_id:
                 self._retire(int(row), "cancelled")
@@ -2501,6 +2753,7 @@ class ContinuousBatcher:
         Returns False once the request has produced a token (decoding),
         finished, or is unknown — callers that need to stop a decoding
         request want :meth:`cancel`, which keeps its partial output."""
+        self._land()
         for row, rec in list(self.prefill_state.items()):
             if rec["req"] == request_id:
                 self._abandon(row, rec)
@@ -2517,9 +2770,12 @@ class ContinuousBatcher:
         done-flag and finish reason are kept — small per-request scalars —
         so ``is_done``/``finish_reason`` stay observable and a poller
         can't spin forever on a released id; ``result`` then reports
-        'released', not 'unknown'."""
-        if request_id in self.done and not self.done[request_id]:
-            raise RuntimeError(f"request {request_id} still decoding")
+        'released', not 'unknown'. A request still decoding is refused,
+        once the step in flight, which may be its last, has landed."""
+        if self.done.get(request_id) is False:
+            self._land()
+            if not self.done[request_id]:
+                raise RuntimeError(f"request {request_id} still decoding")
         self.results.pop(request_id, None)
         self.results_logprobs.pop(request_id, None)
 

@@ -463,25 +463,60 @@ def decoded(mix: str, monitored: bool):
 @pytest.mark.parametrize("mix", MIXES)
 def test_phase_ms_splits_every_decode_step(mix):
     _, steps, batcher = decoded(mix, True)
-    decode = [s for s in steps if s["decode_tokens"]]
+    plain = [s for s in steps if s["phase_ms"]]
+    decode = [s for s in plain if s["decode_tokens"]]
     assert decode and batcher._phase_ms is None
     picks_on_host = sum(sp.steered for sp in MIXES[mix])
     picks_on_device = sum(
         sp.temperature > 0 and not sp.steered for sp in MIXES[mix]
     )
-    for record in decode:
+    # two requests of 5 tokens, the first from the admission: four decode
+    # steps. A steered row's step is landed in the call that dispatched it
+    # (the host chooses its token); every other mix runs one step ahead:
+    # the first call dispatches and lands nothing, the next three dispatch
+    # a step and land the one before, the last lands the fourth
+    if picks_on_host:
+        assert [s["ahead"] for s in plain] == [False] * 4
+        assert [s["decode_tokens"] for s in plain] == [2] * 4
+    else:
+        assert [s["ahead"] for s in plain] == [False, True, True, True, False]
+        assert [s["decode_tokens"] for s in plain] == [0, 2, 2, 2, 2]
+    assert all(s["discarded_tokens"] == 0 for s in steps)
+    stats = batcher.stats
+    assert stats["steps_ahead"] == sum(s["ahead"] for s in plain)
+    assert stats["steps_ahead"] + stats["steps_synchronous"] == 4
+    for record in plain:
         phases = record["phase_ms"]
-        assert set(phases) == {*TOP_PHASES, "sample_choose", "sample_logprob"}
+        dispatched = {"upload", "dispatch"} if "dispatch" in phases else set()
+        landed = (
+            {"wait", "pull", "sample", "sample_choose", "sample_logprob"}
+            if record["decode_tokens"] else set()
+        )
+        # in the order they began: a step goes out before one comes in
+        assert list(phases) == [
+            k for k in (*TOP_PHASES, "sample_choose", "sample_logprob")
+            if k in dispatched | landed
+        ]
+        assert record["ahead"] == (
+            bool(dispatched and landed) and not picks_on_host
+        )
         assert all(ms >= 0.0 for ms in phases.values())
         # the phases lie inside the step, one after another
-        assert sum(phases[k] for k in TOP_PHASES) <= record["duration_ms"]
+        assert sum(
+            phases.get(k, 0.0) for k in TOP_PHASES
+        ) <= record["duration_ms"]
+        # where the tokens of the step that WENT OUT are picked
+        assert record["host_picked_rows"] == picks_on_host * bool(dispatched)
+        assert record["device_picked_rows"] == (
+            picks_on_device * bool(dispatched)
+        )
+        if not landed:
+            continue
         inside = phases["sample_choose"] + phases["sample_logprob"]
         assert inside <= phases["sample"]
         # a greedy row's token and an unsteered sampling row's come off the
         # device: nothing is chosen here
         assert (phases["sample_choose"] > 0.0) == (picks_on_host > 0)
-        assert record["host_picked_rows"] == picks_on_host
-        assert record["device_picked_rows"] == picks_on_device
         assert (phases["sample_logprob"] > 0.0) == any(
             sp.logprobs for sp in MIXES[mix]
         )
@@ -545,9 +580,22 @@ def test_serve_spans_nest_and_carry_their_stats(monkeypatch, mix):
     steps = [stats for name, stats, _ in spans if name == "serve.step"]
     assert [s["n"] for s in steps] == list(range(1, len(steps) + 1))
     assert steps[0]["rows"] == 2
-    assert names[10:16] == ["serve.step"] + [
-        f"serve.step.{phase}" for phase in TOP_PHASES
-    ]
+    went_out = ["serve.step.upload", "serve.step.dispatch"]
+    came_in = [f"serve.step.{phase}" for phase in ("wait", "pull", "sample")]
+    if mix == "steered":
+        # the host chooses a steered row's token: each step is landed in
+        # the call that dispatched it
+        assert [s["ahead"] for s in steps] == [0, 0]
+        assert names[10:] == (["serve.step"] + went_out + came_in) * 2
+    else:
+        # one step ahead: the first call dispatches, the second dispatches
+        # the second step and then lands the first, the third lands that
+        assert [s["ahead"] for s in steps] == [0, 1, 0]
+        assert names[10:] == (
+            ["serve.step"] + went_out
+            + ["serve.step"] + went_out + came_in
+            + ["serve.step"] + came_in
+        )
     # one [3, B] int32 array a step, whatever the rows (row_answers: the
     # token, its logit and the row's normaliser); steered: the rows the
     # host picks from beside it
@@ -577,7 +625,20 @@ def test_rows_the_device_chose_for_cross_as_one_small_array(rows):
     logits = jax.random.normal(jax.random.PRNGKey(3), (4, 1, 97), jnp.float32)
     picked = jnp.asarray([5, 96, 0, 41], jnp.int32)
     sampled = np.array([False, True, True, False])
-    token, logit, log_z = np.asarray(row_answers(logits, picked, sampled))
+    # ...and beside it what the next step is given, left on the device: a
+    # stepping row's token as its current, its position one slot on (never
+    # past the table's last); every other row's as they were
+    current = jnp.asarray([[7], [8], [9], [10]], jnp.int32)
+    pos = jnp.asarray([3, 11, 5, 6], jnp.int32)
+    stepping = np.array([True, True, False, True])
+    answers, fed_current, fed_pos = row_answers(
+        logits, picked, sampled, current, pos, stepping, last_slot=11
+    )
+    token, logit, log_z = np.asarray(answers)
+    assert np.asarray(fed_current)[:, 0].tolist() == [
+        token[0], token[1], 9, token[3]
+    ]
+    assert np.asarray(fed_pos).tolist() == [4, 11, 5, 7]
     last = np.asarray(logits)[:, 0]
     assert token.tolist() == np.where(sampled, picked, last.argmax(-1)).tolist()
     assert logit.view(np.float32).tolist() == last[np.arange(4), token].tolist()
