@@ -27,7 +27,8 @@ model code rather than in an example.
 
 Scope honestly stated: attention biases and rope scaling configs other
 than linear interpolation (and ``deepseek_yarn`` for ``sarvam_mla``) are
-refused rather than silently mis-loaded. ``rms_norm_eps`` is a field of
+refused rather than silently mis-loaded. ``config_from_hf`` also maps
+``granitemoehybrid``, ``sarvam_mla`` and ``exaone_moe`` configurations. ``rms_norm_eps`` is a field of
 ``TransformerConfig`` and is taken as published.
 """
 
@@ -69,6 +70,8 @@ def config_from_hf(hf_config, dtype=jnp.bfloat16) -> TransformerConfig:
         return _granite_hybrid_config(hf_config, dtype, eps)
     if getattr(hf_config, "model_type", None) == "sarvam_mla":
         return _sarvam_mla_config(hf_config, dtype, eps)
+    if getattr(hf_config, "model_type", None) == "exaone_moe":
+        return _exaone_moe_config(hf_config, dtype, eps)
     scaling = getattr(hf_config, "rope_scaling", None)
     rope_scaling = 1.0
     if scaling is not None:
@@ -79,24 +82,15 @@ def config_from_hf(hf_config, dtype=jnp.bfloat16) -> TransformerConfig:
                 "position interpolation maps onto our rope scaling)"
             )
         rope_scaling = float(scaling["factor"])
-    derived_head_dim = hf_config.hidden_size // hf_config.num_attention_heads
-    explicit_head_dim = getattr(hf_config, "head_dim", None)
-    if explicit_head_dim not in (None, derived_head_dim):
-        # our attention derives head_dim from hidden_size // n_heads; a
-        # checkpoint with a non-derived head_dim (increasingly common in
-        # HF Llama-family configs) would otherwise pass construction and
-        # fail later with an opaque reshape error
-        raise ValueError(
-            f"head_dim {explicit_head_dim} != hidden_size // "
-            f"num_attention_heads ({derived_head_dim}); non-derived head "
-            "dims unsupported — refusing a silently wrong load"
-        )
     return TransformerConfig(
         vocab_size=hf_config.vocab_size,
         d_model=hf_config.hidden_size,
         n_layers=hf_config.num_hidden_layers,
         n_heads=hf_config.num_attention_heads,
         n_kv_heads=hf_config.num_key_value_heads,
+        # a head of its own width (increasingly common in HF Llama-family
+        # configs) is a field; absent, it is hidden_size // heads
+        head_dim=getattr(hf_config, "head_dim", None),
         d_ff=hf_config.intermediate_size,
         max_seq_len=hf_config.max_position_embeddings,
         rope_theta=float(getattr(hf_config, "rope_theta", 10000.0)),
@@ -157,6 +151,85 @@ def _sarvam_mla_config(hf_config, dtype, eps) -> TransformerConfig:
         moe_router_bias=bool(
             getattr(hf_config, "moe_router_enable_expert_bias", False)
         ),
+    )
+
+
+def _exaone_moe_config(hf_config, dtype, eps) -> TransformerConfig:
+    """``exaone_moe`` (K-EXAONE): window layers and full layers under the
+    published ``layer_types``, a head of its own width, an RMSNorm over each
+    head's query and key, rotary embedding in the window layers alone,
+    leading dense layers, then routed experts (every one of them held: a
+    chip's share is the configuration's to cut) beside shared ones, under
+    the DeepSeek-V3 lineage's router. It loads WITHOUT the multi-token
+    prediction block (``num_nextn_predict_layers``, ``mtp_*``): the forward
+    pass that gives the next token's logits does not contain it, the
+    program has no field for it and a step yields one token a row, so
+    nothing here can use it (self-speculative serving over rings is refused
+    by name in ``ContinuousBatcher``). What is not computed is refused by
+    name."""
+    if getattr(hf_config, "n_group", 1) not in (None, 1) or getattr(
+        hf_config, "topk_group", 1
+    ) not in (None, 1):
+        raise ValueError(
+            f"n_group {getattr(hf_config, 'n_group', 1)} / topk_group "
+            f"{getattr(hf_config, 'topk_group', 1)} unsupported: the router "
+            "has one routing group"
+        )
+    scoring = getattr(hf_config, "scoring_func", "sigmoid")
+    if scoring != "sigmoid":
+        raise ValueError(
+            f"scoring_func {scoring!r} unsupported for exaone_moe: the held "
+            "experts' router scores with a sigmoid"
+        )
+    if not getattr(hf_config, "norm_topk_prob", True):
+        raise ValueError(
+            "norm_topk_prob false unsupported: the kept scores are weighted "
+            "over their sum"
+        )
+    rope = getattr(hf_config, "rope_parameters", None) or {}
+    kind = rope.get("rope_type", rope.get("type", "default"))
+    if kind != "default":
+        raise ValueError(
+            f"rope_parameters type {kind!r} unsupported for exaone_moe "
+            "(default rotary frequencies)"
+        )
+    kinds = tuple(hf_config.layer_types)
+    mlps = getattr(hf_config, "mlp_layer_types", None)
+    n_dense = getattr(hf_config, "first_k_dense_replace", 0)
+    if mlps is not None and list(mlps) != (
+        ["dense"] * n_dense + ["sparse"] * (len(kinds) - n_dense)
+    ):
+        raise ValueError(
+            "mlp_layer_types unsupported: dense layers lead "
+            "(first_k_dense_replace) and every other layer is sparse"
+        )
+    return TransformerConfig(
+        vocab_size=hf_config.vocab_size,
+        d_model=hf_config.hidden_size,
+        n_layers=hf_config.num_hidden_layers,
+        n_heads=hf_config.num_attention_heads,
+        n_kv_heads=hf_config.num_key_value_heads,
+        head_dim=getattr(hf_config, "head_dim", None),
+        d_ff=hf_config.intermediate_size,
+        max_seq_len=hf_config.max_position_embeddings,
+        rope_theta=float(rope.get(
+            "rope_theta", getattr(hf_config, "rope_theta", 10000.0)
+        )),
+        dtype=dtype,
+        rms_norm_eps=eps,
+        sliding_window=hf_config.sliding_window,
+        layer_types=kinds,
+        position_embedding="rope_window",
+        qk_norm=True,
+        n_dense_layers=n_dense,
+        n_experts=hf_config.num_experts,
+        moe_top_k=hf_config.num_experts_per_tok,
+        moe_scoring="sigmoid",
+        moe_held_experts=hf_config.num_experts,
+        moe_d_ff=hf_config.moe_intermediate_size,
+        moe_shared_experts=getattr(hf_config, "num_shared_experts", 0),
+        moe_routed_scaling=getattr(hf_config, "routed_scaling_factor", 1.0),
+        moe_router_bias=True,
     )
 
 

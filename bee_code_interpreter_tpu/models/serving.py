@@ -87,9 +87,11 @@ from bee_code_interpreter_tpu.models.transformer import (
 )
 from bee_code_interpreter_tpu.ops.paged_attention import reads_pages_in_place
 from bee_code_interpreter_tpu.ops.paged_kv_cache import (
+    BY_ROW_LEAVES,
     alloc_paged_cache,
     pages_leaf,
     seed_prefill,
+    seed_rings,
     seed_state,
 )
 from bee_code_interpreter_tpu.parallel.mesh import mesh_shape_key
@@ -631,7 +633,14 @@ class ContinuousBatcher:
         row's state, whole, at the prompt's true length, and the decode
         program advances every row's by a token. What cannot hold with
         state kept by row is refused here by name (``_refuse_over_state``)
-        or at ``validate_request`` (chunked and interleaved admission)."""
+        or at ``validate_request`` (chunked and interleaved admission).
+
+        A config whose ``layer_types`` tell WINDOW layers from full ones
+        keeps the window layers' K/V in rings by row in that pool too
+        (``sliding_window`` slots a row a layer): pages, ``n_pages`` and
+        the admission's page arithmetic count the full layers alone;
+        admission seeds the row's rings from the prompt's last positions;
+        what cannot be served over rings is refused the same two ways."""
         self._refuse_over_state(
             config, prefix_cache=prefix_cache, draft_params=draft_params,
             adapters=adapters, mesh=mesh,
@@ -796,7 +805,7 @@ class ContinuousBatcher:
         # the predicate it is traced under, over the same pool, window of
         # one token and mesh
         self._decode_in_place = reads_pages_in_place(
-            self.cache, 1, config.sliding_window, mesh
+            self.cache, 1, config.paged_window, mesh
         )
         # what the mamba layers keep for a row, replaced whole at admission
         self._seed_state = None
@@ -808,6 +817,22 @@ class ContinuousBatcher:
                 seed_state, "seed_state", donate_argnums=(0,)
             )
             self._state_bytes_per_row = state_bytes_per_row(config)
+        # what the window layers keep for a row: its rings, replaced whole
+        # at admission from the prompt's last ``sliding_window`` positions
+        self._seed_rings = None
+        self._ring_bytes_per_row = 0
+        if config.window_layers:
+            self._seed_rings = self._track(
+                functools.partial(
+                    seed_rings, window_layers=config.window_layers
+                ),
+                "seed_rings", donate_argnums=(0,),
+            )
+            self._paged_layers = np.asarray(config.paged_layers, np.int32)
+            self._ring_bytes_per_row = sum(
+                self.cache[name].nbytes for name in ("wk", "wv")
+            ) // max_batch
+            self._state_bytes_per_row += self._ring_bytes_per_row
         # The one-shot admission program. With a mesh the full forward runs
         # under it — in particular an ``sp`` axis shards the attention over
         # the sequence axis (ring or Ulysses per ``config.sp_attention``,
@@ -982,13 +1007,16 @@ class ContinuousBatcher:
 
     @staticmethod
     def _refuse_over_state(config, *, prefix_cache, draft_params, adapters, mesh):
-        """What a config with a latent cache or with mamba layers cannot be
-        served with, each refused by name. Over mamba layers the features
-        below assume that everything a row keeps is K/V by position, which
-        a page can share, a window can overwrite and a mesh can split by
-        head; recurrent state is one value a row, advanced in place. A
-        latent cache is one KV head a layer, kept whole on one chip, and
-        the window paths were not carried over to it (PERF.md 7)."""
+        """What a config with a latent cache, with mamba layers or with
+        window layers' rings cannot be served with, each refused by name.
+        Over mamba layers the features below assume that everything a row
+        keeps is K/V by position, which a page can share, a window can
+        overwrite and a mesh can split by head; recurrent state is one
+        value a row, advanced in place. A ring by row holds the last
+        ``sliding_window`` positions alone, each slot overwritten a window
+        later. A latent cache is one KV head a layer, kept whole on one
+        chip, and the window paths were not carried over to it (PERF.md
+        7)."""
         speculative = draft_params is not None
         refusals = []
         if config.kv_lora_rank:
@@ -1019,6 +1047,21 @@ class ContinuousBatcher:
                 (mesh is not None, "mesh (tp > 1)",
                  "the recurrent state and the mixer are kept whole on one "
                  "chip"),
+            )))
+        if config.window_layers:
+            refusals.append(("window layers' rings", (
+                (prefix_cache, "prefix_cache",
+                 "a shared page carries K/V of its tokens but no ring: a hit "
+                 "would admit its suffix with the window layers' slots of "
+                 "the prefix missing"),
+                (speculative, "draft_params (speculative mode)",
+                 "a rejected draft has overwritten the slot of the position "
+                 "a window before it, which the row needs back"),
+                (bool(adapters), "adapters",
+                 "adapter admissions prefill through windows of several "
+                 "tokens, which a ring of one slot a position does not take"),
+                (mesh is not None, "mesh (tp > 1)",
+                 "the rings are kept whole on one chip"),
             )))
         for over, table in refusals:
             for asked, name, why in table:
@@ -1165,7 +1208,8 @@ class ContinuousBatcher:
         }
         out["pages_allocated_total"] = self._pages_allocated
         out["pages_released_total"] = self._pages_released
-        # what mamba layers keep by row beside the pages (0 without them)
+        # what mamba layers and window layers keep by row beside the pages
+        # (0 without them)
         per_row = self._state_bytes_per_row
         out["state_bytes_per_row"] = per_row
         out["state_rows_live"] = int(self.active.sum()) if per_row else 0
@@ -1183,10 +1227,24 @@ class ContinuousBatcher:
         out["cache_bytes_per_token"] = sum(
             x.dtype.itemsize * x.shape[0] * int(np.prod(x.shape[2:]))
             // self.page_size
-            for name, x in self.cache.items() if name not in ("ssm", "conv")
+            for name, x in self.cache.items() if name not in BY_ROW_LEAVES
         )
         out["decode_attention"] = "pages_in_place" if in_place else "gathered"
         out["decode_append"] = "in_place" if in_place else "scattered"
+        # which attention layers keep pages and which a ring of
+        # ``window_slots`` slots a row (the ring's bytes are inside
+        # ``state_bytes_per_row``), and how each kind decodes
+        out["paged_layers"] = len(c.paged_layers)
+        out["window_layers"] = len(c.window_layers)
+        out["window_slots"] = c.sliding_window if c.window_layers else 0
+        out["ring_bytes_per_row"] = self._ring_bytes_per_row
+        by_kind = {
+            kind: out["decode_attention"] for kind in c.layer_kinds
+            if kind not in ("mamba", "sliding_attention")
+        }
+        if c.window_layers:
+            by_kind["sliding_attention"] = "ring_by_row"
+        out["decode_attention_by_kind"] = by_kind
         return out
 
     # ----------------------------------------------------- snapshot/resume
@@ -1382,6 +1440,11 @@ class ContinuousBatcher:
         elif self.config.kv_lora_rank:
             windowless = ("a latent cache", (
                 "the admission window is not run over a latent pool yet"
+            ))
+        elif self.config.window_layers:
+            windowless = ("window layers' rings", (
+                "a chunk or window of several tokens overwrites slots its "
+                "own earlier tokens still attend over"
             ))
         if windowless is not None:
             over, why = windowless
@@ -1886,10 +1949,12 @@ class ContinuousBatcher:
         [1, Lp]: the exact O(L^2) forward, then the shared
         one-scatter-per-leaf page seeding (seed_prefill — the equality
         tests call the same function, so the tested path IS this path)
-        and, over mamba layers, the row's state. The padded prompt bounds
-        the compile count: pad tokens are causal-masked for every row < L,
-        so logits[L-1] and K/V[:L] are exact, and distinct prompt lengths
-        share a program per page count instead of one per length."""
+        and, over mamba layers, the row's state; over window layers, the
+        row's rings first and the pages from the full layers alone. The
+        padded prompt bounds the compile count: pad tokens are
+        causal-masked for every row < L, so logits[L-1] and K/V[:L] are
+        exact, and distinct prompt lengths share a program per page count
+        instead of one per length."""
         pages_arr = jnp.asarray(
             pages[: padded.shape[1] // self.page_size], dtype=jnp.int32
         )
@@ -1902,6 +1967,17 @@ class ContinuousBatcher:
                 logits, (k_pre, *v_pre, ssm, conv) = self._prefill(
                     self.params, padded, length=np.int32(L)
                 )
+        if self._seed_rings is not None:
+            # the window layers' K/V of the last ``sliding_window`` real
+            # positions into the row's rings; the pages take the rest
+            with self._phase(
+                "serve.admit.seed_window", rows=1,
+                bytes=self._ring_bytes_per_row,
+            ):
+                self.cache = self._seed_rings(
+                    self.cache, np.int32(row), k_pre, v_pre[0], np.int32(L)
+                )
+            k_pre, v_pre = k_pre[self._paged_layers], [v_pre[0][self._paged_layers]]
         with self._phase("serve.admit.seed_pool"):
             self.cache = seed_prefill(
                 self.cache, pages_arr,

@@ -45,8 +45,39 @@ from bee_code_interpreter_tpu.parallel.ring_attention import ring_attention
 Params = dict[str, Any]
 # an attention layer's own leaves (the norms and the MLP are every layer's):
 # K and V per head, or a latent for all heads (``kv_lora_rank``)
-ATTENTION_LEAVES = ("wq", "wk", "wv", "wo")
+# (``ln_q`` / ``ln_k``: the per-head norms of ``qk_norm``)
+ATTENTION_LEAVES = ("wq", "wk", "wv", "wo", "ln_q", "ln_k")
 LATENT_LEAVES = ("wq", "ln_q", "w_kva", "ln_kv", "w_kvb", "wo")
+# what ``layer_types`` may name beside "mamba". "attention": a layer of a
+# model whose layers are not told apart (every one masked by
+# ``sliding_window`` where that is set, its K/V kept by page whole). The
+# published pair: "full_attention" (no window, K/V by page) and
+# "sliding_attention" (``sliding_window``, and only the window kept, in a
+# ring by row: ``ops/paged_kv_cache.py``).
+ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention")
+
+
+class _Derived(int):
+    """A size worked out from other fields because none was given. It reads
+    as the number; set back (``dataclasses.replace`` hands every field to
+    the new object) it is "none given" again, so a replaced ``d_model``
+    still decides it."""
+
+
+class _HeadDim:
+    """``TransformerConfig.head_dim`` as a field that reads as a number:
+    None (the default) is ``d_model // n_heads``."""
+
+    def __get__(self, obj, owner=None):
+        if obj is None:
+            return None  # the field's default
+        given = obj.__dict__.get("_head_dim")
+        if given is None:
+            return _Derived(obj.d_model // obj.n_heads)
+        return given
+
+    def __set__(self, obj, value):
+        obj.__dict__["_head_dim"] = None if isinstance(value, _Derived) else value
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,6 +87,8 @@ class TransformerConfig:
     n_layers: int = 32
     n_heads: int = 32
     n_kv_heads: int | None = None  # grouped-query attention; None = MHA
+    # a head's width (the published ``head_dim``); None = d_model // n_heads
+    head_dim: int | None = _HeadDim()
     d_ff: int | None = None  # None = SwiGLU default 8/3 * d_model rounded
     max_seq_len: int = 8192
     rope_theta: float = 500000.0
@@ -93,17 +126,23 @@ class TransformerConfig:
     # per-token/head absmax quantization, ops/kv_cache.py — halves the bytes
     # the bandwidth-bound decode loop streams per step).
     kv_cache_dtype: str = "bf16"
-    # Sliding-window attention (Mistral-style): each query attends only the
-    # last `sliding_window` positions. None = full causal attention. The
-    # flash kernels skip fully-out-of-window blocks; single-shard/tp meshes
-    # only (the sp ring/Ulysses paths don't thread the window).
+    # Sliding-window attention: a query attends only the last
+    # ``sliding_window`` positions. ONE number: the window of the layers
+    # ``layer_types`` calls "sliding_attention" (which keep no more than
+    # it, in a ring by row) and, where the layers are not told apart
+    # (Mistral-style: no pattern, or the kind "attention"), of every layer,
+    # as a mask over K/V that are all kept. None = full causal attention.
+    # The flash kernels skip fully-out-of-window blocks.
     sliding_window: int | None = None
-    # A declared layer pattern: one of "attention" / "mamba" per layer (the
-    # published ``layer_types`` list; frozen to a tuple, since the config is
-    # a static jit argument). None = attention in every layer, the one
-    # ``lax.scan`` below. With a pattern the stack is scanned a PERIOD at a
-    # time (``layer_period``) and each kind's leaves are stacked over the
-    # layers of that kind alone. Every layer keeps its SwiGLU MLP.
+    # A declared layer pattern: "mamba" or one of ``ATTENTION_KINDS`` per
+    # layer (the published ``layer_types`` list; frozen to a tuple, since
+    # the config is a static jit argument). None = attention in every
+    # layer, the one ``lax.scan`` below. With a pattern the stack is scanned
+    # a PERIOD at a time (``layer_period``) and each kind's leaves are
+    # stacked over the layers of that kind alone (the attention leaves over
+    # the attention layers of every kind). The pattern covers ALL layers:
+    # leading dense layers (``n_dense_layers``) are its first entries and
+    # the period is that of what follows them.
     layer_types: tuple[str, ...] | None = None
     # Mamba-2 mixer sizes (published ``mamba_*`` keys): heads x head size is
     # the inner width (``mamba_expand`` x d_model), B and C are shared by
@@ -124,7 +163,10 @@ class TransformerConfig:
     residual_multiplier: float = 1.0
     attention_multiplier: float | None = None
     logits_scaling: float = 1.0
-    # "rope", or the published "nope": no position embedding at all
+    # "rope"; the published "nope": no position embedding at all; or
+    # "rope_window": rotary in the "sliding_attention" layers and none in
+    # the others (K-EXAONE's family: a full layer sees the whole context
+    # without positions, a window layer orders its window)
     position_embedding: str = "rope"
     # the output head is the embedding transposed: no ``lm_head`` leaf
     tie_embeddings: bool = False
@@ -142,7 +184,10 @@ class TransformerConfig:
     # up-projected form against the cached latent itself (the absorbed
     # form: one KV head whose values are the first ``kv_lora_rank`` of its
     # keys). ``qk_norm`` (published ``use_qk_norm``) norms each head's
-    # whole query, with a scale of its own, before the rotary split.
+    # whole query, with a scale of its own, before the rotary split. With K
+    # and V per head (no latent) ``qk_norm`` is an RMSNorm of each head's
+    # query and of each head's key, each with a scale of its own (``ln_q``,
+    # ``ln_k`` [head_dim]), before the position embedding.
     kv_lora_rank: int = 0
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
@@ -179,10 +224,10 @@ class TransformerConfig:
     moe_router_bias: bool = False
 
     def __post_init__(self) -> None:
-        if self.position_embedding not in ("rope", "nope"):
+        if self.position_embedding not in ("rope", "nope", "rope_window"):
             raise ValueError(
-                f"position_embedding must be 'rope' or 'nope', got "
-                f"{self.position_embedding!r}"
+                f"position_embedding must be 'rope', 'nope' or 'rope_window', "
+                f"got {self.position_embedding!r}"
             )
         if self.rope_yarn is not None:
             yarn = dict(self.rope_yarn)
@@ -232,26 +277,51 @@ class TransformerConfig:
                 f"experts {self.moe_held_from}..{self.moe_held_from + self.held_experts}"
                 f" held of a router of {self.n_experts}, top {self.moe_top_k}"
             )
-        if self.n_dense_layers and (
-            self.layer_types is not None or self.n_dense_layers >= self.n_layers
-        ):
+        if self.n_dense_layers and self.n_dense_layers >= self.n_layers:
             raise ValueError(
-                "leading dense layers precede a scan of expert layers, with "
-                "no declared layer pattern"
+                f"{self.n_dense_layers} leading dense layers of "
+                f"{self.n_layers}: they precede a scan of expert layers"
             )
         if self.layer_types is None:
+            if self.position_embedding == "rope_window":
+                raise ValueError(
+                    "position_embedding 'rope_window' needs layer_types to "
+                    "say which layers are 'sliding_attention'"
+                )
             return
         types_ = tuple(self.layer_types)
         object.__setattr__(self, "layer_types", types_)
-        unknown = set(types_) - {"attention", "mamba"}
+        unknown = set(types_) - {"mamba", *ATTENTION_KINDS}
         if unknown or len(types_) != self.n_layers:
             raise ValueError(
-                f"layer_types must name 'attention' or 'mamba' for each of "
-                f"{self.n_layers} layers, got {len(types_)} entries"
+                f"layer_types must name 'mamba' or one of {ATTENTION_KINDS} "
+                f"for each of {self.n_layers} layers, got {len(types_)} entries"
                 + (f" with {sorted(unknown)}" if unknown else "")
             )
-        if self.n_experts:
-            raise ValueError("a layer pattern with expert MLPs is unsupported")
+        if "attention" in types_ and len(set(types_) & set(ATTENTION_KINDS)) > 1:
+            raise ValueError(
+                "layer_types tells its attention layers apart "
+                "('full_attention' / 'sliding_attention') or does not "
+                "('attention'), not both"
+            )
+        if "sliding_attention" in types_ and (
+            self.sliding_window is None or self.sliding_window < 1
+            or self.kv_lora_rank or self.kv_cache_dtype != "bf16"
+        ):
+            raise ValueError(
+                "a 'sliding_attention' layer keeps sliding_window slots a "
+                "row, K and V per head, in the compute dtype: it needs "
+                f"sliding_window (got {self.sliding_window}), no latent "
+                "cache and no int8 cache"
+            )
+        if self.n_experts and self.moe_scoring != "sigmoid":
+            raise ValueError(
+                "a layer pattern with expert MLPs takes the sorted expert "
+                "layer (moe_scoring='sigmoid'); the GShard dispatch scans "
+                "one layer kind"
+            )
+        if "mamba" in types_[:self.n_dense_layers]:
+            raise ValueError("a leading dense layer is an attention layer")
         if "mamba" in types_ and (
             self.mamba_n_heads * self.mamba_d_head
             != self.mamba_expand * self.d_model
@@ -267,27 +337,60 @@ class TransformerConfig:
 
     @property
     def n_attention_layers(self) -> int:
-        if self.layer_types is None:
-            return self.n_layers
-        return self.layer_types.count("attention")
+        """Attention layers of every kind: what ``wq wk wv wo`` are stacked
+        over, and the K/V ``forward(return_kv=True)`` hands back."""
+        return self.n_layers - self.n_mamba_layers
 
     @property
     def n_mamba_layers(self) -> int:
         return 0 if self.layer_types is None else self.layer_types.count("mamba")
 
     @property
+    def layer_kinds(self) -> tuple[str, ...]:
+        """Every layer's kind: ``layer_types``, or "attention" throughout."""
+        return self.layer_types or ("attention",) * self.n_layers
+
+    @property
+    def window_layers(self) -> tuple[int, ...]:
+        """Which of the attention layers, counted among themselves, keep
+        only their window, in a ring by row ("sliding_attention")."""
+        kinds = [k for k in self.layer_kinds if k != "mamba"]
+        return tuple(i for i, k in enumerate(kinds) if k == "sliding_attention")
+
+    @property
+    def paged_layers(self) -> tuple[int, ...]:
+        """Which of the attention layers keep every token's K/V, by page."""
+        ring = set(self.window_layers)
+        return tuple(i for i in range(self.n_attention_layers) if i not in ring)
+
+    @property
+    def paged_window(self) -> int | None:
+        """The window masked over K/V that are kept by page:
+        ``sliding_window`` where the attention layers are not told apart,
+        none where they are (the window layers then keep rings, and the
+        full layers see everything)."""
+        told_apart = {"full_attention", "sliding_attention"} & set(self.layer_kinds)
+        return None if told_apart else self.sliding_window
+
+    @property
     def layer_period(self) -> int:
-        """The shortest period of ``layer_types`` (1 where it has none: a
-        pattern of one attention layer): the layers one iteration of the
-        scan unrolls."""
-        types_ = self.layer_types
-        if types_ is None:
+        """The shortest period of ``layer_types`` after the leading dense
+        layers (1 where it has none: a pattern of one attention layer): the
+        layers one iteration of the scan unrolls. The layers need not be a
+        whole number of periods: what is left over, the start of one more
+        period, runs unrolled after the scan (a dense layer 0 before
+        ``[sliding x 3, full] x 12`` leaves 11 periods of 4 and 3 layers).
+        Of the lengths the pattern repeats at, the one that unrolls fewest
+        layers in all, and of two such the one that leaves none over."""
+        if self.layer_types is None:
             return 1
+        types_ = self.layer_types[self.n_dense_layers:]
         n = len(types_)
-        for p in range(1, n + 1):
-            if n % p == 0 and all(types_[i] == types_[i % p] for i in range(n)):
-                return p
-        return n
+        repeats = [
+            p for p in range(1, n + 1)
+            if all(types_[i] == types_[i % p] for i in range(n))
+        ]
+        return min(repeats, key=lambda p: (p + n % p, n % p))
 
     @property
     def mamba_d_inner(self) -> int:
@@ -333,10 +436,6 @@ class TransformerConfig:
     @property
     def yarn(self) -> dict | None:
         return None if self.rope_yarn is None else dict(self.rope_yarn)
-
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
 
     @property
     def ff_dim(self) -> int:
@@ -518,12 +617,16 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
                 out["ln_q"] = jnp.ones((c.qk_head_dim,), jnp.float32)
             return out
         dh, kvh = c.head_dim, c.kv_heads
-        return {
+        out = {
             "wq": dense(ks[0], c.d_model, c.d_model, c.n_heads * dh),
             "wk": dense(ks[1], c.d_model, c.d_model, kvh * dh),
             "wv": dense(ks[2], c.d_model, c.d_model, kvh * dh),
             "wo": dense(ks[3], c.n_heads * dh, c.n_heads * dh, c.d_model),
         }
+        if c.qk_norm:
+            out["ln_q"] = jnp.ones((dh,), jnp.float32)
+            out["ln_k"] = jnp.ones((dh,), jnp.float32)
+        return out
 
     def mlp(ks, experts=bool(c.n_experts)):
         out = {
@@ -562,11 +665,19 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
                 keys[:c.n_dense_layers]
             )
     else:
+        # the leading dense layers, whole, apart; each stack below over the
+        # layers after them that have the leaf
         k_attn, k_mamba, k_mlp = jax.random.split(k_layers, 3)
+        n_dense = c.n_dense_layers
         stacked = {
-            **per_layer(mlp, 3, k_mlp, c.n_layers),
-            **per_layer(attention, 4, k_attn, c.n_attention_layers),
+            **per_layer(mlp, 3, k_mlp, c.n_layers - n_dense),
+            **per_layer(attention, 4, k_attn, c.n_attention_layers - n_dense),
         }
+        if n_dense:
+            dense_layers = per_layer(
+                lambda ks: {**attention(ks[:4]), **mlp(ks[4:], experts=False)},
+                7, jax.random.fold_in(k_layers, 1), n_dense,
+            )
         if c.n_mamba_layers:
             from bee_code_interpreter_tpu.models.mamba import init_mixer_params
 
@@ -608,6 +719,8 @@ def param_specs(config: TransformerConfig, mesh: Mesh) -> Params:
         })
         if config.qk_norm:
             layer["ln_q"] = _stack(rep)
+    elif config.qk_norm:
+        layer["ln_q"] = layer["ln_k"] = _stack(rep)
     dense_layer = dict(layer)
     if config.n_experts and config.moe_scoring == "sigmoid":
         layer["moe"] = {
@@ -780,14 +893,37 @@ def _attention(
     return fn(q, k, v)
 
 
-def _positioned(x, positions, config: TransformerConfig):
-    """q or k with the configuration's position embedding applied: rotary,
-    or nothing at all (the published "nope")."""
-    if config.position_embedding == "nope":
+def _positioned(x, positions, config: TransformerConfig, kind: str = "attention"):
+    """q or k of an attention layer of ``kind`` with the configuration's
+    position embedding applied: rotary, nothing at all (the published
+    "nope"), or rotary in the window layers alone ("rope_window")."""
+    if config.position_embedding == "nope" or (
+        config.position_embedding == "rope_window" and kind != "sliding_attention"
+    ):
         return x
     return rope(
         x, positions, config.rope_theta, config.rope_scaling, config.yarn
     )
+
+
+def _kind_window(config: TransformerConfig, kind: str) -> int | None:
+    """The window an attention layer of ``kind`` attends within:
+    ``sliding_window`` in a "sliding_attention" layer and in the layers of a
+    model that does not tell them apart ("attention"), none in a
+    "full_attention" layer."""
+    if kind == "sliding_attention":
+        return config.sliding_window
+    return config.paged_window
+
+
+def _head_qk(x, layer, scale: str, positions, config, kind: str = "attention"):
+    """A layer's projected queries (``scale`` "ln_q") or keys ("ln_k") [B,
+    heads, L, dh] as attention takes them: under ``qk_norm`` each head
+    RMS-normed over its own values, with a scale of its own, then the
+    position embedding of a layer of ``kind``."""
+    if config.qk_norm:
+        x = rms_norm(x, layer[scale], config.rms_norm_eps)
+    return _positioned(x, positions, config, kind)
 
 
 def _residual(h, branch, config: TransformerConfig):
@@ -807,11 +943,13 @@ def _layer_apply(
     mesh: Mesh | None = None,
     constrain=lambda x: x,
     return_kv: bool = False,
+    kind: str = "attention",
 ) -> tuple[jax.Array, tuple | None, jax.Array]:
     """One decoder layer — THE single source of the layer math, shared by
     ``forward`` (mesh attention + sharding constraints via the hooks) and
-    ``forward_pipelined`` (single-shard defaults). Returns
-    (h, kv_out | None, aux-loss scalar)."""
+    ``forward_pipelined`` (single-shard defaults). ``kind`` (static, one of
+    ``ATTENTION_KINDS``) decides the layer's window and whether its q and k
+    are rotated. Returns (h, kv_out | None, aux-loss scalar)."""
     c = config
     B, L = h.shape[0], h.shape[1]
     x = rms_norm(h, layer["ln1"], c.rms_norm_eps)
@@ -827,13 +965,13 @@ def _layer_apply(
             out = qeinsum("bld,dk->blk", x, w, c.dtype)
             return out.reshape(B, L, heads, dh).transpose(0, 2, 1, 3)
 
-        q = _positioned(proj(layer["wq"], nh), positions, c)
-        k = _positioned(proj(layer["wk"], kvh), positions, c)
+        q = _head_qk(proj(layer["wq"], nh), layer, "ln_q", positions, c, kind)
+        k = _head_qk(proj(layer["wk"], kvh), layer, "ln_k", positions, c, kind)
         v = proj(layer["wv"], kvh)
         kv_out = (k, v) if return_kv else None
         # GQA-native: compact k/v go in as-is
         attn = _attention(
-            q, k, v, mesh, c.sp_attention, window=c.sliding_window,
+            q, k, v, mesh, c.sp_attention, window=_kind_window(c, kind),
             sm_scale=c.attention_multiplier,
         )
         attn = attn.transpose(0, 2, 1, 3).reshape(B, L, nh * dh)
@@ -1003,29 +1141,46 @@ def _head(params: Params, h, config: TransformerConfig):
     return logits
 
 
+def _pool_part(kind: str) -> tuple[str, ...]:
+    """The layer kinds that keep what they keep in the same part of the pool
+    as ``kind``: recurrent state by row, a ring by row, or pages."""
+    if kind in ("mamba", "sliding_attention"):
+        return (kind,)
+    return ("attention", "full_attention")
+
+
 def _pattern_layer(layers: Params, config: TransformerConfig, period_index, j):
     """Layer ``j`` of period ``period_index`` (traced) of the layer pattern
-    (without ``layer_types``: a period of one attention layer): its kind,
-    its index among the layers of that kind, and its leaves taken out of
-    the stacks (the norms and MLP are stacked over all layers, a kind's own
-    leaves over the layers of that kind)."""
+    after the leading dense layers (without ``layer_types``: a period of one
+    attention layer): its kind, its index among the layers that share its
+    part of the pool (``_pool_part``; the leading dense layers counted), and
+    its leaves taken out of the stacks (the norms and MLP are stacked over
+    all layers, the mixer's leaves over the mamba layers, the attention
+    leaves over the attention layers of every kind)."""
     from bee_code_interpreter_tpu.models.mamba import MIXER_LEAVES
 
     c = config
-    period = (
-        ("attention",) if c.layer_types is None
-        else c.layer_types[:c.layer_period]
-    )
+    first = c.layer_kinds[:c.n_dense_layers]
+    period = c.layer_kinds[c.n_dense_layers:c.n_dense_layers + c.layer_period]
     kind = period[j]
-    of_kind = period_index * period.count(kind) + period[:j].count(kind)
+
+    def among(kinds, upto=None):
+        """Where this layer stands among the layers of ``kinds`` (from
+        layer ``upto`` on: the stacks hold what follows the dense ones)."""
+        count = lambda layers: sum(k in kinds for k in layers)  # noqa: E731
+        return (
+            count(upto or ()) + period_index * count(period) + count(period[:j])
+        )
+
     if kind == "mamba":
-        own = MIXER_LEAVES
+        own, in_stack = MIXER_LEAVES, among(("mamba",))
     else:
         own = LATENT_LEAVES if c.kv_lora_rank else ATTENTION_LEAVES
+        in_stack = among(ATTENTION_KINDS)
     every = period_index * len(period) + j
 
     layer = {
-        name: _take_layer(layers[name], of_kind) for name in own if name in layers
+        name: _take_layer(layers[name], in_stack) for name in own if name in layers
     }
     for name in layers:
         if name in MIXER_LEAVES + ATTENTION_LEAVES + LATENT_LEAVES:
@@ -1036,7 +1191,7 @@ def _pattern_layer(layers: Params, config: TransformerConfig, period_index, j):
             layer[name] = take_held_layer(layers[name], every)
         else:
             layer[name] = _take_layer(layers[name], every)
-    return kind, of_kind, layer
+    return kind, among(_pool_part(kind), first), layer
 
 
 def _take_layer(stacked, index):
@@ -1046,10 +1201,11 @@ def _take_layer(stacked, index):
     )
 
 
-def _n_periods(config: TransformerConfig) -> int:
-    """Iterations of the layer scan: the periods of a declared pattern, or
-    the layers after the leading dense ones."""
-    return (config.n_layers - config.n_dense_layers) // config.layer_period
+def _n_periods(config: TransformerConfig) -> tuple[int, int]:
+    """(iterations of the layer scan: the whole periods of a declared
+    pattern, or the layers after the leading dense ones; the layers left
+    over after them, the start of one more period)."""
+    return divmod(config.n_layers - config.n_dense_layers, config.layer_period)
 
 
 def _scans_by_index(config: TransformerConfig) -> bool:
@@ -1145,20 +1301,21 @@ def forward(
     h = constrain(h, batch_ax, sp, None)
     constrain_h = lambda x: constrain(x, batch_ax, sp, None)  # noqa: E731
 
-    def layer_step(h, layer):
+    def layer_step(h, layer, kind="attention"):
         h, kv_out, aux = _layer_apply(
             h, layer, c, positions,
             mesh=mesh,
             constrain=constrain_h,
             return_kv=return_kv,
+            kind=kind,
         )
         return h, (kv_out, aux)
 
-    def period_step(h, period_index):
+    def period_step(h, period_index, n_layers=c.layer_period):
         """One period of a declared pattern (or one layer taken out of the
-        stacks at its index), its layers unrolled."""
+        stacks at its index), its layers (the first ``n_layers``) unrolled."""
         kv, state = [], []
-        for j in range(c.layer_period):
+        for j in range(n_layers):
             kind, _, layer = _pattern_layer(params["layers"], c, period_index, j)
             if kind == "mamba":
                 h, kept = _mamba_layer_apply(h, layer, c, length, constrain_h)
@@ -1166,7 +1323,7 @@ def forward(
             else:
                 h, kept, _ = _layer_apply(
                     h, layer, c, positions, mesh=mesh, constrain=constrain_h,
-                    return_kv=return_kv,
+                    return_kv=return_kv, kind=kind,
                 )
                 kv.append(kept)
         stack = lambda xs: tuple(jnp.stack(x) for x in zip(*xs))  # noqa: E731
@@ -1177,12 +1334,25 @@ def forward(
     else:
         dense_kv = []
         for i in range(c.n_dense_layers):
-            h, (kept, _) = layer_step(h, _take_layer(params["dense_layers"], i))
+            h, (kept, _) = layer_step(
+                h, _take_layer(params["dense_layers"], i), c.layer_kinds[i]
+            )
             dense_kv.append(kept)
-        h, kept = lax.scan(period_step, h, jnp.arange(_n_periods(c)))
+        periods, left_over = _n_periods(c)
+        h, kept = lax.scan(period_step, h, jnp.arange(periods))
+        if left_over:
+            h, last = period_step(h, periods, left_over)
         aux_layers = jnp.zeros((), jnp.float32)
         if return_kv:  # [periods, a period's layers of a kind, ...] -> [layers, ...]
-            kv = tuple(x.reshape(-1, *x.shape[2:]) for part in kept for x in part)
+            kept = [
+                tuple(x.reshape(-1, *x.shape[2:]) for x in part) for part in kept
+            ]
+            if left_over:  # (a kind the last layers lack adds nothing)
+                kept = [
+                    tuple(jnp.concatenate(xs) for xs in zip(part, more))
+                    if more else part for part, more in zip(kept, last)
+                ]
+            kv = tuple(x for part in kept for x in part)
             if dense_kv:  # the leading layers' first
                 kv = tuple(
                     jnp.concatenate([jnp.stack(first), rest])
@@ -1380,8 +1550,8 @@ def decode_window(
             out = qeinsum("bld,dk->blk", x, w, c.dtype)
             return out.reshape(B, W, heads, dh).transpose(0, 2, 1, 3)
 
-        q = _positioned(proj(layer["wq"], nh), positions, c)  # [B,nh,W,Dh]
-        k_new = _positioned(proj(layer["wk"], kvh), positions, c)
+        q = _head_qk(proj(layer["wq"], nh), layer, "ln_q", positions, c)
+        k_new = _head_qk(proj(layer["wk"], kvh), layer, "ln_k", positions, c)
         v_new = proj(layer["wv"], kvh)
         from bee_code_interpreter_tpu.ops.kv_cache import (
             cache_append,
@@ -1469,14 +1639,17 @@ def decode_window_paged(
 
     A window of ONE token goes through the Pallas kernel that addresses the
     pool where it lies, wherever ``ops.paged_attention.reads_pages_in_place``
-    says it can (``mesh`` is read for that alone: the kernel runs in
-    ``shard_map`` over the KV heads): the stacked leaf and the layer's index
+    says it can, asked for each kind of layer that keeps pages (``mesh`` is
+    read for that alone: the kernel runs in ``shard_map`` over the KV
+    heads): the stacked leaf and the layer's index
     go to the kernel, which puts the new token into its row's boundary page
     and reads the row's live pages; no slice of the pool is cut. Everything
     else cuts the layer's slice, scatters into it and gathers the table's
-    width (``paged_append``, ``_attend_paged``). Either way the pool is the
-    CARRY of the one layer scan (``_decode_layers``), so the donated input
-    is updated in place and never copied through the scan.
+    width (``paged_append``, ``_attend_paged``). A "sliding_attention" layer
+    keeps no pages: it writes the token into its row's ring and attends over
+    the ring (``_ring_layer``; a window of one token only). Either way the
+    pool is the CARRY of the one layer scan (``_decode_layers``), so the
+    donated input is updated in place and never copied through the scan.
 
     ``lora_bank`` enables MULTI-LoRA serving (S-LoRA style): a stacked
     bank of adapters for the attention projections, with ``adapter_idx``
@@ -1490,6 +1663,7 @@ def decode_window_paged(
     """
     from bee_code_interpreter_tpu.ops import paged_attention
     from bee_code_interpreter_tpu.ops.paged_kv_cache import (
+        BY_ROW_LEAVES,
         paged_append,
         paged_page_size,
     )
@@ -1516,20 +1690,28 @@ def decode_window_paged(
         raise NotImplementedError(
             "adapters target wq/wk/wv/wo: latent attention has no wk and wv"
         )
+    if c.window_layers and (W != 1 or lora_bank is not None):
+        raise NotImplementedError(
+            "window layers that keep a ring by row decode one token a row "
+            "and take no adapters: a window of several tokens would "
+            "overwrite slots its own earlier tokens still attend over"
+        )
     page_size = paged_page_size(cache)
     positions = pos0[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]  # [B, W]
     page_idx = jnp.take_along_axis(
         block_table, positions // page_size, axis=1
     )  # [B, W]
     slot_idx = positions % page_size
-    kv_names = [name for name in cache if name not in ("ssm", "conv")]
-    in_place = paged_attention.reads_pages_in_place(
-        cache, W, c.sliding_window, mesh
-    )
+    kv_names = [name for name in cache if name not in BY_ROW_LEAVES]
+
+    def in_place(kind):  # of a layer kind that keeps pages
+        return paged_attention.reads_pages_in_place(
+            cache, W, _kind_window(c, kind), mesh
+        )
 
     h = _embed(params, tokens, c)  # [B, W, D]
 
-    def latent_layer(h, layer, cache, index):
+    def latent_layer(h, layer, cache, index, kind):
         """Latent-attention layer ``index`` (traced) of the pool ``cache``,
         whose one leaf ``ckv`` is [layers, n_pages, ps, latent_width]: the
         absorbed form, one KV head whose values are the first
@@ -1540,7 +1722,7 @@ def decode_window_paged(
         with jax.named_scope("mla.absorb"):
             q = _absorbed_query(q_nope, q_rope, layer, c)
         with jax.named_scope("mla.attend"):
-            if in_place:
+            if in_place(kind):
                 o_latent, ckv = paged_attention.paged_decode_attention(
                     q[:, :, 0], cache["ckv"], None, block_table,
                     positions[:, 0] + 1, sm_scale=_score_scale(c), mesh=mesh,
@@ -1563,9 +1745,11 @@ def decode_window_paged(
         h, _ = _mlp_residual(_residual(h, o, c), layer, c)
         return h, cache
 
-    def attention_layer(h, layer, cache, index):
-        """Attention layer ``index`` (traced) of the pool ``cache``, whose
-        K/V leaves are [attention layers, n_pages, kvh, ps, dh]."""
+    def attention_layer(h, layer, cache, index, kind):
+        """The attention layer of ``kind`` that keeps what it keeps at
+        ``index`` (traced) of its part of the pool ``cache``: of the K/V
+        leaves [paged layers, n_pages, kvh, ps, dh], or of the rings
+        [window layers, B, window, kvh, dh]."""
         x = rms_norm(h, layer["ln1"], c.rms_norm_eps)
         dh, nh, kvh = c.head_dim, c.n_heads, c.kv_heads
         lora_layer = {} if lora_bank is None else _take_layer(lora_bank, index)
@@ -1586,29 +1770,40 @@ def decode_window_paged(
                 out = out + delta
             return out.reshape(B, W, heads, dh).transpose(0, 2, 1, 3)
 
-        q = _positioned(proj(layer["wq"], nh, "wq"), positions, c)
-        k_new = _positioned(proj(layer["wk"], kvh, "wk"), positions, c)
+        q = _head_qk(proj(layer["wq"], nh, "wq"), layer, "ln_q", positions, c, kind)
+        k_new = _head_qk(
+            proj(layer["wk"], kvh, "wk"), layer, "ln_k", positions, c, kind
+        )
         v_new = proj(layer["wv"], kvh, "wv")
-        if in_place:
+        if kind == "sliding_attention":
+            with jax.named_scope("attn.window"):
+                attn, cache = _ring_layer(
+                    q, k_new, v_new, cache, index, positions[:, 0], c
+                )
+        elif in_place(kind):
             # the new token into its page and the row's live pages read,
             # both where they lie in the stacked leaf: nothing cut, nothing
             # scattered, nothing gathered
-            attn, k, v = paged_attention.paged_decode_attention(
-                q[:, :, 0], cache["k"], cache["v"], block_table,
-                positions[:, 0] + 1, sm_scale=_score_scale(c), mesh=mesh,
-                layer=index, k_new=k_new[:, :, 0], v_new=v_new[:, :, 0],
-            )
+            with jax.named_scope("attn.full"):
+                attn, k, v = paged_attention.paged_decode_attention(
+                    q[:, :, 0], cache["k"], cache["v"], block_table,
+                    positions[:, 0] + 1, sm_scale=_score_scale(c), mesh=mesh,
+                    layer=index, k_new=k_new[:, :, 0], v_new=v_new[:, :, 0],
+                )
             attn = attn.reshape(B, 1, nh * dh).astype(c.dtype)
             cache = {**cache, "k": k, "v": v}
         else:
-            c_layer = paged_append(
-                _take_layer({n: cache[n] for n in kv_names}, index),
-                k_new.transpose(0, 2, 1, 3),  # [B, W, kvh, dh]
-                v_new.transpose(0, 2, 1, 3),
-                page_idx, slot_idx,
-            )
-            attn = _attend_paged(q, c_layer, block_table, positions, c)
-            cache = _put_layer(cache, c_layer, index)
+            with jax.named_scope("attn.full"):
+                c_layer = paged_append(
+                    _take_layer({n: cache[n] for n in kv_names}, index),
+                    k_new.transpose(0, 2, 1, 3),  # [B, W, kvh, dh]
+                    v_new.transpose(0, 2, 1, 3),
+                    page_idx, slot_idx,
+                )
+                attn = _attend_paged(
+                    q, c_layer, block_table, positions, c, _kind_window(c, kind)
+                )
+                cache = _put_layer(cache, c_layer, index)
         o = qeinsum("blk,kd->bld", attn, layer["wo"], c.dtype)
         delta_o = lora_delta(attn, "wo")
         if delta_o is not None:
@@ -1622,9 +1817,13 @@ def decode_window_paged(
     return _head(params, h, c), cache
 
 
-def _attend_paged(q, c_layer, block_table, positions, config: TransformerConfig):
+def _attend_paged(
+    q, c_layer, block_table, positions, config: TransformerConfig,
+    window: int | None = None,
+):
     """Attention of ``q`` [B, nh, W, dh] (at ``positions`` [B, W]) over one
-    layer's pages, the width of each row's block table gathered
+    layer's pages (within ``window`` positions where the layer has one),
+    the width of each row's block table gathered
     (``paged_read``) for the grouped einsums: [B, kvh, rep, W, dv] (a latent
     layer: one KV head, ``dv`` the latent's rank, the absorbed query's heads
     all on it). What a plain decode step on a TPU does in its place is
@@ -1648,15 +1847,54 @@ def _attend_paged(q, c_layer, block_table, positions, config: TransformerConfig)
     visible = (
         jnp.arange(S)[None, None, :] <= positions[:, :, None]
     )  # [B, W, S]
-    if c.sliding_window is not None:
-        visible &= (
-            jnp.arange(S)[None, None, :]
-            > positions[:, :, None] - c.sliding_window
-        )
+    if window is not None:
+        visible &= jnp.arange(S)[None, None, :] > positions[:, :, None] - window
     scores = jnp.where(visible[:, None, None, :, :], scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1).astype(c.dtype)
     attn = jnp.einsum("bgrws,bgsd->bgrwd", weights, vf)
     return attn.transpose(0, 3, 1, 2, 4).reshape(B, W, nh * vf.shape[-1])
+
+
+def _ring_layer(q, k_new, v_new, cache, index, pos, config: TransformerConfig):
+    """A window layer's decode step over the rows' rings, ``wk`` / ``wv``
+    [window layers, B, window, kvh, dh] of the pool (``index``, traced: the
+    layer's among them): the new token's K/V (q, k_new, v_new [B, heads, 1,
+    dh], at positions ``pos`` [B]) go to slot ``pos mod window`` of their
+    row, B slots written in place on the scan's carry, and the row attends
+    over its ``min(pos + 1, window)`` live slots. A softmax does not mind the
+    order of its keys and the keys carry their rotary position, so the ring
+    is never unrolled; a slot beyond a short row's cursor holds whatever the
+    row's last tenant left and is masked. Gives (attention [B, 1, nh * dh],
+    the pool).
+
+    A slot with all its KV heads is one contiguous block: the layout the
+    TPU compiler scatters B such blocks into in place. (Head-major, [.., kvh,
+    window, dh], it copied the whole leaf slot-major before the scatter and
+    back after it, 0.6 ms of a 16.8 ms step; written head-major as B x kvh
+    rows of dh, each scatter took 0.18 ms, 1.4 ms a step; PERF.md, PR 35.
+    The einsums read a layer's ring head-major, so each layer's is cut out
+    and turned once: a layer's worth, never the leaf.)"""
+    c = config
+    B, nh, _, dh = q.shape
+    wk, wv = cache["wk"], cache["wv"]
+    window, kvh = wk.shape[2:4]
+    rows, slot = jnp.arange(B), pos % window
+    wk = wk.at[index, rows, slot].set(k_new[:, :, 0].astype(wk.dtype))
+    wv = wv.at[index, rows, slot].set(v_new[:, :, 0].astype(wv.dtype))
+    k = lax.dynamic_index_in_dim(wk, index, 0, keepdims=False)  # [B,window,kvh,dh]
+    v = lax.dynamic_index_in_dim(wv, index, 0, keepdims=False)
+    # operands in the ring's dtype, sums in float32: as the paged kernel
+    qg = q[:, :, 0].reshape(B, kvh, nh // kvh, dh).astype(k.dtype)
+    scores = _scaled_scores(jnp.einsum(
+        "bgrd,bsgd->bgrs", qg, k, preferred_element_type=jnp.float32
+    ), c)
+    live = jnp.arange(window)[None, :] <= pos[:, None]  # [B, window]
+    scores = jnp.where(live[:, None, None, :], scores, -jnp.inf)
+    weights = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    attn = jnp.einsum("bgrs,bsgd->bgrd", weights, v)
+    return (
+        attn.reshape(B, 1, nh * dh).astype(c.dtype), {**cache, "wk": wk, "wv": wv}
+    )
 
 
 def _put_layer(stacked: dict, new: dict, index) -> dict:
@@ -1684,34 +1922,36 @@ def _decode_layers(params, h, cache, config: TransformerConfig, attention_layer)
 
     c = config
 
-    def period_step(carry, period_index):
+    def period_step(carry, period_index, n_layers=c.layer_period):
         h, cache = carry
-        for j in range(c.layer_period):
-            kind, of_kind, layer = _pattern_layer(
+        for j in range(n_layers):
+            kind, index, layer = _pattern_layer(
                 params["layers"], c, period_index, j
             )
             if kind == "mamba":
                 mix, ssm, conv = mixer_step(
                     rms_norm(h, layer["ln1"], c.rms_norm_eps), layer, c,
-                    _take_layer(cache["ssm"], of_kind),
-                    _take_layer(cache["conv"], of_kind),
+                    _take_layer(cache["ssm"], index),
+                    _take_layer(cache["conv"], index),
                 )
                 h, _ = _mlp_residual(_residual(h, mix, c), layer, c)
-                cache = _put_layer(cache, {"ssm": ssm, "conv": conv}, of_kind)
+                cache = _put_layer(cache, {"ssm": ssm, "conv": conv}, index)
             else:
-                h, cache = attention_layer(
-                    h, layer, cache, c.n_dense_layers + of_kind
-                )
+                h, cache = attention_layer(h, layer, cache, index, kind)
         return (h, cache), None
 
-    for i in range(c.n_dense_layers):
+    first = c.layer_kinds[:c.n_dense_layers]
+    for i, kind in enumerate(first):
+        part = _pool_part(kind)
         h, cache = attention_layer(
-            h, _take_layer(params["dense_layers"], i), cache, i
+            h, _take_layer(params["dense_layers"], i), cache,
+            sum(k in part for k in first[:i]), kind,
         )
-    (h, cache), _ = lax.scan(
-        period_step, (h, cache), jnp.arange(_n_periods(c))
-    )
-    return h, cache
+    periods, left_over = _n_periods(c)
+    carry, _ = lax.scan(period_step, (h, cache), jnp.arange(periods))
+    if left_over:  # the start of one more period
+        carry, _ = period_step(carry, periods, left_over)
+    return carry
 
 
 def prefill_chunked(

@@ -14,7 +14,8 @@ PR 30); the kernel costs what is LIVE.
 Structure — the flash kernel's online softmax, for one query token a row:
 
 - grid ``(B,)``, one row a step. The STACKED pool leaf ``[layers, n_pages,
-  kvh, ps, dh]`` stays in HBM (``memory_space`` any) in the layout
+  kvh, ps, dh]`` (stacked over the layers that keep pages: every attention
+  layer, or the full layers alone beside window layers' rings) stays in HBM (``memory_space`` any) in the layout
   everything else reads, and the layer is a scalar-prefetched index beside
   the lengths and the block table: no operand is a slice. A page with all
   its KV heads is one contiguous block there, and the kernel copies
@@ -91,10 +92,13 @@ def reads_pages_in_place(
     """THE predicate of how the decode step addresses the pool (``c_layer``:
     the pool, or one layer's slice of it), over what the traced program can
     see: the kernel, writing and reading in place, where the backend is a
-    TPU, the window is one token, the pool has no scale planes, nothing
-    slides, the head fills the lane tile and, under a mesh, the KV heads
-    divide over tp; ``paged_append``, ``paged_read`` and the einsums on the
-    layer's slice otherwise."""
+    TPU, the window is one token, the pool has no scale planes, no
+    ``sliding_window`` is masked over the layer's pages (asked per layer
+    kind: a "full_attention" layer has none, and a "sliding_attention"
+    layer keeps a ring by row and no pages, so it never asks), the head
+    fills the lane tile and, under a mesh, the KV heads divide over tp;
+    ``paged_append``, ``paged_read`` and the einsums on the layer's slice
+    otherwise."""
     if "ckv" in c_layer:  # a latent: one KV head of the slot's width
         kvh, dh = 1, c_layer["ckv"].shape[-1]
     else:

@@ -20,11 +20,14 @@ TPU-first constraints shape the layout:
   token goes through ``ops/paged_attention.py``, a Pallas kernel that takes
   the STACKED leaf and a layer index, writes the new token's page in place
   and copies each row's LIVE pages from where they lie, wherever
-  ``paged_attention.reads_pages_in_place`` holds (a TPU, no scale planes,
-  no sliding window, a head that fills the lane tile): nothing is cut out
+  ``paged_attention.reads_pages_in_place`` holds for the layer (a TPU, no
+  scale planes, no window masked over its pages, a head that fills the
+  lane tile): nothing is cut out
   of the pool, scattered into it or gathered from it. ``paged_append`` and
   ``paged_read`` below are the other path, on one layer's slice inside the
-  same scan: speculative windows, int8 pools, sliding windows, a head of
+  same scan: speculative windows, int8 pools, a ``sliding_window`` over
+  layers that are not told apart (a mask over pages that keep every
+  token), a head of
   64 and the CPU of the tests scatter the new tokens (an XLA scatter,
   which copies the slice and picks its layout) and gather the whole
   block-table width into the [B, kvh, S, dh] view the contiguous attention
@@ -37,6 +40,22 @@ TPU-first constraints shape the layout:
   the kernel copies whole pages in and out, ``seed_prefill`` scatters whole
   pages, the mesh shards the kvh axis. dh is contiguous and page_size defaults to
   a multiple of 8 so slabs keep the (8, 128) tiling XLA wants.
+- **A pool of two parts where the model tells window layers from full
+  ones.** A "sliding_attention" layer (``TransformerConfig.layer_types``)
+  attends within ``sliding_window`` positions, so a row never needs more of
+  it than that: those layers keep a RING by row, ``wk`` / ``wv`` [window
+  layers, max_batch, sliding_window, kvh, dh], a token at slot ``position
+  mod sliding_window`` (at 128 slots, 8 whole pages' worth; a slot with all
+  its KV heads is one contiguous block, at 8 heads of 128 one (8, 128)
+  tile, which the step's scatter writes in place), beside the pages of the
+  full layers, ``k`` / ``v`` stacked
+  over THOSE alone. The block table, ``n_pages`` and the admission's page
+  arithmetic count the full layers only: a row of L tokens holds
+  ``ceil(L / page_size)`` pages whatever the number of window layers. The
+  ring is seeded at admission from the prompt's last positions
+  (``seed_rings``), written a slot a row a step and read whole by the
+  decode program (``transformer._ring_layer``), as part of the one tree it
+  donates, like the recurrent state of mamba layers.
 
 The reference has no serving stack at all (SURVEY §2); this module is part
 of the rebuild's decode family next to the int8 cache (ops/kv_cache.py).
@@ -103,6 +122,11 @@ def pool_telemetry(
     }
 
 
+# what a row of the batch keeps apart from its pages: the mamba layers'
+# state and conv tail, the window layers' rings
+BY_ROW_LEAVES = ("ssm", "conv", "wk", "wv")
+
+
 def paged_page_size(cache: dict) -> int:
     """Slots a page of the pool holds."""
     if "ckv" in cache:
@@ -119,7 +143,11 @@ def alloc_paged_cache(
     config, n_pages: int, page_size: int, sharding=None,
     max_batch: int | None = None,
 ) -> dict:
-    """Zeroed page pool: k/v [attention layers, n_pages, kvh, page_size, dh],
+    """Zeroed page pool: k/v [paged layers, n_pages, kvh, page_size, dh]
+    over the attention layers that keep every token (all of them, unless
+    ``config.layer_types`` names "sliding_attention" layers: those keep
+    ``wk`` / ``wv`` [window layers, max_batch, sliding_window, kvh, dh], a
+    ring by ROW; module docstring),
     or for latent attention (``config.kv_lora_rank``) ONE leaf ``ckv``
     [layers, n_pages, page_size, latent_width], a token's normed latent and
     shared rotary key side by side (keys and values both: the values are
@@ -128,7 +156,8 @@ def alloc_paged_cache(
     the batch beside it (``models/mamba.alloc_state``: ``ssm`` and ``conv``
     over [mamba layers, max_batch, ...]). One tree holds everything a
     request keeps on the device between steps, and the decode program
-    donates it whole. ``max_batch`` is read only where state is kept by row.
+    donates it whole. ``max_batch`` is read only where something is kept by
+    row.
 
     ``sharding`` (one ``jax.sharding.Sharding`` for every leaf — they share
     the leading dims) places the pool where it will live, from host zeros,
@@ -153,7 +182,7 @@ def alloc_paged_cache(
     c = config
     if page_size < 1:
         raise ValueError(f"page_size must be >= 1, got {page_size}")
-    shape = (c.n_attention_layers, n_pages, c.kv_heads, page_size, c.head_dim)
+    shape = (len(c.paged_layers), n_pages, c.kv_heads, page_size, c.head_dim)
 
     def zeros(shape, dtype):
         if sharding is None:
@@ -169,6 +198,17 @@ def alloc_paged_cache(
         return {"ckv": zeros(
             (c.n_attention_layers, n_pages, page_size, c.latent_width), c.dtype
         )}
+    if c.n_mamba_layers or c.window_layers:
+        if max_batch is None:
+            raise ValueError(
+                "a configuration with mamba layers or window layers keeps "
+                "state by row: alloc_paged_cache needs max_batch"
+            )
+        if sharding is not None:
+            raise NotImplementedError(
+                "state kept by row is not sharded: no mesh over mamba "
+                "layers or over window layers' rings"
+            )
     if c.kv_cache_dtype == "int8":
         pool = {
             "k": zeros(shape, jnp.int8),
@@ -181,16 +221,13 @@ def alloc_paged_cache(
     if c.n_mamba_layers:
         from bee_code_interpreter_tpu.models.mamba import alloc_state
 
-        if max_batch is None:
-            raise ValueError(
-                "a configuration with mamba layers keeps state by row: "
-                "alloc_paged_cache needs max_batch"
-            )
-        if sharding is not None:
-            raise NotImplementedError(
-                "state kept by row is not sharded: no mesh over mamba layers"
-            )
         pool.update(alloc_state(c, max_batch, zeros))
+    if c.window_layers:
+        ring = (
+            len(c.window_layers), max_batch, c.sliding_window, c.kv_heads,
+            c.head_dim,
+        )
+        pool.update({"wk": zeros(ring, c.dtype), "wv": zeros(ring, c.dtype)})
     return pool
 
 
@@ -339,3 +376,31 @@ def seed_state(cache: dict, row: jax.Array, ssm: jax.Array, conv: jax.Array) -> 
         return lax.dynamic_update_slice_in_dim(leaf, new.astype(leaf.dtype), row, 1)
 
     return {**cache, "ssm": put(cache["ssm"], ssm), "conv": put(cache["conv"], conv)}
+
+
+def seed_rings(
+    cache: dict, row: jax.Array, k_pre: jax.Array, v_pre: jax.Array,
+    length: jax.Array, window_layers: tuple[int, ...],
+) -> dict:
+    """Replace, whole and in place, the rings of one ``row`` (a traced int32
+    scalar) with the last ``window`` positions of a one-sequence prefill's
+    K/V: ``k_pre`` / ``v_pre`` [attention layers, 1, kvh, Lp, dh] as
+    ``forward(return_kv=True)`` hands them back, of which ``window_layers``
+    (static) keep a ring; ``length`` (traced) is the prompt's TRUE length,
+    not the padded width. Slot s takes the last position p < length with
+    p mod window == s; a slot no position of a short prompt falls on takes
+    position 0's and stays masked until the cursor reaches it
+    (``transformer._ring_layer``). Whatever the row's last tenant left is
+    gone. To be jitted with ``cache`` donated, as ``seed_state``."""
+    window = cache["wk"].shape[2]
+    slots = jnp.arange(window, dtype=jnp.int32)
+    position = jnp.maximum(length - 1 - (length - 1 - slots) % window, 0)
+    layers = jnp.asarray(window_layers, jnp.int32)
+
+    def put(leaf, pre):
+        new = jnp.take(pre[layers, 0], position, axis=2)  # [layers, kvh, window, dh]
+        return lax.dynamic_update_slice_in_dim(
+            leaf, new.transpose(0, 2, 1, 3)[:, None].astype(leaf.dtype), row, 1
+        )
+
+    return {**cache, "wk": put(cache["wk"], k_pre), "wv": put(cache["wv"], v_pre)}

@@ -136,19 +136,23 @@ def test_hidden_act_and_mlp_bias_refused():
         config_from_hf(cfg)
 
 
-def test_non_derived_head_dim_refused():
-    """Checkpoints with an explicit head_dim != hidden_size // n_heads
-    (increasingly common in HF Llama-family configs) must refuse at
-    config construction, not fail later with an opaque reshape error."""
-    cfg = transformers.LlamaConfig(
-        rms_norm_eps=1e-5, hidden_size=64, num_attention_heads=4,
-        head_dim=32,
-    )
-    with pytest.raises(ValueError, match="head_dim"):
-        config_from_hf(cfg)
+def test_non_derived_head_dim_loads_and_matches_transformers():
+    """A checkpoint with an explicit head_dim != hidden_size // n_heads
+    (increasingly common in HF Llama-family configs; refused until
+    ``TransformerConfig.head_dim`` became a field, PR 35) loads at that
+    head size, and the logits match transformers' own forward."""
+    model = tiny_hf(head_dim=32)
+    params, config = load_llama_params(model, dtype=jnp.float32)
+    assert config.head_dim == 32 != config.d_model // config.n_heads
+    assert params["layers"]["wq"].shape == (2, 64, 4 * 32)
+    assert params["layers"]["wk"].shape == (2, 64, 2 * 32)
+    assert params["layers"]["wo"].shape == (2, 4 * 32, 64)
+    ours = np.asarray(forward(params, jnp.asarray(TOKENS), config))
+    np.testing.assert_allclose(ours, hf_logits(model), atol=1e-4, rtol=1e-4)
     # a derived (or absent) head_dim still loads
     cfg = transformers.LlamaConfig(
         rms_norm_eps=1e-5, hidden_size=64, num_attention_heads=4,
         head_dim=16,
     )
     assert config_from_hf(cfg).d_model == 64
+    assert config_from_hf(cfg).head_dim == 16
