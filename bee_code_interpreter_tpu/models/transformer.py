@@ -926,6 +926,29 @@ def _head_qk(x, layer, scale: str, positions, config, kind: str = "attention"):
     return _positioned(x, positions, config, kind)
 
 
+def _project_heads(x, w, heads: int, config: TransformerConfig, delta=None):
+    """The normed ``x`` [B, L, D] through a projection into ``heads`` heads:
+    ONE flat matmul against ``w`` [D, heads * dh] (a plain or weight-only-int8
+    leaf; ``delta``, a LoRA's [B, L, heads * dh], added to its result), then
+    split into heads, [B, heads, L, dh].
+
+    The split sits behind ``lax.optimization_barrier`` so that the flat
+    result is materialised first. Left to itself the TPU compiler folds the
+    split into the dot, which then wants its weight head-major with D minor,
+    where the stacks hold [layers, D, heads * dh]: each layer of each step it
+    cut the layer out of the stack into a buffer, copied the buffer
+    transposed and ran the dot from the copy (1.1 ms of a 13.6 ms decode
+    step at Mistral-7B's widths, 3.2 of 16.2 at K-EXAONE's; PERF.md, PR 36).
+    Kept flat, the dot reads its layer of the stack where it lies, the slice
+    fused into it as for ``wo`` and the MLP. The barrier is the identity, to
+    a gradient too."""
+    B, L = x.shape[:2]
+    out = lax.optimization_barrier(qeinsum("bld,dk->blk", x, w, config.dtype))
+    if delta is not None:
+        out = out + delta
+    return out.reshape(B, L, heads, -1).transpose(0, 2, 1, 3)
+
+
 def _residual(h, branch, config: TransformerConfig):
     """h + residual_multiplier * branch (the multiplier is 1 for every
     architecture but Granite's, and then nothing is multiplied)."""
@@ -960,14 +983,13 @@ def _layer_apply(
         attn = _latent_attention_published(q_nope, q_rope, latent, layer, c, mesh)
     else:
         dh, nh, kvh = c.head_dim, c.n_heads, c.kv_heads
-
-        def proj(w, heads):
-            out = qeinsum("bld,dk->blk", x, w, c.dtype)
-            return out.reshape(B, L, heads, dh).transpose(0, 2, 1, 3)
-
-        q = _head_qk(proj(layer["wq"], nh), layer, "ln_q", positions, c, kind)
-        k = _head_qk(proj(layer["wk"], kvh), layer, "ln_k", positions, c, kind)
-        v = proj(layer["wv"], kvh)
+        q = _head_qk(
+            _project_heads(x, layer["wq"], nh, c), layer, "ln_q", positions, c, kind
+        )
+        k = _head_qk(
+            _project_heads(x, layer["wk"], kvh, c), layer, "ln_k", positions, c, kind
+        )
+        v = _project_heads(x, layer["wv"], kvh, c)
         kv_out = (k, v) if return_kv else None
         # GQA-native: compact k/v go in as-is
         attn = _attention(
@@ -989,14 +1011,10 @@ def _latent_projections(x, layer, config: TransformerConfig, positions):
     what a token keeps, [B, L, kv_lora_rank + qk_rope]: the normed latent
     beside the one rotated key all heads share."""
     c = config
-    B, L = x.shape[:2]
     rank, eps = c.kv_lora_rank, c.rms_norm_eps
-    q = qeinsum("bld,dk->blk", x, layer["wq"], c.dtype).reshape(
-        B, L, c.n_heads, c.qk_head_dim
-    )
+    q = _project_heads(x, layer["wq"], c.n_heads, c)  # [B, nh, L, qk_head_dim]
     if c.qk_norm:
         q = rms_norm(q, layer["ln_q"], eps)
-    q = q.transpose(0, 2, 1, 3)
     q_nope, q_rope = q[..., :c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
     kva = qeinsum("bld,dk->blk", x, layer["w_kva"], c.dtype)
     latent = rms_norm(kva[..., :rank], layer["ln_kv"], eps)
@@ -1545,14 +1563,13 @@ def decode_window(
         layer, c_layer = scanned
         x = rms_norm(h, layer["ln1"], c.rms_norm_eps)
         dh, nh, kvh = c.head_dim, c.n_heads, c.kv_heads
-
-        def proj(w, heads):
-            out = qeinsum("bld,dk->blk", x, w, c.dtype)
-            return out.reshape(B, W, heads, dh).transpose(0, 2, 1, 3)
-
-        q = _head_qk(proj(layer["wq"], nh), layer, "ln_q", positions, c)
-        k_new = _head_qk(proj(layer["wk"], kvh), layer, "ln_k", positions, c)
-        v_new = proj(layer["wv"], kvh)
+        q = _head_qk(
+            _project_heads(x, layer["wq"], nh, c), layer, "ln_q", positions, c
+        )
+        k_new = _head_qk(
+            _project_heads(x, layer["wk"], kvh, c), layer, "ln_k", positions, c
+        )
+        v_new = _project_heads(x, layer["wv"], kvh, c)
         from bee_code_interpreter_tpu.ops.kv_cache import (
             cache_append,
             cache_read,
@@ -1650,6 +1667,14 @@ def decode_window_paged(
     the ring (``_ring_layer``; a window of one token only). Either way the
     pool is the CARRY of the one layer scan (``_decode_layers``), so the
     donated input is updated in place and never copied through the scan.
+
+    The weights are read where they lie too. A layer's projection is a dot
+    against its slice of the stack with the slice fused into the dot; for
+    the projections into heads that holds because the split into heads
+    happens on the dot's materialised flat result (``_project_heads``):
+    folded into the dot, as the TPU compiler folds it when it may, the split
+    asks for the weight head-major, and the layer was cut out of the stack
+    into a buffer and copied transposed every step (PERF.md, PR 36).
 
     ``lora_bank`` enables MULTI-LoRA serving (S-LoRA style): a stacked
     bank of adapters for the attention projections, with ``adapter_idx``
@@ -1764,11 +1789,7 @@ def decode_window_paged(
             ) * jnp.asarray(lora_scale, c.dtype)
 
         def proj(w, heads, name):
-            out = qeinsum("bld,dk->blk", x, w, c.dtype)
-            delta = lora_delta(x, name)
-            if delta is not None:
-                out = out + delta
-            return out.reshape(B, W, heads, dh).transpose(0, 2, 1, 3)
+            return _project_heads(x, w, heads, c, lora_delta(x, name))
 
         q = _head_qk(proj(layer["wq"], nh, "wq"), layer, "ln_q", positions, c, kind)
         k_new = _head_qk(
@@ -1912,7 +1933,10 @@ def _decode_layers(params, h, cache, config: TransformerConfig, attention_layer)
     """THE layer scan of a decode step: a period of the layer pattern an
     iteration (one attention layer where none is declared), the whole pool
     the carry beside ``h``. Each layer takes its weights out of the stacks
-    at its index and updates its own part of the pool in place
+    at its index (a slice the compiler fuses into the dot that reads it, so
+    a projection reads its layer of the stack where it lies and nothing is
+    cut into a buffer: ``_project_heads`` says what that takes of a
+    projection into heads) and updates its own part of the pool in place
     (``attention_layer(h, layer, cache, index)``: the K/V pages of its
     attention layer; here, the rows' state of its mamba layer), so the pool
     is never a scan input or output and the donated buffer is the one the
