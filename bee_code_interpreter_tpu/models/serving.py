@@ -68,6 +68,7 @@ pool: admit, run isolated, recycle.
 from __future__ import annotations
 
 import functools
+import gc
 import hashlib
 import time
 from collections import OrderedDict, deque
@@ -539,6 +540,21 @@ def rejection_sample_commit(
     return commit, n
 
 
+def _phase_key(name: str) -> str:
+    """A ``serve.*`` span's key in the ``phase_ms`` of the record it runs
+    in. Under the record's own span the rest of its name
+    (``serve.step.wait``: ``wait``; ``serve.admit.pull``: ``pull``;
+    ``serve.admit`` itself ``admit``, which the admission's record turns
+    into its ``duration_ms``); under any other span its own name, so that
+    the drain inside an admission (``serve.land``, ``serve.land.pull``)
+    lands in ``land`` and ``land_pull`` and not in the admission's
+    ``pull``."""
+    key = name.removeprefix("serve.")
+    if key.startswith(("step.", "admit.")):
+        key = key.partition(".")[2]
+    return key.replace(".", "_")
+
+
 class ContinuousBatcher:
     """Admit → step → collect loop over ``decode_step_paged``.
 
@@ -953,9 +969,13 @@ class ContinuousBatcher:
         self._steps_ahead = 0
         self._steps_synchronous = 0
         self._discarded_tokens = 0
-        # the step being recorded's phase -> ms (see _phase); a dict only
-        # inside a step with a lifecycle monitor attached, None otherwise
+        # the phase -> ms of the step or the blocking admission being
+        # recorded (see _recording); a dict only inside one with a
+        # lifecycle monitor attached, None otherwise
         self._phase_ms: dict[str, float] | None = None
+        # of the record being made: when the full collection under way
+        # began, and the milliseconds of those that have ended
+        self._gc_t0 = self._gc_ms = 0.0
         if metrics is not None:
             from bee_code_interpreter_tpu.utils.metrics import (
                 TOKEN_LATENCY_BUCKETS,
@@ -1121,14 +1141,15 @@ class ContinuousBatcher:
         observability"): always a ``jax.profiler.TraceAnnotation``, which
         is a flag test while no profiler session is open and an event on
         the device trace's clock while one is; and, while a monitored
-        ``step`` is recording, the span's milliseconds under the last part
-        of its name in that step's ``phase_ms``."""
+        ``step`` or blocking admission is recording, the span's
+        milliseconds under its key (``_phase_key``) in that record's
+        ``phase_ms``."""
         phases = self._phase_ms
         with jax.profiler.TraceAnnotation(name, **stats):
             if phases is None:
                 yield
                 return
-            key = name.rpartition(".")[2]
+            key = _phase_key(name)
             phases.setdefault(key, 0.0)  # keys in the order the phases began
             t0 = time.perf_counter()
             try:
@@ -1136,14 +1157,16 @@ class ContinuousBatcher:
             finally:
                 phases[key] += (time.perf_counter() - t0) * 1000.0
 
-    def _clocked(self, fn, key: str):
-        """``fn`` itself, or, while a monitored ``step`` is recording, ``fn``
-        with its calls' milliseconds summed under ``key`` in ``phase_ms``
-        (two clock reads a call; no span: a row's pick is too small a
-        thing to put on the trace)."""
+    def _clocked(self, fn, under: str, part: str):
+        """``fn`` itself, or, while a monitored ``step`` or admission is
+        recording, ``fn`` with its calls' milliseconds summed in
+        ``phase_ms`` under the key of ``part`` of the span ``under`` (two
+        clock reads a call; no span: a row's pick is too small a thing to
+        put on the trace)."""
         phases = self._phase_ms
         if phases is None:
             return fn
+        key = _phase_key(f"{under}.{part}")
         phases[key] = 0.0
 
         def clocked(*args):
@@ -1154,6 +1177,39 @@ class ContinuousBatcher:
                 phases[key] += (time.perf_counter() - t0) * 1000.0
 
         return clocked
+
+    @contextmanager
+    def _recording(self):
+        """The ``phase_ms`` of ONE record, a step's or a blocking
+        admission's, while a lifecycle monitor has it made: ``_phase`` and
+        ``_clocked`` fill it, and a full collection of Python's garbage
+        that falls inside it is named in it (``_gc_pause``). Nothing of
+        this exists outside a monitored record."""
+        phases = self._phase_ms = {}
+        self._gc_ms = 0.0
+        gc.callbacks.append(self._gc_pause)
+        try:
+            yield phases
+        finally:
+            self._phase_ms = None
+            gc.callbacks.remove(self._gc_pause)
+            if self._gc_ms:  # the last key, whenever it came
+                phases["gc"] = self._gc_ms
+
+    def _gc_pause(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook (``_recording``): the milliseconds of the
+        FULL collections (generation 2: the ones that walk every object
+        the process keeps and stop it for a tenth of a second; the young
+        ones take microseconds) under ``gc`` in the record being made.
+        The pause lies inside whatever phase was allocating when it came,
+        a wait in the runtime's code as likely as the host's own loop: it
+        is beside the phases like ``sample_choose``, not one of them."""
+        if info["generation"] < 2:
+            return
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        else:
+            self._gc_ms += (time.perf_counter() - self._gc_t0) * 1000.0
 
     def set_device_monitor(self, monitor) -> None:
         """Attach (or detach, with None) a compile/step telemetry monitor
@@ -1670,8 +1726,10 @@ class ContinuousBatcher:
             self.results[req] = []
             self.done[req] = False
             self.prefill_state[row] = rec  # step drives it (_advance_prefills)
-        else:
+        elif self._monitor is None:
             self._admit_blocking(row, rec)
+        else:
+            self._admit_observed(row, rec)
         return req
 
     def _admit_blocking(self, row: int, rec: dict) -> None:
@@ -1693,6 +1751,44 @@ class ContinuousBatcher:
                 pass
             with self._phase("serve.admit.activate"):
                 self._activate_row(row, rec)
+
+    def _admit_observed(self, row: int, rec: dict) -> None:
+        """``_admit_blocking`` under the attached lifecycle monitor: the
+        admission as ONE record, the step record's sibling, handed to
+        ``on_admitted`` (docs/observability.md "Serving observability").
+        ``duration_ms`` is the ``serve.admit`` span and ``phase_ms`` the
+        spans that ran inside it, in the order they began (``_phase``);
+        what the top-level ones leave of ``duration_ms`` is unspanned: next
+        to nothing of a one-shot admission, and of one through ``windows``
+        (``_admit_next``'s loop, which draws no spans) the windows
+        themselves. A failed admission (``_admission``'s handler) raises
+        through here and leaves no record. Only the monitored path enters
+        this frame: the unmonitored one calls ``_admit_blocking`` from
+        ``submit`` as before (PERF.md, PR 31: the depth of the Python stack
+        at a first compile is part of ``setup_s``)."""
+        decoding_rows = int(np.count_nonzero(self.active))
+        pages = len(rec["pages"])  # the activation hands them to the row
+        with self._recording() as phases:
+            self._admit_blocking(row, rec)
+        padded = rec["pos"] - rec["start"]
+        self._monitor.on_admitted(rec["req"], {
+            "req": rec["req"],
+            "row": row,
+            "prompt_tokens": int(rec["prompt"].shape[0]),
+            # what ran through the model, padding included (a prefix hit's
+            # matched pages did not)
+            "padded_tokens": padded,
+            "pages": pages,
+            # 0: the one-shot program
+            "windows": (
+                0 if rec["width"] is None else -(-padded // rec["width"])
+            ),
+            # the rows it stalls, and whether it landed the step in flight
+            "decoding_rows": decoding_rows,
+            "landed_step": "land" in phases,
+            "duration_ms": phases.pop("admit"),
+            "phase_ms": phases,
+        })
 
     def _held_pairs_stat(self, tokens: int) -> dict:
         """For a configuration whose expert layers hold a share of the
@@ -1955,10 +2051,9 @@ class ContinuousBatcher:
         causal-masked for every row < L, so logits[L-1] and K/V[:L] are
         exact, and distinct prompt lengths share a program per page count
         instead of one per length."""
-        pages_arr = jnp.asarray(
-            pages[: padded.shape[1] // self.page_size], dtype=jnp.int32
-        )
-        with self._phase("serve.admit.prefill"):
+        with self._phase(
+            "serve.admit.prefill", padded_tokens=padded.shape[1]
+        ):
             # K and V [layers, 1, kvh, Lp, dh], or a latent [layers, 1, Lp,
             # width] alone (no V: ``v_pre`` is empty)
             if self._seed_state is None:
@@ -1970,24 +2065,24 @@ class ContinuousBatcher:
         if self._seed_rings is not None:
             # the window layers' K/V of the last ``sliding_window`` real
             # positions into the row's rings; the pages take the rest
-            with self._phase(
-                "serve.admit.seed_window", rows=1,
-                bytes=self._ring_bytes_per_row,
-            ):
+            with self._phase("serve.admit.seed_window"):
                 self.cache = self._seed_rings(
                     self.cache, np.int32(row), k_pre, v_pre[0], np.int32(L)
                 )
-            k_pre, v_pre = k_pre[self._paged_layers], [v_pre[0][self._paged_layers]]
         with self._phase("serve.admit.seed_pool"):
+            if self._seed_rings is not None:
+                k_pre, v_pre = (
+                    k_pre[self._paged_layers], [v_pre[0][self._paged_layers]]
+                )
+            pages_arr = jnp.asarray(
+                pages[: padded.shape[1] // self.page_size], dtype=jnp.int32
+            )
             self.cache = seed_prefill(
                 self.cache, pages_arr,
                 k_pre[:, 0, ..., :L, :], *[v[:, 0, :, :L, :] for v in v_pre],
             )
         if self._seed_state is not None:
-            with self._phase(
-                "serve.admit.seed_state", rows=1,
-                bytes=ssm.nbytes + conv.nbytes,
-            ):
+            with self._phase("serve.admit.seed_state"):
                 self.cache = self._seed_state(
                     self.cache, np.int32(row), ssm, conv
                 )
@@ -2175,13 +2270,12 @@ class ContinuousBatcher:
         alloc_before = self._pages_allocated
         released_before = self._pages_released
         discarded_before = self._discarded_tokens
-        phase_ms = self._phase_ms = {} if self._monitor is not None else None
-        t0 = time.monotonic()
-        try:
+        with (
+            nullcontext() if self._monitor is None else self._recording()
+        ) as phase_ms:
+            t0 = time.monotonic()
             self._step_inner(ahead)
-        finally:
-            self._phase_ms = None
-        t1 = time.monotonic()
+            t1 = time.monotonic()
         produced = self.n_tokens_generated - tokens_before
         if self._metrics is not None:
             self._step_seconds.observe(t1 - t0)
@@ -2208,7 +2302,6 @@ class ContinuousBatcher:
                     # the phases that ran (_phase; an absent key did not);
                     # what they leave of duration_ms is unspanned
                     "phase_ms": phase_ms,
-                    "mesh": self._mesh_key,
                     # dispatched before the step before it was landed: its
                     # decode_tokens are that step's, landed here
                     "ahead": ahead,
@@ -2408,8 +2501,7 @@ class ContinuousBatcher:
         return {
             "rows": rows, "requests": self.row_request[rows],
             "outlives": outlives, "answers": answers, "logits": lg,
-            "next": tuple(fed), "device_rows": len(device_rows),
-            "host_rows": len(host_rows),
+            "next": tuple(fed), "host_rows": len(host_rows),
         }
 
     def _land(self) -> None:
@@ -2440,13 +2532,9 @@ class ContinuousBatcher:
             log_z = log_z.view(np.float32).astype(np.float64)
             if lg is not None:
                 lg = np.asarray(lg, dtype=np.float32)
-        with self._phase(
-            f"{under}.sample",
-            device_picked_rows=step["device_rows"],
-            host_picked_rows=step["host_rows"],
-        ):
-            choose = self._clocked(choose_host, "sample_choose")
-            logprob = self._clocked(logprob_of, "sample_logprob")
+        with self._phase(f"{under}.sample"):
+            choose = self._clocked(choose_host, under, "sample.choose")
+            logprob = self._clocked(logprob_of, under, "sample.logprob")
             for row, req_row in zip(step["rows"], step["requests"]):
                 if self.row_request[row] != req_row:
                     # the row ended at the landing before this one, by eos

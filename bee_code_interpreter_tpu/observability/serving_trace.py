@@ -22,6 +22,9 @@ already uses — no parallel pipeline:
   prefill vs decode token counts, speculative accept/reject counts, page
   churn, and step wall time — served raw at ``GET /v1/serving`` so a
   tokens/sec dip can be read step by step instead of inferred from gauges.
+  The records of the blocking admissions since the step before ride on
+  the next step record under ``admissions`` (``on_admitted``): what each
+  cost, phase by phase, the queue before it and the rows it stalled.
 - **KV-cache telemetry** via the batcher's ``kv_telemetry()``
   (ops/paged_kv_cache.pool_telemetry): slot-level internal fragmentation
   and prefix-chain reuse hits/misses.
@@ -71,6 +74,7 @@ class _RequestRecord:
         "prefix_pages", "adapter", "speculative", "interleaved",
         "prefill_chunks", "prefill_tokens", "spec_accepted",
         "spec_rejected", "queued_ms", "requeues", "ttft_ms",
+        "admit_ms", "admit_phase_ms",
         "output_tokens", "finish", "outcome", "duration_ms", "error",
     )
 
@@ -95,6 +99,8 @@ class _RequestRecord:
         self.queued_ms = None
         self.requeues = 0
         self.ttft_ms = None
+        self.admit_ms = None
+        self.admit_phase_ms = None
         self.output_tokens = 0
         self.finish = None
         self.outcome = None
@@ -121,6 +127,8 @@ class _RequestRecord:
             "queued_ms": self.queued_ms,
             "requeues": self.requeues,
             "ttft_ms": self.ttft_ms,
+            "admit_ms": self.admit_ms,
+            "admit_phase_ms": self.admit_phase_ms,
             "finish": self.finish,
             "outcome": self.outcome,
             "duration_ms": (
@@ -156,6 +164,8 @@ class ServingMonitor:
         self._done: deque[_RequestRecord] = deque(maxlen=max(1, max_requests))
         self._steps: deque[dict] = deque(maxlen=max(1, max_steps))
         self._step_seq = 0
+        # admission records (on_admitted) waiting for the next step record
+        self._admitted: list[dict] = []
         self._tickets: dict[int, tuple[float, int]] = {}  # ticket -> (t, requeues)
         # queue wait staged by on_ticket_admitting for the on_submit fired
         # inside the engine's synchronous batcher.submit call (one slot:
@@ -354,6 +364,39 @@ class ServingMonitor:
                 "decode", parent_id=rec.trace.root.span_id
             )
 
+    def on_admitted(self, req: int, record: dict) -> None:
+        """A blocking admission's record (``ContinuousBatcher.
+        _admit_observed``), after the admission: it gains ``queued_ms``
+        (the wait ``on_ticket_admitting`` staged, engine intake to the
+        admission's start; absent without an engine), the request keeps
+        ``admit_ms`` / ``admit_phase_ms``, its ``prefill`` span is split
+        into one ``admit.<phase>`` child a top-level phase (the phases'
+        own lengths, laid end to end from the span's start in the order
+        they began: they ran one after another, and what no phase covers
+        is missing between them), and the record waits for the next step
+        record, which carries it under ``admissions``."""
+        with self._lock:
+            rec = self._live.get(req)
+            if rec is None and self._done and self._done[-1].req == req:
+                rec = self._done[-1]  # it ended at its first token
+            if rec is not None:
+                if rec.queued_ms is not None:
+                    record["queued_ms"] = rec.queued_ms
+                rec.admit_ms = record["duration_ms"]
+                rec.admit_phase_ms = record["phase_ms"]
+                parent, at = rec.prefill_span, 0.0
+                for phase, ms in record["phase_ms"].items():
+                    if phase.startswith("land_") or phase == "gc":
+                        continue  # inside ``land``; inside whichever it hit
+                    s = rec.trace.start_span(
+                        f"admit.{phase}", parent_id=parent.span_id
+                    )
+                    s.start_mono = parent.start_mono + at
+                    s.start_unix = parent.start_unix + at
+                    s.duration_s = ms / 1000.0
+                    at += s.duration_s
+            self._admitted.append(record)
+
     def on_commit(self, req: int, *, accepted: int, rejected: int) -> None:
         with self._lock:
             self._spec_accepted_total += accepted
@@ -415,6 +458,8 @@ class ServingMonitor:
             record["ts"] = time.time()
             if self._engine is not None:
                 record["queue_depth"] = self._engine.pending
+            if self._admitted:
+                record["admissions"], self._admitted = self._admitted, []
             self._steps.append(record)
 
     # ------------------------------------------------------- engine hooks
@@ -591,6 +636,8 @@ class ServingMonitor:
             "spec_rejected": rec.spec_rejected,
             "requeues": rec.requeues,
             "ttft_ms": rec.ttft_ms,
+            "admit_ms": rec.admit_ms,
+            "admit_phase_ms": rec.admit_phase_ms,
             "finish": rec.finish,
         }
         event: dict = {

@@ -162,13 +162,28 @@ def render_steps(snap: dict) -> str:
         for phase, ms in (s.get("phase_ms") or {}).items():
             phases.setdefault(phase, []).append(ms)
     if phases:
+        lines.append("  phase p50: " + fmt_medians(phases))
+    # and the blocking admissions that rode on them (the serve.admit.*
+    # spans; a drain inside one is land and land_*): what one cost, whole
+    # and by phase
+    admitted: dict[str, list[float]] = {}
+    for s in last:
+        for a in s.get("admissions") or ():
+            admitted.setdefault("admit", []).append(a["duration_ms"])
+            for phase, ms in a["phase_ms"].items():
+                admitted.setdefault(phase, []).append(ms)
+    if admitted:
         lines.append(
-            "  phase p50: " + "  ".join(
-                f"{phase} {fmt_ms(statistics.median(ms))}"
-                for phase, ms in phases.items()
-            )
+            f"  admit p50 ({len(admitted['admit'])}): " + fmt_medians(admitted)
         )
     return "\n".join(lines)
+
+
+def fmt_medians(by_phase: dict[str, list[float]]) -> str:
+    return "  ".join(
+        f"{phase} {fmt_ms(statistics.median(ms))}"
+        for phase, ms in by_phase.items()
+    )
 
 
 def render_requests(rows: list[dict]) -> str:

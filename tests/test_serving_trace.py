@@ -7,6 +7,7 @@ and its `bci_serving_ttft_seconds` exemplar all share one trace_id."""
 
 import dataclasses
 import functools
+import gc
 import json
 import re
 import time
@@ -422,6 +423,17 @@ def test_speculative_commit_accounting():
 
 # ------------------------------------------------ phases, spans, program names
 
+@pytest.fixture
+def no_full_collections():
+    """A full collection of Python's garbage inside a monitored record adds
+    the key ``gc`` to its ``phase_ms``: the tests that hold a record to an
+    exact list of keys run with the collector off."""
+    gc.collect()
+    gc.disable()
+    yield
+    gc.enable()
+
+
 GREEDY = SamplingParams()
 SAMPLED = SamplingParams(temperature=0.8, top_p=0.95, seed=3, logprobs=True)
 MIXES = {
@@ -461,6 +473,7 @@ def decoded(mix: str, monitored: bool):
 
 
 @pytest.mark.parametrize("mix", MIXES)
+@pytest.mark.usefixtures("no_full_collections")
 def test_phase_ms_splits_every_decode_step(mix):
     _, steps, batcher = decoded(mix, True)
     plain = [s for s in steps if s["phase_ms"]]
@@ -530,6 +543,269 @@ def test_unmonitored_batcher_keeps_no_phase_state_and_the_same_tokens(mix):
     assert tokens == decoded(mix, True)[0]
 
 
+# ------------------------------------------------------ the admission's record
+
+# a hybrid (state by row) and window layers' rings beside pages, in small:
+# tests/test_hybrid_serving.py's and tests/test_window_full_serving.py's
+HYBRID = T.TransformerConfig(
+    vocab_size=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=2, d_ff=128,
+    max_seq_len=64, layer_types=("mamba", "attention", "mamba"),
+    mamba_n_heads=8, mamba_d_head=16, mamba_d_state=16, mamba_chunk_size=32,
+    position_embedding="nope", tie_embeddings=True, dtype=jnp.float32,
+)
+RINGS = T.TransformerConfig(
+    vocab_size=256, d_model=48, n_layers=3, n_heads=4, n_kv_heads=2,
+    head_dim=8, d_ff=96, max_seq_len=64, dtype=jnp.float32, sliding_window=8,
+    layer_types=("sliding_attention", "full_attention", "sliding_attention"),
+    position_embedding="rope_window",
+)
+ADMIT_TOP = (
+    "prefill", "seed_window", "seed_pool", "seed_state", "land", "pull",
+    "activate",
+)
+DRAIN = (
+    "land_wait", "land_pull", "land_sample", "land_sample_choose",
+    "land_sample_logprob",
+)
+
+
+def admissions_of(mon) -> list[dict]:
+    return [
+        a for s in mon.snapshot(steps=512)["steps"]["last"]
+        for a in s.get("admissions", ())
+    ]
+
+
+def unspanned_ms(record: dict) -> float:
+    return record["duration_ms"] - sum(
+        record["phase_ms"].get(k, 0.0) for k in ADMIT_TOP
+    )
+
+
+@pytest.mark.parametrize("kind, config, seeds", [
+    ("dense", CFG, ()),
+    ("hybrid", HYBRID, ("seed_state",)),
+    ("rings", RINGS, ("seed_window",)),
+])
+@pytest.mark.usefixtures("no_full_collections")
+def test_an_admission_is_one_record_with_the_phases_that_ran(
+    kind, config, seeds
+):
+    params = PARAMS if kind == "dense" else T.init_params(
+        config, jax.random.PRNGKey(1)
+    )
+    mon = ServingMonitor()
+    batcher = ContinuousBatcher(
+        params, config, max_batch=2, n_pages=32, page_size=4,
+        max_pages_per_seq=8,
+    )
+    mon.attach(batcher)
+    first = batcher.submit(SHORT, 4)
+    batcher.step()  # leaves a step in flight: the next admission lands it
+    second = batcher.submit(LONG, 4, sampling=SAMPLED)
+    assert batcher._phase_ms is None
+    batcher.run_to_completion()
+    a, b = admissions_of(mon)
+    assert [a["req"], b["req"]] == [first, second]
+    assert (a["row"], a["prompt_tokens"], a["padded_tokens"], a["pages"]) == (
+        0, 4, 4, 2
+    )
+    assert (b["row"], b["prompt_tokens"], b["padded_tokens"], b["pages"]) == (
+        1, 21, 24, 7
+    )
+    assert (a["decoding_rows"], a["landed_step"]) == (0, False)
+    assert (b["decoding_rows"], b["landed_step"]) == (1, True)
+    assert a["windows"] == b["windows"] == 0
+    # a bare batcher has no queue: nothing staged a wait
+    assert "queued_ms" not in a and "queued_ms" not in b
+    # the spans that ran, in the order they began; the drain's phases under
+    # their own names, so that its pull is not the admission's
+    one_shot = [
+        k for k in ADMIT_TOP
+        if k in ("prefill", "seed_pool", "pull", "activate", *seeds)
+    ]
+    assert list(a["phase_ms"]) == one_shot
+    at = one_shot.index("pull")
+    assert list(b["phase_ms"]) == (
+        one_shot[:at] + ["land", *DRAIN] + one_shot[at:]
+    )
+    assert b["phase_ms"]["pull"] > 0.0 and b["phase_ms"]["land_pull"] > 0.0
+    for record in (a, b):
+        phases = record["phase_ms"]
+        assert all(ms >= 0.0 for ms in phases.values())
+        # the top-level phases lie inside the admission, one after another
+        assert 0.0 <= unspanned_ms(record) < 0.5 * record["duration_ms"]
+    drain = b["phase_ms"]
+    assert (
+        drain["land_wait"] + drain["land_pull"] + drain["land_sample"]
+        <= drain["land"]
+    )
+    assert drain["land_sample_logprob"] == 0.0  # the row it landed is greedy
+    # the records ride on the step record after them, and on no other
+    steps = mon.snapshot(steps=512)["steps"]["last"]
+    assert [len(s.get("admissions", ())) for s in steps[:2]] == [1, 1]
+    assert not any("admissions" in s for s in steps[2:])
+
+
+@pytest.mark.usefixtures("no_full_collections")
+def test_two_admissions_in_one_engine_step_and_what_the_request_keeps():
+    engine, mon, _, store, recorder = monitored_stack()
+    tickets = [engine.submit(SHORT, 3), engine.submit(LONG, 3)]
+    engine.step()
+    (step,) = mon.snapshot(steps=8)["steps"]["last"]
+    a, b = step["admissions"]
+    assert (a["decoding_rows"], b["decoding_rows"]) == (0, 1)
+    assert not a["landed_step"] and not b["landed_step"]
+    # the second waited out the first's admission in the queue
+    assert 0.0 <= a["queued_ms"] < b["queued_ms"]
+    assert b["queued_ms"] >= a["duration_ms"]
+    engine.run_to_completion()
+    for ticket in tickets:
+        engine.release(ticket)
+    rows = {r["request_id"]: r for r in mon.requests()}
+    events = {
+        int(e["request_id"].rpartition("-")[2]): e
+        for e in recorder.events(kind="serving")
+    }
+    for record in (a, b):
+        row = rows[record["req"]]
+        assert row["admit_ms"] == record["duration_ms"]
+        assert row["admit_phase_ms"] == record["phase_ms"]
+        assert row["queued_ms"] == record["queued_ms"]
+        assert row["ttft_ms"] >= row["queued_ms"] + row["admit_ms"] * 0.9
+        serving = events[record["req"]]["serving"]
+        assert serving["admit_ms"] == record["duration_ms"]
+        assert serving["admit_phase_ms"] == record["phase_ms"]
+        # the request's prefill span is split by the top-level phases
+        trace = store.get(row["trace_id"])
+        prefill = next(s for s in trace.spans if s.name == "prefill")
+        parts = [s for s in trace.spans if s.parent_id == prefill.span_id]
+        assert [s.name for s in parts] == [
+            f"admit.{k}" for k in record["phase_ms"]
+        ]
+        assert [s.duration_ms for s in parts] == pytest.approx(
+            list(record["phase_ms"].values())
+        )
+        assert parts[0].start_mono == prefill.start_mono
+        for before, after in zip(parts, parts[1:]):
+            assert after.start_mono == pytest.approx(
+                before.start_mono + before.duration_s
+            )
+
+
+@pytest.mark.usefixtures("no_full_collections")
+def test_an_admission_through_windows_has_them_in_its_unspanned_part():
+    engine, mon, *_ = monitored_stack()
+    ticket = engine.submit(LONG, 3, prefill_chunk=4)
+    engine.run_to_completion()
+    assert len(engine.result(ticket)) == 3
+    (record,) = admissions_of(mon)
+    # 21 tokens through windows of one page: six, the last one padded
+    assert (record["windows"], record["padded_tokens"]) == (6, 24)
+    assert list(record["phase_ms"]) == ["activate"]
+    assert unspanned_ms(record) > record["phase_ms"]["activate"]
+    assert unspanned_ms(record) > 0.5 * record["duration_ms"]
+
+
+@pytest.mark.usefixtures("no_full_collections")
+def test_a_failed_admission_leaves_no_half_record():
+    engine, mon, *_ = monitored_stack()
+    batcher = engine.batcher
+    bad = SamplingParams(temperature=1.0, top_k=CFG.vocab_size + 1)
+    with pytest.raises(Exception):
+        batcher.submit(SHORT, 4, sampling=bad)  # the first-token draw fails
+    assert batcher._phase_ms is None and not batcher.active.any()
+    good = batcher.submit(SHORT, 2)
+    batcher.run_to_completion()
+    (record,) = admissions_of(mon)
+    assert record["req"] == good
+    assert list(record["phase_ms"]) == [
+        "prefill", "seed_pool", "pull", "activate",
+    ]
+    failed = [r for r in mon.requests() if r["outcome"] == "error"]
+    assert len(failed) == 1 and failed[0]["admit_ms"] is None
+
+
+def test_a_full_collection_inside_a_record_is_named_in_it(monkeypatch):
+    from bee_code_interpreter_tpu.models import serving
+
+    hooked, choose_host = [], serving.choose_host
+
+    def collecting(*args):
+        hooked.append(engine.batcher._gc_pause in gc.callbacks)
+        gc.collect()  # a full one, as the stalls on the chip are suspected
+        return choose_host(*args)
+
+    monkeypatch.setattr(serving, "choose_host", collecting)
+    engine, mon, *_ = monitored_stack()
+    steered = SamplingParams(temperature=0.8, seed=5, logit_bias={3: 2.0})
+    engine.batcher.submit(SHORT, 3, sampling=steered)
+    engine.batcher.run_to_completion()
+    (admission,) = admissions_of(mon)
+    phases = admission["phase_ms"]
+    # beside the phases, inside the one it hit, never one of them
+    assert 0.0 < phases["gc"] <= phases["activate"]
+    assert unspanned_ms(admission) >= 0.0
+    steps = [
+        s for s in mon.snapshot(steps=64)["steps"]["last"]
+        if s["decode_tokens"]
+    ]
+    assert steps and all(
+        0.0 < s["phase_ms"]["gc"] <= s["phase_ms"]["sample_choose"]
+        for s in steps
+    )
+    # registered while a record is being made, and at no other time
+    assert hooked and all(hooked)
+    assert engine.batcher._gc_pause not in gc.callbacks
+    # the request's prefill span is split by phases, and this is none
+    (row,) = mon.requests()
+    assert "gc" in row["admit_phase_ms"]
+
+
+def test_without_a_monitor_an_admission_reads_no_clock_and_keeps_no_state(
+    monkeypatch,
+):
+    import types
+
+    from bee_code_interpreter_tpu.models import serving
+
+    reads = []
+    monkeypatch.setattr(serving, "time", types.SimpleNamespace(
+        monotonic=time.monotonic,  # submit's TTFT anchor, as before
+        perf_counter=lambda: reads.append(1) or time.perf_counter(),
+    ))
+    batcher = ContinuousBatcher(
+        PARAMS, CFG, max_batch=2, n_pages=32, page_size=4,
+        max_pages_per_seq=8,
+    )
+    seen = []
+    activate = batcher._activate_row
+    monkeypatch.setattr(
+        batcher, "_activate_row",
+        lambda row, rec: seen.append(
+            (batcher._phase_ms, batcher._gc_pause in gc.callbacks)
+        ) or activate(row, rec),
+    )
+    reqs = [
+        batcher.submit(SHORT, 5),
+        batcher.submit(LONG, 5, sampling=SAMPLED, prefill_chunk=4),
+    ]
+    batcher.run_to_completion()
+    assert seen == [(None, False)] * 2 and batcher._phase_ms is None
+    assert not reads
+    # ... and with one, it reads it: the stand-in does count
+    engine, mon, *_ = monitored_stack()
+    monitored = [
+        engine.batcher.submit(SHORT, 5),
+        engine.batcher.submit(LONG, 5, sampling=SAMPLED, prefill_chunk=4),
+    ]
+    engine.batcher.run_to_completion()
+    assert reads and len(admissions_of(mon)) == 2
+    assert [batcher.result(r) for r in reqs] == [
+        engine.batcher.result(r) for r in monitored
+    ]
+
+
 class SpanSpy:
     """Stands in for ``jax.profiler.TraceAnnotation``: every span with its
     stats and the spans that were open when it was entered."""
@@ -572,6 +848,9 @@ def test_serve_spans_nest_and_carry_their_stats(monkeypatch, mix):
         {"req": reqs[0], "prompt_tokens": len(SHORT), "pages": 2},
         {"req": reqs[1], "prompt_tokens": len(LONG), "pages": 6},
     ]
+    # the one-shot program's width: the prompt padded to whole pages
+    prefills = [s for name, s, _ in spans if name == "serve.admit.prefill"]
+    assert prefills == [{"padded_tokens": 4}, {"padded_tokens": 24}]
     names = [name for name, _, _ in spans]
     assert names[:5] == [
         "serve.admit", "serve.admit.prefill", "serve.admit.seed_pool",
@@ -602,13 +881,11 @@ def test_serve_spans_nest_and_carry_their_stats(monkeypatch, mix):
     answers, rows = 3 * 4 * 2, 4 * 2 * CFG.vocab_size
     pulls = {s["bytes"] for name, s, _ in spans if name == "serve.step.pull"}
     assert pulls == {answers + (rows if mix == "steered" else 0)}
-    picked = {
-        (s["device_picked_rows"], s["host_picked_rows"])
-        for name, s, _ in spans if name == "serve.step.sample"
-    }
-    assert picked == {
-        {"greedy": (0, 0), "mixed": (1, 0), "steered": (0, 1)}[mix]
-    }
+    # where the rows' tokens were picked is the step record's to say
+    # (device_picked_rows, host_picked_rows): the span carries nothing
+    assert {
+        tuple(s) for name, s, _ in spans if name == "serve.step.sample"
+    } == {()}
 
 
 @pytest.mark.parametrize("rows", ["greedy", "sampled", "both"])
@@ -697,6 +974,7 @@ def test_jitted_programs_carry_their_tracked_names(attr):
 
 
 @pytest.mark.parametrize("with_phases", [True, False])
+@pytest.mark.usefixtures("no_full_collections")
 def test_serving_top_prints_the_median_phase_times(with_phases):
     import importlib.util
     from pathlib import Path
@@ -709,15 +987,21 @@ def test_serving_top_prints_the_median_phase_times(with_phases):
     if not with_phases:  # an older replica's records
         for s in steps:
             del s["phase_ms"]
+            s.pop("admissions", None)
     text = top.render_steps(
         {"steps": {"recorded": len(steps), "retained": len(steps), "last": steps}}
     )
-    assert len(text.splitlines()) == 2 + len(steps) + with_phases
+    # two lines of medians: the steps' phases, then the two admissions'
+    assert len(text.splitlines()) == 2 + len(steps) + 2 * with_phases
     if with_phases:
-        line = text.splitlines()[-1]
+        line, admit = text.splitlines()[-2:]
         assert line.startswith("  phase p50: upload ")
         assert [w for w in line.split() if w.isalpha() or "_" in w] == [
             "phase", *TOP_PHASES, "sample_choose", "sample_logprob",
+        ]
+        assert admit.startswith("  admit p50 (2): admit ")
+        assert [w for w in admit.split() if w.isalpha() or "_" in w] == [
+            "admit", "admit", "prefill", "seed_pool", "pull", "activate",
         ]
 
 

@@ -453,9 +453,10 @@ def test_the_admission_seeds_the_rings_under_a_span_of_its_own(params, monkeypat
         "serve.admit", "serve.admit.prefill", "serve.admit.seed_window",
         "serve.admit.seed_pool", "serve.admit.pull", "serve.admit.activate",
     ]
-    stats = dict(SpanSpy.spans)["serve.admit.seed_window"]
-    # 4 window layers x K and V x 2 heads x 8 slots x 8 x float32
-    assert stats == {"rows": 1, "bytes": 4 * 2 * 2 * 8 * 8 * 4}
+    assert dict(SpanSpy.spans)["serve.admit.seed_window"] == {}
+    # what it moves is the telemetry's, by row: 4 window layers x K and V x
+    # 2 heads x 8 slots x 8 x float32
+    assert batcher.kv_telemetry()["ring_bytes_per_row"] == 4 * 2 * 2 * 8 * 8 * 4
     # a configuration without window layers has no such span
     monkeypatch.setattr(SpanSpy, "spans", [])
     full = dataclasses.replace(TINY, layer_types=("full_attention",) * 5)
