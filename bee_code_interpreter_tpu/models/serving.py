@@ -91,7 +91,7 @@ from bee_code_interpreter_tpu.ops.paged_kv_cache import (
     BY_ROW_LEAVES,
     alloc_paged_cache,
     pages_leaf,
-    seed_prefill,
+    seed_pool,
     seed_rings,
     seed_state,
 )
@@ -565,8 +565,9 @@ class ContinuousBatcher:
 
     A prompt's K/V reach its row's pages by one of TWO PROGRAMS, chosen at
     one site in ``submit``: the one-shot forward over the padded prompt
-    with one scatter a pool leaf (a base row with no prefix hit and no
-    window width asked for: what every benchmark cell runs), or windows of
+    with the donating ``seed_pool`` program behind it (a base row with no
+    prefix hit and no window width asked for: what every benchmark cell
+    runs), or windows of
     ``decode_window_paged`` over the row's own block table (prefix hits,
     adapter rows, ``prefill_chunk``, ``interleave_admission``). One
     admission record (``prefill_state`` holds those in flight) says which,
@@ -844,11 +845,25 @@ class ContinuousBatcher:
                 ),
                 "seed_rings", donate_argnums=(0,),
             )
-            self._paged_layers = np.asarray(config.paged_layers, np.int32)
             self._ring_bytes_per_row = sum(
                 self.cache[name].nbytes for name in ("wk", "wv")
             ) // max_batch
             self._state_bytes_per_row += self._ring_bytes_per_row
+        # a prompt's K/V into its pages, behind the prefill: one program a
+        # padded prompt width, the pool donated and, under a mesh, kept in
+        # its sharding, so that the write is each chip's own (the draft pool
+        # of the speculative drive goes through it too)
+        self._seed_pool = self._track(
+            functools.partial(
+                seed_pool,
+                paged_layers=config.paged_layers if config.window_layers else None,
+            ),
+            "seed_pool", donate_argnums=(0,),
+            **({} if mesh is None else {
+                "in_shardings": (self._pool_sharding(), None, None, None),
+                "out_shardings": self._pool_sharding(),
+            }),
+        )
         # The one-shot admission program. With a mesh the full forward runs
         # under it — in particular an ``sp`` axis shards the attention over
         # the sequence axis (ring or Ulysses per ``config.sp_attention``,
@@ -1426,13 +1441,17 @@ class ContinuousBatcher:
         (axis 2 of [n_layers, n_pages, kvh, ps, dh]; the int8 scale planes
         share the leading dims, so the one spec covers every leaf). A mesh
         without a tp axis replicates the pool — matching param_specs'
-        whichever-axes-exist stance."""
+        whichever-axes-exist stance. The spec names no axis behind the last
+        sharded one: that is how a compiled program's output carries it, and
+        a spec that differs from it by trailing ``None`` alone is another
+        key to every program's cache (a pool fresh from the allocator or
+        from ``seed_pool`` and one from a decode step compiled each program
+        that takes the pool twice)."""
         from jax.sharding import NamedSharding, PartitionSpec
 
-        tp = "tp" if "tp" in self.mesh.axis_names else None
-        return NamedSharding(
-            self.mesh, PartitionSpec(None, None, tp, None, None)
-        )
+        if "tp" not in self.mesh.axis_names:
+            return NamedSharding(self.mesh, PartitionSpec())
+        return NamedSharding(self.mesh, PartitionSpec(None, None, "tp"))
 
     def _alloc_pool(self, config: TransformerConfig, n_pages: int) -> dict:
         """A zeroed page pool, placed where it will live: under a mesh each
@@ -2042,11 +2061,11 @@ class ContinuousBatcher:
 
     def _one_shot(self, row, padded, L, pages, speculative, logprobs):
         """The one-shot admission program over the ``padded`` prompt
-        [1, Lp]: the exact O(L^2) forward, then the shared
-        one-scatter-per-leaf page seeding (seed_prefill — the equality
-        tests call the same function, so the tested path IS this path)
-        and, over mamba layers, the row's state; over window layers, the
-        row's rings first and the pages from the full layers alone. The
+        [1, Lp]: the exact O(L^2) forward, then the page seeding
+        (``seed_pool``, a donating program over ``seed_prefill`` — the
+        equality tests call the same function, so the tested path IS this
+        path) and, over mamba layers, the row's state; over window layers,
+        the row's rings first and the pages from the full layers alone. The
         padded prompt bounds the compile count: pad tokens are
         causal-masked for every row < L, so logits[L-1] and K/V[:L] are
         exact, and distinct prompt lengths share a program per page count
@@ -2055,11 +2074,11 @@ class ContinuousBatcher:
             "serve.admit.prefill", padded_tokens=padded.shape[1]
         ):
             # K and V [layers, 1, kvh, Lp, dh], or a latent [layers, 1, Lp,
-            # width] alone (no V: ``v_pre`` is empty)
+            # width] alone
             if self._seed_state is None:
-                logits, (k_pre, *v_pre) = self._prefill(self.params, padded)
+                logits, kv = self._prefill(self.params, padded)
             else:  # the state it hands back is that of the L real tokens
-                logits, (k_pre, *v_pre, ssm, conv) = self._prefill(
+                logits, (*kv, ssm, conv) = self._prefill(
                     self.params, padded, length=np.int32(L)
                 )
         if self._seed_rings is not None:
@@ -2067,19 +2086,16 @@ class ContinuousBatcher:
             # positions into the row's rings; the pages take the rest
             with self._phase("serve.admit.seed_window"):
                 self.cache = self._seed_rings(
-                    self.cache, np.int32(row), k_pre, v_pre[0], np.int32(L)
+                    self.cache, np.int32(row), *kv, np.int32(L)
                 )
         with self._phase("serve.admit.seed_pool"):
-            if self._seed_rings is not None:
-                k_pre, v_pre = (
-                    k_pre[self._paged_layers], [v_pre[0][self._paged_layers]]
-                )
-            pages_arr = jnp.asarray(
-                pages[: padded.shape[1] // self.page_size], dtype=jnp.int32
+            # the prompt's pages (those past them hold its answer) and its
+            # true length: all that is uploaded
+            pages = np.asarray(
+                pages[: padded.shape[1] // self.page_size], dtype=np.int32
             )
-            self.cache = seed_prefill(
-                self.cache, pages_arr,
-                k_pre[:, 0, ..., :L, :], *[v[:, 0, :, :L, :] for v in v_pre],
+            self.cache = self._seed_pool(
+                self.cache, pages, np.int32(L), tuple(kv)
             )
         if self._seed_state is not None:
             with self._phase("serve.admit.seed_state"):
@@ -2097,10 +2113,9 @@ class ContinuousBatcher:
             last = self._pull_last_row(logits[0, L - 1, :], logprobs)
         if speculative:
             # the draft's prefill into ITS pool at the same pages
-            _, (dk, dv) = self._draft_prefill(self.draft_params, padded)
-            self.draft_cache = seed_prefill(
-                self.draft_cache, pages_arr,
-                dk[:, 0, :, :L, :], dv[:, 0, :, :L, :],
+            _, draft_kv = self._draft_prefill(self.draft_params, padded)
+            self.draft_cache = self._seed_pool(
+                self.draft_cache, pages, np.int32(L), tuple(draft_kv)
             )
         return last
 

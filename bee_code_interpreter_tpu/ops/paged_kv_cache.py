@@ -318,13 +318,27 @@ def seed_prefill(
     pages: jax.Array,  # [P] int32 physical pages covering ceil(L/ps)
     k_pre: jax.Array,  # [n_layers, kvh, L, dh] — one sequence's prefill K
     v_pre: jax.Array | None = None,  # (a latent pool: [n_layers, L, width] alone)
+    length: jax.Array | None = None,  # the TRUE length where L is a padded width
 ) -> dict:
     """Write one sequence's prefill K/V into its pages — ONE batched
     scatter per pool leaf; the single copy of the prefill-seeding logic
-    (serving.ContinuousBatcher.submit and the equality tests both call
+    (the batcher's ``seed_pool`` program and the equality tests both call
     this, so the tested path IS the served path). int8 pools quantize per
     (token, head) row, identical to cache_append's semantics; the pad tail
-    quantizes to scale-0 exact zeros and stays masked by ``s <= pos``."""
+    quantizes to scale-0 exact zeros and stays masked by ``s <= pos``.
+
+    ``length`` (a traced int32 scalar) is given where the K/V come at a
+    padded width: the positions at and beyond it are written as zeros, which
+    is what cutting the K/V to their true length beforehand writes, so one
+    compiled program serves every length of a width.
+
+    Jitted with ``cache`` donated, the scatter writes a leaf where it lies
+    as long as the leaf lies page-major on the device; a head narrower than
+    the lane tile does not (Granite's 64: the TPU lays that leaf out pages
+    minor-most, and the compiler copies it page-major and back around the
+    scatter, 3.1 ms an admission on a v5e; a loop of
+    ``dynamic_update_slice`` writes it in place at 0.22 ms a PAGE, which is
+    more for a prompt of 27; PERF.md, PR 38)."""
     ps = paged_page_size(cache)
     n_pages_used = int(pages.shape[0])
     L = k_pre.shape[-2]
@@ -332,18 +346,25 @@ def seed_prefill(
         raise ValueError(
             f"prefill length {L} exceeds {n_pages_used} pages of {ps}"
         )
-    if "ckv" in cache:
-        leaf = cache["ckv"]
-        vals = jnp.pad(k_pre, ((0, 0), (0, n_pages_used * ps - L), (0, 0)))
-        vals = vals.reshape(leaf.shape[0], n_pages_used, ps, leaf.shape[-1])
-        return {**cache, "ckv": leaf.at[:, pages].set(vals.astype(leaf.dtype))}
+
+    def whole_pages(x):  # [..., L, last] -> [..., P * ps, last], zeros past the end
+        if length is not None:
+            real = jnp.arange(L, dtype=jnp.int32) < length
+            x = jnp.where(real[:, None], x, jnp.zeros((), x.dtype))
+        tail = [(0, 0)] * (x.ndim - 2) + [(0, n_pages_used * ps - L), (0, 0)]
+        return jnp.pad(x, tail)
 
     def page_view(x):  # [n_layers, kvh, L, dh] -> [n_layers, P, kvh, ps, dh]
-        x = jnp.pad(
-            x, ((0, 0), (0, 0), (0, n_pages_used * ps - L), (0, 0))
-        )
+        x = whole_pages(x)
         nl, kvh, _, dh = x.shape
         return x.reshape(nl, kvh, n_pages_used, ps, dh).transpose(0, 2, 1, 3, 4)
+
+    if "ckv" in cache:
+        leaf = cache["ckv"]
+        vals = whole_pages(k_pre).reshape(
+            leaf.shape[0], n_pages_used, ps, leaf.shape[-1]
+        )
+        return {**cache, "ckv": leaf.at[:, pages].set(vals.astype(leaf.dtype))}
 
     def put(cache, name, sname, pre):
         vals = page_view(pre)
@@ -363,6 +384,23 @@ def seed_prefill(
 
     cache = put(cache, "k", "k_s", k_pre)
     return put(cache, "v", "v_s", v_pre)
+
+
+def seed_pool(
+    cache: dict, pages: jax.Array, length: jax.Array, kv: tuple,
+    paged_layers: tuple[int, ...] | None = None,
+) -> dict:
+    """``seed_prefill`` over a one-sequence prefill's K/V as
+    ``forward(return_kv=True)`` hands them back: ``kv`` is K and V [attention
+    layers, 1, kvh, Lp, dh], or a latent [layers, 1, Lp, width] alone, at
+    the padded width; ``length`` (traced) is the prompt's TRUE length.
+    ``paged_layers`` (static) names the layers that keep pages where the
+    others keep rings (``seed_rings`` takes those). To be jitted with
+    ``cache`` donated, as ``seed_state``: an eager ``.at[].set`` copies each
+    leaf of the pool whole to write one prompt's pages into it."""
+    if paged_layers is not None:
+        kv = [x[np.asarray(paged_layers, np.int32)] for x in kv]
+    return seed_prefill(cache, pages, *[x[:, 0] for x in kv], length=length)
 
 
 def seed_state(cache: dict, row: jax.Array, ssm: jax.Array, conv: jax.Array) -> dict:

@@ -13,6 +13,13 @@ instruction moves an array of the size of one layer of ``wq``, ``wk`` or
 ``wv``. The second runs on the CPU and holds the helper to the plain
 formula, bit for bit, through every path that shares it.
 
+The same reader holds the admission's ``seed_pool`` program to the same
+words about the POOL (PERF.md, PR 38; it stands here because tests that
+compile for the described chip stay in as few files as can be): every leaf
+goes in and comes out in one buffer, and nothing but the scatter itself
+yields an array of a leaf's size. ``tests/test_seed_pool_program.py`` has
+what the program writes.
+
 The topology is described inside a module-scoped fixture (the one
 ``tests/benchmark/test_benchmark_v5e_compile.py`` has, with its shapes):
 only one process may load the TPU's library, and every worker imports
@@ -26,6 +33,7 @@ from __future__ import annotations
 
 import functools
 import re
+import types
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +44,7 @@ from bee_code_interpreter_tpu.models import transformer as T
 from bee_code_interpreter_tpu.models.transformer import TransformerConfig
 from tests.benchmark.conftest import few_cores  # noqa: F401
 from tests.benchmark.test_benchmark_v5e_compile import (  # noqa: F401
+    CONFIGS,
     _shapes,
     flash_on,
     no_compile_cache,
@@ -68,10 +77,12 @@ def _size(shape) -> tuple[int, ...]:
     return tuple(sorted(d for d in shape if d != 1))
 
 
-def weight_moves(hlo: str, sizes: dict) -> list[str]:
+def weight_moves(hlo: str, sizes: dict, uses=("convolution", "dot")) -> list[str]:
     """The instructions of a compiled program that MOVE an array of one of
     ``sizes`` ({sorted dims: name}): outside every fusion, a ``copy``, a
-    slice, or a fusion that holds no dot, whose bf16 result is that large. A
+    slice, or a fusion that holds no dot (none of ``uses``: what may yield
+    an array that large because it is the work itself), whose bf16 result
+    is that large. A
     slice fused into the dot that reads it stands inside the dot's fusion
     and is no instruction of its own. A ``copy-start`` that keeps the layout
     is the weight's one read, fetched into fast memory under other work (the
@@ -103,7 +114,7 @@ def weight_moves(hlo: str, sizes: dict) -> list[str]:
     @functools.cache
     def holds_dot(computation: str) -> bool:
         return any(
-            opcode in ("convolution", "dot") or (called and holds_dot(called))
+            opcode in uses or (called and holds_dot(called))
             for *_, opcode, called in computations.get(computation, ())
         )
 
@@ -114,11 +125,11 @@ def weight_moves(hlo: str, sizes: dict) -> list[str]:
         for name, dtype, shape, opcode, called in body:
             if dtype != "bf16" or opcode in FREE or _size(shape) not in sizes:
                 continue
-            if called and holds_dot(called):
+            if opcode in uses or (called and holds_dot(called)):
                 continue
             moves.append(
                 f"{name} = {dtype}{list(shape)} {opcode} "
-                f"(a layer of {sizes[_size(shape)]})"
+                f"(the size of {sizes[_size(shape)]})"
             )
     return moves
 
@@ -200,6 +211,119 @@ def test_no_prefill_moves_a_layer_of_a_projection(topo, no_compile_cache, flash_
     )
     compiled = prefill.lower(params, ints(1, 512)).compile()
     assert weight_moves(compiled.as_text(), _layer_sizes(params)) == []
+
+
+# ------------------------------------- the pool, written where it lies
+
+def test_the_reader_tells_a_copied_leaf_from_one_written_in_place():
+    """The scatter over a leaf that lies pages minor-most (a head of 64) and
+    over one that lies page-major, as the v5e's compiler wrote them (cut to
+    what the reader reads)."""
+    read = functools.partial(weight_moves, uses=("scatter",))
+    copied = """
+%fused_computation (param_0: bf16[4,2112,8,16,64], param_1.2: s32[13], param_2.4: bf16[13,4,8,16,64]) -> bf16[4,2112,8,16,64] {
+  %param_0 = bf16[4,2112,8,16,64]{4,3,2,1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %scatter.1 = bf16[4,2112,8,16,64]{4,3,2,1,0:T(8,128)(2,1)} scatter(%param_0, %param_1.2, %param_2.4), update_window_dims={0,2,3,4}
+}
+ENTRY %main.5 (cache__k__.1: bf16[4,2112,8,16,64], pages.1: s32[13]) -> bf16[4,2112,8,16,64] {
+  %cache__k__.1 = bf16[4,2112,8,16,64]{1,4,3,2,0:T(8,128)(2,1)} parameter(1), metadata={op_name="cache['k']"}
+  %copy.1 = bf16[4,2112,8,16,64]{4,3,2,1,0:T(8,128)(2,1)} copy(%cache__k__.1), metadata={op_name="cache['k']"}
+  %fusion = bf16[4,2112,8,16,64]{4,3,2,1,0:T(8,128)(2,1)} fusion(%copy.1, %compare_select_fusion, %copy.2), kind=kCustom, calls=%fused_computation
+  ROOT %copy.6 = bf16[4,2112,8,16,64]{1,4,3,2,0:T(8,128)(2,1)} copy(%fusion)
+}
+"""
+    moves = read(copied, sizes={(4, 8, 16, 64, 2112): "k"})
+    assert [m.split(" = ")[0] for m in moves] == ["copy.1", "copy.6"]
+    in_place = """
+%fused_computation (param_0: bf16[16,2560,8,16,128], param_1.2: s32[16], param_2.4: bf16[16,16,8,16,128]) -> bf16[16,2560,8,16,128] {
+  %param_0 = bf16[16,2560,8,16,128]{4,3,2,1,0:T(8,128)(2,1)} parameter(0)
+  ROOT %scatter.1 = bf16[16,2560,8,16,128]{4,3,2,1,0:T(8,128)(2,1)} scatter(%param_0, %transpose.6, %transpose.7), update_window_dims={0,2,3,4}
+}
+ENTRY %main.5 (cache__k__.1: bf16[16,2560,8,16,128], pages.1: s32[16]) -> bf16[16,2560,8,16,128] {
+  %cache__k__.1 = bf16[16,2560,8,16,128]{4,3,2,1,0:T(8,128)(2,1)} parameter(0), metadata={op_name="cache['k']"}
+  ROOT %fusion = bf16[16,2560,8,16,128]{4,3,2,1,0:T(8,128)(2,1)} fusion(%cache__k__.1, %compare_select_fusion, %bitcast.4), kind=kCustom, calls=%fused_computation
+}
+"""
+    assert read(in_place, sizes={(8, 16, 16, 128, 2560): "k"}) == []
+
+
+@pytest.mark.parametrize("name", CONFIGS)
+def test_seed_pool_writes_every_leaf_of_the_pool_in_place(
+    name, topo, no_compile_cache
+):
+    """The admission's program as the batcher jits it, at the longest prompt
+    the configuration's cells send: the pool's bytes are all aliased input
+    to output, the program's temporaries are the prompt's K/V and not a
+    leaf, and no instruction but the scatter yields a leaf. A head narrower
+    than the lane tile is the exception, and is held to what it does: the
+    device lays that leaf out pages minor-most, and the scatter has it
+    copied page-major and back (``seed_prefill``'s docstring has the
+    price, and why a loop of in-place writes is not the cure)."""
+    from jax.sharding import PartitionSpec
+
+    from bee_code_interpreter_tpu.models.serving import ContinuousBatcher
+    from bee_code_interpreter_tpu.ops.paged_kv_cache import (
+        BY_ROW_LEAVES,
+        seed_pool,
+    )
+    from tests.benchmark.test_benchmark_v5e_compile import (
+        _bytes_per_chip,
+        _longest_prompt,
+    )
+
+    cfg, tconfig, mesh, params, cache, ints = _shapes(name, topo)
+    page = cfg["pool"]["page_size"]
+    width = -(-_longest_prompt(name) // page) * page
+    replicated = ints().sharding
+    # K and V as the prefill hands them back: over every attention layer,
+    # under the mesh with the KV heads where the pool has them
+    if tconfig.kv_lora_rank:
+        shapes = [(tconfig.n_attention_layers, 1, width, tconfig.latent_width)]
+    else:
+        shapes = 2 * [(
+            tconfig.n_attention_layers, 1, tconfig.kv_heads, width,
+            tconfig.head_dim,
+        )]
+    pool_sharding = mesh and ContinuousBatcher._pool_sharding(
+        types.SimpleNamespace(mesh=mesh)
+    )
+    kv = tuple(
+        jax.ShapeDtypeStruct(
+            shape, tconfig.dtype, sharding=pool_sharding or replicated
+        )
+        for shape in shapes
+    )
+    fn = functools.partial(
+        seed_pool,
+        paged_layers=tconfig.paged_layers if tconfig.window_layers else None,
+    )
+    fn.__name__ = "seed_pool"
+    program = jax.jit(fn, donate_argnums=(0,), **({} if mesh is None else {
+        "in_shardings": (pool_sharding, None, None, None),
+        "out_shardings": pool_sharding,
+    }))
+    compiled = program.lower(cache, ints(width // page), ints(), kv).compile()
+    hlo = compiled.as_text()
+    assert hlo.startswith("HloModule jit_seed_pool")
+    written = {
+        leaf: x for leaf, x in cache.items() if leaf not in BY_ROW_LEAVES
+    }
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= _bytes_per_chip(cache)  # (a tile pads some)
+    sizes = {
+        _size(x.sharding.shard_shape(x.shape)): leaf for leaf, x in written.items()
+    }
+    moves = weight_moves(hlo, sizes, uses=("scatter",))
+    if tconfig.kv_lora_rank or tconfig.head_dim % 128 == 0:
+        assert moves == []
+        smallest = min(_bytes_per_chip(x) for x in written.values())
+        assert m.temp_size_in_bytes <= 2 * _bytes_per_chip(kv) < smallest
+    else:  # each leaf there and back
+        assert len(moves) == 2 * len(written)
+        assert all(" copy " in move for move in moves)
+    if mesh is not None:  # each chip writes its own KV heads: nothing crosses
+        assert pool_sharding.spec == PartitionSpec(None, None, "tp")
+        assert not re.search(r"all-(reduce|gather|to-all)|collective-permute", hlo)
 
 
 # ------------------------------------------- nothing else changed (CPU)

@@ -608,10 +608,12 @@ def test_a_steady_step_uploads_nothing(tp):
     assert uploads == []
     # the arrays the device left and the ones the host uploaded are the
     # same kind of argument: no other decode program was compiled for them
-    # (under a mesh the pool's first donated copy costs one, as before)
+    # (nor, under a mesh, for the pool a step hands back: the allocator's
+    # pool carries the sharding spec a program's output does,
+    # ``_pool_sharding``)
     late = b.submit(D, 3)
     b.run_to_completion()
-    assert b._decode._cache_size() == compiled + (tp is not None)
+    assert b._decode._cache_size() == compiled
     unsharded = ContinuousBatcher(
         params, config, max_batch=3, n_pages=32, page_size=4,
         max_pages_per_seq=6,

@@ -946,7 +946,7 @@ def test_rows_the_device_chose_for_cross_as_one_small_array(rows):
 
 TRACKED = {
     "_decode": "decode_step_paged", "_prefill": "prefill_forward",
-    "_window": "decode_window_paged",
+    "_window": "decode_window_paged", "_seed_pool": "seed_pool",
     "_draft_decode": "draft_decode_step_paged",
     "_draft_prefill": "draft_prefill_forward",
     "_draft_window": "draft_decode_window_paged",
