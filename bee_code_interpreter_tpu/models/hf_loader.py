@@ -46,9 +46,9 @@ Params = dict[str, Any]
 
 def config_from_hf(hf_config, dtype=jnp.bfloat16) -> TransformerConfig:
     """Our TransformerConfig for an HF ``LlamaConfig``, or for a
-    ``GraniteMoeHybridConfig`` without routed experts (``model_type``
-    ``granitemoehybrid``, ``num_local_experts`` 0: Mamba-2 layers beside
-    attention layers). A plain dict of the published keys (a ``config.json``)
+    ``GraniteMoeHybridConfig`` (``model_type`` ``granitemoehybrid``: Mamba-2
+    layers beside attention layers, with or without routed experts under
+    each). A plain dict of the published keys (a ``config.json``)
     is taken like the object. Refuses silently unloadable settings instead
     of approximating them."""
     if isinstance(hf_config, dict):
@@ -234,16 +234,33 @@ def _exaone_moe_config(hf_config, dtype, eps) -> TransformerConfig:
 
 
 def _granite_hybrid_config(hf_config, dtype, eps=1e-5) -> TransformerConfig:
-    """``granitemoehybrid`` without routed experts: every published key the
-    program has a field for, and a refusal by name for what it computes
-    otherwise."""
+    """``granitemoehybrid``: every published key the program has a field
+    for, and a refusal by name for what it computes otherwise. Without
+    routed experts (``num_local_experts`` 0) every layer's MLP is the
+    shared SwiGLU of ``shared_intermediate_size``; with them, the sorted
+    expert layer under a softmax over the top-k logits (every expert held:
+    a chip's share is the configuration's to cut), experts of
+    ``intermediate_size`` beside the shared MLP, which is ONE SwiGLU of
+    ``shared_intermediate_size``: that many experts' widths."""
     experts = getattr(hf_config, "num_local_experts", 0)
-    if experts:
+    top_k = getattr(hf_config, "num_experts_per_tok", 0)
+    width = hf_config.intermediate_size
+    shared = getattr(hf_config, "shared_intermediate_size", width)
+    if experts and not 0 < top_k <= experts:
         raise ValueError(
-            f"num_local_experts {experts} unsupported for granitemoehybrid: "
-            "a layer pattern runs the shared SwiGLU MLP only (no routed "
-            "experts beside it)"
+            f"num_local_experts {experts} unsupported with "
+            f"num_experts_per_tok {top_k}: a token keeps 1 to "
+            f"{experts} experts"
         )
+    if experts and shared % width:
+        raise ValueError(
+            f"shared_intermediate_size {shared} unsupported beside experts "
+            f"of {width}: the shared MLP is a whole number of experts' widths"
+        )
+    moe = {} if not experts else {
+        "n_experts": experts, "moe_top_k": top_k, "moe_held_experts": experts,
+        "moe_shared_experts": shared // width,
+    }
     refused = {
         "mamba_proj_bias": (False, "the mixer's projections have no bias"),
         "mamba_conv_bias": (True, "the mixer's conv has a bias"),
@@ -253,14 +270,13 @@ def _granite_hybrid_config(hf_config, dtype, eps=1e-5) -> TransformerConfig:
         value = getattr(hf_config, key, fixed)
         if value != fixed:
             raise ValueError(f"{key} {value!r} unsupported: {why}")
-    d_ff = getattr(hf_config, "shared_intermediate_size", hf_config.intermediate_size)
     return TransformerConfig(
         vocab_size=hf_config.vocab_size,
         d_model=hf_config.hidden_size,
         n_layers=hf_config.num_hidden_layers,
         n_heads=hf_config.num_attention_heads,
         n_kv_heads=hf_config.num_key_value_heads,
-        d_ff=d_ff,
+        d_ff=width if experts else shared,
         max_seq_len=hf_config.max_position_embeddings,
         rope_theta=getattr(hf_config, "rope_theta", 10000.0),
         dtype=dtype,
@@ -279,6 +295,7 @@ def _granite_hybrid_config(hf_config, dtype, eps=1e-5) -> TransformerConfig:
         position_embedding=hf_config.position_embedding_type,
         tie_embeddings=bool(getattr(hf_config, "tie_word_embeddings", False)),
         rms_norm_eps=eps,
+        **moe,
     )
 
 

@@ -19,8 +19,9 @@ NOT a ragged/sort-based CUDA-style implementation):
   mean-prob_e) keeps routing from collapsing; the transformer adds it to the
   training loss scaled by ``moe_aux_weight``.
 
-Beside it, the SORTED dispatch (``held_experts_mlp``; the DeepSeek-V3
-lineage's router): an expert layer that is told which experts it holds,
+Beside it, the SORTED dispatch (``held_experts_mlp``, under either
+router: the DeepSeek-V3 lineage's sigmoid or a softmax over the top-k
+logits): an expert layer that is told which experts it holds,
 routes over all of them, sorts the (token, expert) pairs routed to its own
 experts by expert and runs them through one grouped matmul
 (``jax.lax.ragged_dot``, on a TPU a Mosaic kernel over the rows the groups
@@ -265,6 +266,24 @@ def route_sigmoid(x: jax.Array, moe: Params, config):
     return chosen.astype(jnp.int32), weights
 
 
+def route_softmax(x: jax.Array, moe: Params, config):
+    """The softmax router's choice for tokens ``x`` [T, D], in float32: the
+    ``moe_top_k`` largest logits over all experts (the router's published
+    width), weighted by a softmax over those kept alone; no bias, no
+    scaling. (Mixtral's softmax over every expert, then renormalised over
+    the kept, is the same numbers; Granite's top-k gating.) Gives (expert
+    ids [T, k] int32, weights [T, k] float32)."""
+    logits = jnp.einsum(
+        "td,de->te", x.astype(jnp.float32), moe["router"].astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    kept, chosen = lax.top_k(logits, config.moe_top_k)
+    return chosen.astype(jnp.int32), jax.nn.softmax(kept, axis=-1)
+
+
+ROUTERS = {"sigmoid": route_sigmoid, "softmax": route_softmax}
+
+
 def sorted_rows(n_tokens: int, config) -> int:
     """Rows of the sorted buffer the common case runs at: the pairs
     ``n_tokens`` route to the held experts in expectation, and a quarter
@@ -338,7 +357,7 @@ def held_experts_mlp(moe: Params, y: jax.Array, config) -> jax.Array:
     T = B * L
     x = y.reshape(T, D)
     with jax.named_scope("moe.route"):
-        chosen, weights = route_sigmoid(x, moe, c)
+        chosen, weights = ROUTERS[c.moe_scoring](x, moe, c)
     with jax.named_scope("moe.sort"):
         order, sizes = sort_pairs(chosen, c)
     small, every = sorted_rows(T, c), T * c.moe_top_k

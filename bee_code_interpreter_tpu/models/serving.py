@@ -1316,6 +1316,15 @@ class ContinuousBatcher:
         if c.window_layers:
             by_kind["sliding_attention"] = "ring_by_row"
         out["decode_attention_by_kind"] = by_kind
+        # the expert layer, where there is one: the layers that have it, the
+        # experts held here of the router's width, the experts a token
+        # keeps and how the router scores ("softmax" / "sigmoid"; None
+        # without experts)
+        out["expert_layers"] = c.n_experts and c.n_layers - c.n_dense_layers
+        out["held_experts"] = c.n_experts and c.held_experts
+        out["router_width"] = c.n_experts
+        out["experts_per_token"] = c.n_experts and c.moe_top_k
+        out["router"] = c.moe_scoring if c.n_experts else None
         return out
 
     # ----------------------------------------------------- snapshot/resume
@@ -1818,7 +1827,7 @@ class ContinuousBatcher:
         the pool alone, and a count of their own would be one more pull a
         step. Empty for every other configuration."""
         c = self.config
-        if not c.n_experts or c.moe_scoring != "sigmoid":
+        if not c.sorted_experts:
             return {}
         per_token = (
             (c.n_layers - c.n_dense_layers) * c.moe_top_k

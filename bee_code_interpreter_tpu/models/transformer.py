@@ -203,18 +203,22 @@ class TransformerConfig:
     # leaves are stacked under ``params["dense_layers"]`` and they run
     # before the scan over the expert layers.
     n_dense_layers: int = 0
-    # "softmax": Mixtral's router (softmax over all experts, top-k,
-    # renormalised) through the GShard dispatch of ``moe.moe_mlp``.
-    # "sigmoid": the DeepSeek-V3 lineage's (sigmoid scores in float32, the
-    # top-k of score + a per-expert bias, weighted by the kept scores over
-    # their sum times ``moe_routed_scaling``) through the token-sorted
-    # dropless dispatch of ``moe.held_experts_mlp``, which is told which
-    # experts it holds: ``moe_held_experts`` of them from ``moe_held_from``
-    # on (None = all ``n_experts``, the router's width). What the experts
-    # held elsewhere would add is left out and the partial sum goes on.
-    # ``moe_d_ff`` is an expert's own width (None = ``d_ff``),
-    # ``moe_shared_experts`` experts of that width take every token, and
-    # with ``moe_router_bias`` the router leaf's last row is the bias.
+    # How the router scores, and nothing else. "softmax": the top-k of the
+    # float32 logits over all experts, weighted by a softmax over the kept
+    # ones (Mixtral's softmax-then-renormalise, which is the same numbers;
+    # Granite's top-k gating). "sigmoid": the DeepSeek-V3 lineage's
+    # (sigmoid scores in float32, the top-k of score + a per-expert bias,
+    # weighted by the kept scores over their sum times
+    # ``moe_routed_scaling``). Which dispatch runs is ``sorted_experts``:
+    # the token-sorted dropless one of ``moe.held_experts_mlp``, which is
+    # told which experts it holds: ``moe_held_experts`` of them from
+    # ``moe_held_from`` on (None = all ``n_experts``, the router's width).
+    # What the experts held elsewhere would add is left out and the partial
+    # sum goes on. ``moe_d_ff`` is an expert's own width (None = ``d_ff``),
+    # ``moe_shared_experts`` experts of that width take every token as ONE
+    # SwiGLU of their summed width, and with ``moe_router_bias`` the router
+    # leaf's last row is the bias. Without any of these, a softmax router
+    # runs through the GShard dispatch of ``moe.moe_mlp`` (Mixtral's).
     moe_scoring: str = "softmax"
     moe_held_experts: int | None = None
     moe_held_from: int = 0
@@ -255,20 +259,23 @@ class TransformerConfig:
                 "an int8 latent cache is not supported: the latent is kept "
                 "in the compute dtype"
             )
-        sorted_only = {
-            "moe_held_experts": self.moe_held_experts is not None,
-            "moe_shared_experts": bool(self.moe_shared_experts),
-            "moe_router_bias": self.moe_router_bias,
-            "moe_routed_scaling": self.moe_routed_scaling != 1.0,
-            "n_dense_layers": bool(self.n_dense_layers),
-        }
-        if self.moe_scoring != "sigmoid" and any(sorted_only.values()):
+        sigmoid_only = [
+            name for name, asked in (
+                ("moe_router_bias", self.moe_router_bias),
+                ("moe_routed_scaling", self.moe_routed_scaling != 1.0),
+            ) if asked
+        ]
+        if self.moe_scoring == "softmax" and sigmoid_only:
             raise NotImplementedError(
-                f"{[k for k, v in sorted_only.items() if v]} belong to the "
-                "sorted expert layer (moe_scoring='sigmoid'); the GShard "
-                "dispatch holds every expert and has none of them"
+                f"{sigmoid_only} belong to the sigmoid router; the softmax "
+                "router weighs the kept logits alone"
             )
-        if self.moe_scoring == "sigmoid" and not (
+        expert_only = [k for k, v in self._sorted_fields().items() if v]
+        if not self.n_experts and expert_only:
+            raise NotImplementedError(
+                f"{expert_only} belong to an expert layer (n_experts 0)"
+            )
+        if self.sorted_experts and not (
             0 <= self.moe_held_from
             and self.moe_held_from + self.held_experts <= self.n_experts
             and self.moe_top_k <= self.n_experts
@@ -314,11 +321,11 @@ class TransformerConfig:
                 f"sliding_window (got {self.sliding_window}), no latent "
                 "cache and no int8 cache"
             )
-        if self.n_experts and self.moe_scoring != "sigmoid":
+        if self.n_experts and not self.sorted_experts:
             raise ValueError(
                 "a layer pattern with expert MLPs takes the sorted expert "
-                "layer (moe_scoring='sigmoid'); the GShard dispatch scans "
-                "one layer kind"
+                "layer (sorted_experts: a held share, shared experts or a "
+                "sigmoid router); the GShard dispatch scans one layer kind"
             )
         if "mamba" in types_[:self.n_dense_layers]:
             raise ValueError("a leading dense layer is an attention layer")
@@ -405,6 +412,30 @@ class TransformerConfig:
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
+    def _sorted_fields(self) -> dict[str, bool]:
+        """What a configuration states that only the sorted expert layer
+        has (the leading dense layers precede a scan of its layers)."""
+        return {
+            "moe_held_experts": self.moe_held_experts is not None,
+            "moe_shared_experts": bool(self.moe_shared_experts),
+            "moe_router_bias": self.moe_router_bias,
+            "moe_routed_scaling": self.moe_routed_scaling != 1.0,
+            "n_dense_layers": bool(self.n_dense_layers),
+        }
+
+    @property
+    def sorted_experts(self) -> bool:
+        """Whether the expert layer is the sorted, dropless one of
+        ``moe.held_experts_mlp`` (pairs sorted by expert through a grouped
+        matmul over the experts held here), chosen by what the configuration
+        states: a share of the experts, shared experts, a router bias, a
+        routed scaling, leading dense layers or a sigmoid router. A softmax
+        router with none of them (Mixtral's) keeps the GShard dispatch of
+        ``moe.moe_mlp``."""
+        return bool(self.n_experts) and (
+            self.moe_scoring == "sigmoid" or any(self._sorted_fields().values())
+        )
+
     @property
     def held_experts(self) -> int:
         """Routed experts whose weights this program holds."""
@@ -451,14 +482,14 @@ class TransformerConfig:
         composition — dense configs always; MoE configs under dropless
         per-token routing (moe_dropless + moe_group_size=1: no capacity
         eviction, and the expert einsums see the pool only as a batch
-        dim), and always under the sorted dispatch (``moe_scoring``
-        "sigmoid"), which drops nothing and computes a (token, expert)
-        pair's row from that token alone. The exactness-claiming features (serving solo-equality,
+        dim), and always under the sorted dispatch (``sorted_experts``),
+        which drops nothing and computes a (token, expert) pair's row from
+        that token alone. The exactness-claiming features (serving solo-equality,
         prefix cache, speculative verify, beam rescoring) key on this;
         dropless with larger groups is deterministic and ulp-stable but
         reduction tiling varies with pool shape, so near-exact logit ties
         could flip a token."""
-        return self.n_experts == 0 or self.moe_scoring == "sigmoid" or (
+        return self.n_experts == 0 or self.sorted_experts or (
             self.moe_dropless and self.moe_group_size == 1
         )
 
@@ -633,7 +664,7 @@ def init_params(config: TransformerConfig, key: jax.Array) -> Params:
             "ln1": jnp.ones((c.d_model,), jnp.float32),
             "ln2": jnp.ones((c.d_model,), jnp.float32),
         }
-        if experts and c.moe_scoring == "sigmoid":
+        if experts and c.sorted_experts:
             from bee_code_interpreter_tpu.models.moe import init_held_params
 
             out["moe"] = init_held_params(ks[0], c)
@@ -722,7 +753,7 @@ def param_specs(config: TransformerConfig, mesh: Mesh) -> Params:
     elif config.qk_norm:
         layer["ln_q"] = layer["ln_k"] = _stack(rep)
     dense_layer = dict(layer)
-    if config.n_experts and config.moe_scoring == "sigmoid":
+    if config.sorted_experts:
         layer["moe"] = {
             "router": _stack(rep), "we_gate": _stack(P(ep, fsdp, tp)),
             "we_up": _stack(P(ep, fsdp, tp)), "we_down": _stack(P(ep, tp, fsdp)),
@@ -1109,7 +1140,7 @@ def _mlp_block(
     decode_step_paged. Returns (mlp_out, aux) with aux = 0.0 for dense
     configs (decode paths drop it)."""
     c = config
-    if "moe" in layer and c.moe_scoring == "sigmoid":
+    if "moe" in layer and c.sorted_experts:
         from bee_code_interpreter_tpu.models.moe import held_experts_mlp
 
         return held_experts_mlp(layer["moe"], y, c), jnp.float32(0.0)
@@ -1203,7 +1234,7 @@ def _pattern_layer(layers: Params, config: TransformerConfig, period_index, j):
     for name in layers:
         if name in MIXER_LEAVES + ATTENTION_LEAVES + LATENT_LEAVES:
             continue
-        if name == "moe" and c.moe_scoring == "sigmoid":
+        if name == "moe" and c.sorted_experts:
             from bee_code_interpreter_tpu.models.moe import take_held_layer
 
             layer[name] = take_held_layer(layers[name], every)
@@ -1235,7 +1266,7 @@ def _scans_by_index(config: TransformerConfig) -> bool:
     c = config
     return (
         c.layer_types is not None or bool(c.n_dense_layers)
-        or (bool(c.n_experts) and c.moe_scoring == "sigmoid")
+        or c.sorted_experts
     )
 
 

@@ -447,9 +447,9 @@ def test_what_a_latent_pool_cannot_do_yet_is_refused_by_name(params):
                                  qk_norm=False), 1, 8)
     with pytest.raises(NotImplementedError, match="a latent cache"):
         T.decode_window(params, jnp.zeros((1, 1), jnp.int32), 0, cache, TINY)
-    # fields of the sorted expert layer under the GShard dispatch
-    with pytest.raises(NotImplementedError, match="moe_shared_experts"):
-        T.TransformerConfig(n_experts=8, moe_shared_experts=1)
+    # a field of the sigmoid router under the softmax one
+    with pytest.raises(NotImplementedError, match="moe_router_bias"):
+        T.TransformerConfig(n_experts=8, moe_router_bias=True)
     with pytest.raises(ValueError, match="deepseek_yarn"):
         T.TransformerConfig(rope_yarn={"type": "linear", "factor": 2})
 
