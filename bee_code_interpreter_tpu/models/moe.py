@@ -23,11 +23,13 @@ Beside it, the SORTED dispatch (``held_experts_mlp``, under either
 router: the DeepSeek-V3 lineage's sigmoid or a softmax over the top-k
 logits): an expert layer that is told which experts it holds,
 routes over all of them, sorts the (token, expert) pairs routed to its own
-experts by expert and runs them through one grouped matmul
-(``jax.lax.ragged_dot``, on a TPU a Mosaic kernel over the rows the groups
-cover). Nothing is dropped, a pair's row is computed from its token alone,
-and what experts held elsewhere would add is left out: the chip's share of
-an expert-parallel layer, without the exchange.
+experts by expert and runs them through one grouped matmul: at a decode
+step's sizes on a TPU, ``ops/grouped_matmul``'s Pallas kernel, which visits
+the held experts of the one layer that has rows; elsewhere
+``jax.lax.ragged_dot`` (``grouped_experts`` has the rule). Nothing is
+dropped, a pair's row is computed from its token alone, and what experts
+held elsewhere would add is left out: the chip's share of an
+expert-parallel layer, without the exchange.
 
 The reference (a code-execution service) has no MoE; this module exists for
 the framework's model-family/parallelism completeness: the full dp × ep × tp
@@ -42,6 +44,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from bee_code_interpreter_tpu.ops import grouped_matmul
 
 Params = dict
 
@@ -310,13 +314,31 @@ def sort_pairs(chosen: jax.Array, config):
     return order, sizes
 
 
+def expert_matmul(n_tokens: int, config) -> str:
+    """Which grouped matmul ``n_tokens`` tokens' common buffer
+    (``sorted_rows``) runs through: ``"gmm"`` or ``"ragged_dot"``, by
+    ``grouped_experts``'s rule."""
+    rows = sorted_rows(n_tokens, config)
+    if grouped_matmul.takes_kernel(rows, config.held_experts):
+        return "gmm"
+    return "ragged_dot"
+
+
 def grouped_experts(x, order, sizes, weights, moe: Params, config, rows: int):
     """The held experts' weighted sum for every token of ``x`` [T, D], in
     float32, over a sorted buffer of ``rows`` rows (which must hold every
     pair ``sizes`` counts): the rows' tokens gathered, three grouped
     matmuls whose groups are layers x held experts (all but this layer's
     empty), and each token's pairs gathered back and summed in the order of
-    its top-k slots."""
+    its top-k slots.
+
+    The matmuls: a buffer of at most ``grouped_matmul.MAX_ROWS_PER_GROUP``
+    rows a held expert on a TPU (a decode step's: 6 to 24 rows an expert in
+    the benchmark's configurations, where a prefill's has 40 and more) goes
+    through ``grouped_matmul.gmm``, rounded up to its row tile, which visits
+    the groups that have rows and skips the empty ones; every other buffer
+    through ``lax.ragged_dot``. Both take the merged stack whole. Rows no
+    group covers hold garbage and are kept out of the sum by a select."""
     c = config
     T, k, held = x.shape[0], c.moe_top_k, c.held_experts
     stacks = [moe[name] for name in EXPERT_STACKS]
@@ -331,11 +353,16 @@ def grouped_experts(x, order, sizes, weights, moe: Params, config, rows: int):
     gate_w, up_w, down_w = (
         w.astype(c.dtype).reshape(n_layers * held, *w.shape[2:]) for w in stacks
     )
-    xs = x.astype(c.dtype)[order[:rows] // k]  # [rows, D]
+    if grouped_matmul.takes_kernel(rows, held):
+        matmul, run = grouped_matmul.gmm, grouped_matmul.padded_rows(rows)
+    else:
+        matmul, run = lax.ragged_dot, rows
+    sources = order if run <= T * k else jnp.pad(order, (0, run - T * k))
+    xs = x.astype(c.dtype)[sources[:run] // k]  # [run, D]
     with jax.named_scope("moe.experts"):
-        gate = lax.ragged_dot(xs, gate_w, groups)
-        up = lax.ragged_dot(xs, up_w, groups)
-        out = lax.ragged_dot(jax.nn.silu(gate) * up, down_w, groups)
+        gate = matmul(xs, gate_w, groups)
+        up = matmul(xs, up_w, groups)
+        out = matmul(jax.nn.silu(gate) * up, down_w, groups)
     # where each pair sits in the buffer (beyond the pairs counted: nowhere)
     at = jnp.zeros((T * k,), jnp.int32).at[order].set(
         jnp.arange(T * k, dtype=jnp.int32)

@@ -80,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from bee_code_interpreter_tpu.models.moe import expert_matmul
 from bee_code_interpreter_tpu.models.transformer import (
     TransformerConfig,
     decode_step_paged,
@@ -824,6 +825,21 @@ class ContinuousBatcher:
         self._decode_in_place = reads_pages_in_place(
             self.cache, 1, config.paged_window, mesh
         )
+        # which grouped matmul the held experts take (kv_telemetry), by the
+        # rule the programs are traced under: the decode program's buffer
+        # (max_batch tokens), a prefill of the longest prompt a row holds,
+        # and the widest prompt whose prefill takes the kernel (0: none)
+        self._expert_matmul = None
+        if config.sorted_experts:
+            widths = range(page_size, self.max_len + 1, page_size)
+            self._expert_matmul = {
+                "decode": expert_matmul(max_batch, config),
+                "prefill": expert_matmul(self.max_len, config),
+                "prefill_gmm_tokens": max(
+                    (w for w in widths if expert_matmul(w, config) == "gmm"),
+                    default=0,
+                ),
+            }
         # what the mamba layers keep for a row, replaced whole at admission
         self._seed_state = None
         self._state_bytes_per_row = 0
@@ -1325,6 +1341,10 @@ class ContinuousBatcher:
         out["router_width"] = c.n_experts
         out["experts_per_token"] = c.n_experts and c.moe_top_k
         out["router"] = c.moe_scoring if c.n_experts else None
+        # the sorted expert layer's grouped matmul, program by program:
+        # "gmm" (ops.grouped_matmul's kernel, over the groups that have
+        # rows) or "ragged_dot"; None without that layer
+        out["expert_matmul"] = self._expert_matmul and dict(self._expert_matmul)
         return out
 
     # ----------------------------------------------------- snapshot/resume

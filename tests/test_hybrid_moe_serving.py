@@ -28,6 +28,7 @@ from bee_code_interpreter_tpu.models.serving import (
     ContinuousBatcher,
     SamplingParams,
 )
+from bee_code_interpreter_tpu.ops import grouped_matmul
 
 ROOT = Path(__file__).resolve().parents[1]
 PATTERN = ("mamba", "attention", "mamba") * 2
@@ -233,10 +234,13 @@ def _bench_config(name: str) -> T.TransformerConfig:
     return harness.transformer_config(T, json.loads((ROOT / entry["file"]).read_text()))
 
 
-def test_each_configuration_takes_the_dispatch_it_took_before():
+def test_each_configuration_takes_the_dispatch_it_took_before(monkeypatch):
     """Mixtral (softmax, every expert, nothing beside) keeps the GShard
     dispatch; the sigmoid configurations and granite-4.0-h-small (softmax, a
-    held share, a shared MLP) take the sorted one. No option chooses it."""
+    held share, a shared MLP) take the sorted one. No option chooses it.
+    Its grouped matmul: ``ragged_dot`` off a TPU; on one, the kernel at a
+    decode-sized buffer (8 tokens: 4 rows a held expert) and ``ragged_dot``
+    at a prefill's (96 tokens: 48 rows an expert)."""
     assert not _bench_config("mixtral-8x7b").sorted_experts
     assert not T.TransformerConfig.mixtral_8x7b().sorted_experts
     for name in ("sarvam-105b", "k-exaone-236b-a23b", "granite-4.0-h-small"):
@@ -246,16 +250,21 @@ def test_each_configuration_takes_the_dispatch_it_took_before():
         "softmax", 36, 72
     )
 
-    def program(config):
+    def program(config, tokens=8):
         params = T.init_params(config, jax.random.PRNGKey(0))
         return str(jax.make_jaxpr(lambda p, t: T.forward(p, t, config))(
-            params, jnp.zeros((1, 8), jnp.int32)
+            params, jnp.zeros((1, tokens), jnp.int32)
         ))
 
     gshard = T.TransformerConfig.tiny_moe()
     assert "ragged_dot" not in program(gshard)
     shared = dataclasses.replace(gshard, moe_shared_experts=1)
     assert shared.sorted_experts and "ragged_dot" in program(shared)
+    assert "pallas_call" not in program(shared)
+    monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
+    decode_sized, prefill_sized = program(shared, 8), program(shared, 96)
+    assert "pallas_call" in decode_sized and "ragged_dot" not in decode_sized
+    assert "ragged_dot" in prefill_sized and "pallas_call" not in prefill_sized
     # what only the sigmoid router has is refused under the softmax one
     for field, value in (("moe_router_bias", True), ("moe_routed_scaling", 2.5)):
         with pytest.raises(NotImplementedError, match=field):

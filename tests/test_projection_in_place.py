@@ -213,6 +213,59 @@ def test_no_prefill_moves_a_layer_of_a_projection(topo, no_compile_cache, flash_
     assert weight_moves(compiled.as_text(), _layer_sizes(params)) == []
 
 
+# ---------------------------- the held experts' stacks, read in place
+
+def _kernel_operands(hlo: str) -> list[str]:
+    """The operand shapes of each Mosaic call, as its layout constraints
+    list them (and what follows them on the line)."""
+    return [
+        line.split("operand_layout_constraints=", 1)[1]
+        for line in hlo.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+        and "operand_layout_constraints=" in line
+    ]
+
+
+def test_the_held_experts_decode_step_reads_the_stacks_in_place(
+    topo, no_compile_cache, flash_on, monkeypatch
+):
+    """granite-4.0-h-small on a described v5e, the path steered to the
+    chip's (``grouped_matmul.on_tpu``, Mosaic and not the interpreter): the
+    decode step's grouped matmuls are the kernel's calls over the 10 layers
+    x 36 held experts merged, ``bf16[360,4096,768]`` and
+    ``bf16[360,768,4096]``, and nothing copies or cuts a stack or a layer of
+    it; the prefill of the shortest prompt its cell sends keeps
+    ``ragged_dot``."""
+    from bee_code_interpreter_tpu.ops import grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
+    monkeypatch.setattr(
+        grouped_matmul, "gmm", functools.partial(grouped_matmul.gmm, interpret=False)
+    )
+    cfg, tconfig, mesh, params, cache, ints = _shapes("granite-4.0-h-small", topo)
+    batch, width = cfg["pool"]["max_batch"], cfg["pool"]["max_pages_per_seq"]
+    decode = jax.jit(
+        functools.partial(T.decode_step_paged, config=tconfig, lora_scale=1.0),
+        donate_argnums=(3,),
+    )
+    hlo = decode.lower(
+        params, ints(batch, 1), ints(batch), cache, ints(batch, width)
+    ).compile().as_text()
+    calls = _kernel_operands(hlo)
+    for stack in ("bf16[360,4096,768]{", "bf16[360,768,4096]{"):
+        assert sum(stack in operands for operands in calls) >= 10, stack
+    assert "ragged-dot" not in hlo
+    sizes = {}
+    for name in ("we_gate", "we_down"):
+        stack = params["layers"]["moe"][name].shape  # [layers, held, ...]
+        merged = (stack[0] * stack[1], *stack[2:])
+        for shape in (stack, merged, stack[1:]):
+            sizes[_size(shape)] = name
+    assert weight_moves(hlo, sizes, uses=("custom-call", "convolution", "dot")) == []
+    prefill = jax.jit(functools.partial(T.forward, config=tconfig, return_kv=True))
+    assert "ragged-dot" in prefill.lower(params, ints(1, 512)).compile().as_text()
+
+
 # ------------------------------------- the pool, written where it lies
 
 def test_the_reader_tells_a_copied_leaf_from_one_written_in_place():
